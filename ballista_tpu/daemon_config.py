@@ -12,10 +12,7 @@ from __future__ import annotations
 import argparse
 import os
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: same API under the PyPI name
-    import tomli as tomllib
+import tomllib
 from typing import Any, Dict, List, Optional, Tuple
 
 SCHEDULER_SPEC: List[Tuple[str, Any, str]] = [

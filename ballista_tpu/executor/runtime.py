@@ -92,6 +92,13 @@ class BallistaExecutor:
         return self.poll_loop.drain(timeout)
 
     def start(self) -> None:
+        if self.config.backend() == "tpu":
+            # the chip belongs to this process: establish it before the
+            # first task can be pulled, so an executor that came up on the
+            # wrong platform refuses to serve instead of answering from it
+            from ballista_tpu.ops import device
+
+            device.establish(self.config)
         if self.config.tpu_prewarm():
             # AOT pre-warm BEFORE serving (ISSUE 8): compile every persisted
             # program so the first small query pays zero trace/compile. A

@@ -16,7 +16,7 @@ beside the persisted layout cache (ops/layout_cache.py):
 - A later process's first call LOADS the artifact instead of tracing:
   deserialize + AOT-compile (jax.jit(exported.call).lower(avals).compile()),
   which skips the Python trace entirely and turns the XLA compile into a
-  persistent-compilation-cache hit (kernels._configure_jax_cache).
+  persistent-compilation-cache hit (ops/device.py places that cache).
 - `prewarm()` walks the manifest at executor start and compiles every
   artifact BEFORE the first task arrives, so a cold executor's first small
   query runs with zero trace and zero compile (the latency harness asserts
@@ -67,19 +67,19 @@ def _record(event: str, n: int = 1) -> None:
 
 def fingerprint() -> str:
     """jax/jaxlib/backend identity baked into every key AND every artifact:
-    a program compiled by a different stack must never be trusted."""
+    a program compiled by a different stack must never be trusted. Raises
+    when no device can be established — an artifact keyed "unknown" would
+    be shared by every platform that failed to come up."""
     global _fingerprint_cache
     if _fingerprint_cache is None:
         import jax
         import jaxlib
 
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:
-            platform = "unknown"
+        from ballista_tpu.ops import device
+
         _fingerprint_cache = (
             f"v{_FORMAT}|jax{jax.__version__}|jaxlib{jaxlib.__version__}"
-            f"|{platform}"
+            f"|{device.establish().platform}"
         )
     return _fingerprint_cache
 

@@ -24,11 +24,18 @@ job-id-scrubbed stage plan shape via task_run_op below — the rates behind
 speculative-execution straggler detection), and "stage.batch" under engine
 "task" (units = member count; the SCHEDULER's wall durations of shared-scan
 batched tasks, ISSUE 13 — the evidence gate dispatches solo when a batch is
-predicted slower than the members' solo task.run sum). Entries carry the
-jax/jaxlib/backend
-fingerprint of the writer (ops/aotcache.py::fingerprint): a store written
-by a different stack is ignored wholesale — costs measured on another
-backend must never steer this one.
+predicted slower than the members' solo task.run sum).
+
+The store is two files, one per writer role, because a chip belongs to one
+process. `costs.json` holds what an EXECUTOR measured (engines "device" and
+"host") and carries the jax/jaxlib/platform fingerprint of the writer
+(ops/aotcache.py::fingerprint): a store written by a different stack is
+ignored wholesale — costs measured on another backend must never steer
+this one. `tasks.json` holds what the SCHEDULER measured (engine "task":
+wall durations of whole tasks as the control plane saw them) under a
+fingerprint that names no platform, so the scheduler loads, predicts and
+flushes without ever initialising a JAX backend — reading the platform
+would take the chip from the executor beside it.
 
 Prediction is rate-based: predict(op, engine, units) returns
 units * (total_s / total_units), preferring the exact units bucket when it
@@ -66,7 +73,10 @@ from ballista_tpu.utils.locks import make_lock
 # pre-existing store's unit-less rates would predict file_bytes x
 # seconds-per-run, a guaranteed gross mispredict per cached stage shape.
 _FORMAT = 2
-_STORE_BASENAME = "costs.json"
+# engine -> store file: the scheduler's engine has a file of its own, which
+# a process running no device code can read and write (module docstring)
+_TASK_ENGINE = "task"
+_BASENAMES = {"exec": "costs.json", "task": "tasks.json"}
 
 # minimum observations before a rate is trusted for prediction
 MIN_OBSERVATIONS = 4
@@ -85,8 +95,8 @@ _dir: str = ""  # "" = in-memory only; guarded-by: _lock
 # read on hot paths (readback, h2d) — CPython bool loads are atomic and a
 # stale read costs at most one missed/extra observation, never corruption
 _enabled: bool = False
-_loaded: bool = False  # guarded-by: _lock
-_dirty: bool = False  # guarded-by: _lock
+_loaded: set = set()  # domains ("exec"/"task") lazily loaded; guarded-by: _lock
+_dirty: set = set()  # domains with unpersisted mutations; guarded-by: _lock
 # bumped with every mutation; flush() only clears _dirty when the store it
 # snapshotted is still current, so observations landing during an in-flight
 # flush are never left unpersisted at exit; guarded-by: _lock
@@ -113,7 +123,7 @@ def configure(config) -> None:
     """Bind directory + enablement from a config, like the AOT cache. The
     last configuration wins; a directory change drops the in-memory store
     (entries lazily reload from the new path)."""
-    global _dir, _enabled, _loaded, _dirty, _gen
+    global _dir, _enabled, _gen
     d = config.tpu_cost_model_dir()
     en = config.tpu_cost_model()
     global _atexit_registered
@@ -123,8 +133,8 @@ def configure(config) -> None:
             _dir = d
             _store.clear()
             _gen += 1
-            _loaded = False
-            _dirty = False
+            _loaded.clear()
+            _dirty.clear()
             # start the flush throttle NOW: the first observation on a hot
             # path (readback, gather) must not pay a synchronous disk
             # round-trip; atexit + explicit flush() cover the tail
@@ -140,18 +150,29 @@ def configure(config) -> None:
 def reset(clear_dir: bool = False) -> None:
     """Test hook: drop the in-memory store (and optionally forget the
     directory) so a fresh process can be simulated."""
-    global _dir, _enabled, _loaded, _dirty, _gen
+    global _dir, _enabled, _gen
     with _lock:
         _store.clear()
         _gen += 1
-        _loaded = False
-        _dirty = False
+        _loaded.clear()
+        _dirty.clear()
         if clear_dir:
             _dir = ""
             _enabled = False
 
 
-def _fingerprint() -> str:
+def _domain(engine: str) -> str:
+    return "task" if engine == _TASK_ENGINE else "exec"
+
+
+def _key_domain(key: str) -> str:
+    # key = op|engine|b<bucket>; op may itself contain "|"
+    return _domain(key.rsplit("|", 2)[1])
+
+
+def _fingerprint(domain: str) -> str:
+    if domain == "task":
+        return f"cm{_FORMAT}|task"
     from ballista_tpu.ops import aotcache
 
     return f"cm{_FORMAT}|{aotcache.fingerprint()}"
@@ -183,83 +204,62 @@ def task_run_op(shape: str) -> str:
 
 
 # holds-lock: _lock
-def _load_locked() -> None:
-    """Lazy-load the persisted store. Corruption or a fingerprint mismatch
-    starts empty with the reason recorded — a bad store must reproduce
-    cold-start routing, never crash or steer."""
-    global _loaded
-    if _loaded:
+def _load_locked(engine: str) -> None:
+    """Lazy-load the persisted file `engine` lives in. Corruption or a
+    fingerprint mismatch starts empty with the reason recorded — a bad
+    store must reproduce cold-start routing, never crash or steer."""
+    domain = _domain(engine)
+    if domain in _loaded:
         return
-    _loaded = True
+    _loaded.add(domain)
     if not _dir:
         return
-    path = os.path.join(_dir, _STORE_BASENAME)
+    path = os.path.join(_dir, _BASENAMES[domain])
+    loaded: Dict[str, Dict[str, float]] = {}
     try:
         with open(path) as f:
             blob = json.load(f)
-        if blob.get("format") != _FORMAT or blob.get("fingerprint") != _fingerprint():
+        if (
+            blob.get("format") != _FORMAT
+            or blob.get("fingerprint") != _fingerprint(domain)
+        ):
             _record_event("cost_store_fingerprint_mismatch")
             return
         for k, e in blob.get("entries", {}).items():
             s, units, n = float(e["s"]), float(e["units"]), int(e["n"])
-            if s < 0 or units <= 0 or n <= 0:
+            if s < 0 or units <= 0 or n <= 0 or _key_domain(k) != domain:
                 raise ValueError(f"bad entry {k}")
-            _store[k] = {"s": s, "units": units, "n": n}
+            loaded[k] = {"s": s, "units": units, "n": n}
     except FileNotFoundError:
         return
-    except Exception:
-        _store.clear()
+    except (OSError, ValueError, KeyError, TypeError, AttributeError):
         _record_event("cost_store_corrupt")
         return
+    _store.update(loaded)
 
 
 def flush() -> None:
-    """Best-effort atomic persist (tmp+rename). Merge policy is
-    last-writer-wins per key: another process's entries for keys we never
-    touched survive; shared keys take our value. Never raises."""
-    global _dirty, _last_flush
+    """Best-effort atomic persist (tmp+rename) of every file with unsaved
+    mutations. Merge policy is last-writer-wins per key: another process's
+    entries for keys we never touched survive; shared keys take our value.
+    Never raises."""
+    global _last_flush
     with _lock:
         if not _dir or not _dirty:
             return
+        domains = sorted(_dirty)
         entries = {k: dict(v) for k, v in _store.items()}
         base = _dir
         gen = _gen
     try:
         os.makedirs(base, exist_ok=True)
-        path = os.path.join(base, _STORE_BASENAME)
-        merged = dict(entries)
-        try:
-            with open(path) as f:
-                blob = json.load(f)
-            if (
-                blob.get("format") == _FORMAT
-                and blob.get("fingerprint") == _fingerprint()
-            ):
-                for k, e in blob.get("entries", {}).items():
-                    merged.setdefault(k, e)
-        except Exception:
-            pass
-        fd, tmp = tempfile.mkstemp(dir=base, prefix=".wip-")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(
-                    {
-                        "format": _FORMAT,
-                        "fingerprint": _fingerprint(),
-                        "entries": merged,
-                    },
-                    f,
-                )
-            os.replace(tmp, path)
-        except BaseException:
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            raise
+        for domain in domains:
+            _write_domain(base, domain, {
+                k: v for k, v in entries.items() if _key_domain(k) == domain
+            })
         with _lock:
             if _gen == gen:
-                _dirty = False
+                _dirty.difference_update(domains)
             _last_flush = time.monotonic()
     except Exception:
         # still advance the throttle clock: an unwritable dir must not make
@@ -269,15 +269,44 @@ def flush() -> None:
         return
 
 
+def _write_domain(base: str, domain: str, entries: dict) -> None:
+    path = os.path.join(base, _BASENAMES[domain])
+    fingerprint = _fingerprint(domain)
+    merged = dict(entries)
+    try:
+        with open(path) as f:
+            blob = json.load(f)
+        if blob.get("format") == _FORMAT and blob.get("fingerprint") == fingerprint:
+            for k, e in blob.get("entries", {}).items():
+                merged.setdefault(k, e)
+    except (OSError, ValueError, AttributeError):
+        pass  # absent or unreadable: ours replaces it
+    fd, tmp = tempfile.mkstemp(dir=base, prefix=".wip-")
+    try:
+        with os.fdopen(fd, "w") as f:
+            json.dump(
+                {"format": _FORMAT, "fingerprint": fingerprint,
+                 "entries": merged},
+                f,
+            )
+        os.replace(tmp, path)
+    except BaseException:
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        raise
+
+
 def observe(op: str, units: float, seconds: float, engine: str = "device") -> None:
     """Record one measured cost. No-op while the model is disabled, so hot
     paths (readback, h2d) can call unconditionally."""
     if not _enabled or seconds < 0 or units <= 0:
         return
-    global _dirty, _last_flush, _gen
+    global _last_flush, _gen
     k = _key(op, engine, _bucket(units))
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         e = _store.get(k)
         if e is None:
             _store[k] = {"s": float(seconds), "units": float(units), "n": 1}
@@ -289,7 +318,7 @@ def observe(op: str, units: float, seconds: float, engine: str = "device") -> No
             e["s"] += float(seconds)
             e["units"] += float(units)
             e["n"] += 1
-        _dirty = True
+        _dirty.add(_domain(engine))
         _gen += 1
         due = _dir and time.monotonic() - _last_flush > _FLUSH_INTERVAL_S
         if due:
@@ -307,13 +336,13 @@ def seed(op: str, units: float, seconds: float, engine: str = "device",
          n: int = MIN_OBSERVATIONS) -> None:
     """Directly install a warm entry (tests + the fuzz slice's adversarial
     entries). Replaces any history for the bucket."""
-    global _dirty, _gen
+    global _gen
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         _store[_key(op, engine, _bucket(units))] = {
             "s": float(seconds), "units": float(units), "n": int(n),
         }
-        _dirty = True
+        _dirty.add(_domain(engine))
         _gen += 1
 
 
@@ -323,13 +352,13 @@ def retier(op: str, units: float, seconds: float, engine: str = "device") -> Non
     averaging the surprise away."""
     if not _enabled:
         return
-    global _dirty, _gen
+    global _gen
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         _store[_key(op, engine, _bucket(units))] = {
             "s": float(seconds), "units": float(units), "n": MIN_OBSERVATIONS,
         }
-        _dirty = True
+        _dirty.add(_domain(engine))
         _gen += 1
     _record_event("retier")
 
@@ -386,7 +415,7 @@ def rate(op: str, engine: str = "device") -> Optional[Tuple[float, int]]:
     None when nothing was observed."""
     prefix = f"{op}|{engine}|b"
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         s = units = 0.0
         n = 0
         for k, e in _store.items():
@@ -409,7 +438,7 @@ def bucket_rate(op: str, units: float, engine: str = "device") -> Optional[float
         return None
     k = _key(op, engine, _bucket(units))
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         e = _store.get(k)
         if e is None or e["n"] < MIN_OBSERVATIONS or e["units"] <= 0:
             return None
@@ -424,7 +453,7 @@ def predict(op: str, units: float, engine: str = "device") -> Optional[float]:
         return None
     k = _key(op, engine, _bucket(units))
     with _lock:
-        _load_locked()
+        _load_locked(engine)
         e = _store.get(k)
         if e is not None and e["n"] >= MIN_OBSERVATIONS and e["units"] > 0:
             return units * e["s"] / e["units"]
@@ -435,7 +464,9 @@ def predict(op: str, units: float, engine: str = "device") -> Optional[float]:
 
 
 def snapshot() -> Dict[str, Dict[str, float]]:
-    """Copy of the in-memory store (tests/diagnostics)."""
+    """Copy of the in-memory store, both files loaded (tests/diagnostics
+    in a process that may touch the device)."""
     with _lock:
-        _load_locked()
+        _load_locked("device")
+        _load_locked(_TASK_ENGINE)
         return {k: dict(v) for k, v in _store.items()}

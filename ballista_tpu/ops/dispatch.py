@@ -1,7 +1,9 @@
 """Backend dispatch: route operator compute to JAX/XLA kernels.
 
-Each hook returns None when the JAX kernel set is unavailable (or declines
-the shape); operators then fall back to the host Arrow path.
+Each hook returns None when the device path declines the shape (always
+with a recorded reason, ops/kernels.py); operators then fall back to the
+host Arrow path. A kernel module that fails to IMPORT is not a decline: it
+raises, so a broken installation cannot pass for a host-side answer.
 """
 
 from __future__ import annotations
@@ -11,20 +13,13 @@ from typing import Optional
 import pyarrow as pa
 
 
-def _kernels():
-    try:
-        from ballista_tpu.ops import kernels
-
-        return kernels
-    except ImportError:
-        return None
-
-
 def tpu_filter(batch: pa.RecordBatch, predicate) -> Optional[pa.RecordBatch]:
-    k = _kernels()
-    return k.filter_batch(batch, predicate) if k else None
+    from ballista_tpu.ops import kernels
+
+    return kernels.filter_batch(batch, predicate)
 
 
 def tpu_hash_aggregate(exec_node, partition: int, ctx) -> Optional[pa.Table]:
-    k = _kernels()
-    return k.hash_aggregate(exec_node, partition, ctx) if k else None
+    from ballista_tpu.ops import kernels
+
+    return kernels.hash_aggregate(exec_node, partition, ctx)

@@ -50,7 +50,10 @@ import numpy as np
 # entries move from one-blob-per-(file set, partition) to one entry per
 # (path, mtime, size, chunk_index) so appends re-prepare only new chunks;
 # whole-set v5 blobs would shadow the chunk store, so they are orphaned.
-_FORMAT = 6
+# v7 (PR 21): LUT-narrowed columns carry 64-entry tables (runtime.
+# _LUT_MAX_VALUES); a v6 entry's 256-entry table decodes through the slow
+# TPU gather the narrower table exists to avoid.
+_FORMAT = 7
 
 
 def cache_dir_for(base: str, stage_key: str, partition: int) -> str:

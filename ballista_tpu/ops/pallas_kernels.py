@@ -12,8 +12,8 @@ block resident in VMEM) and sorted_grouped_sum (cardinality-independent,
 RMW DMA windows over sorted dense ranks). The latter is wired into the
 fused stage behind ballista.tpu.sorted_kernel=pallas
 (stage.py::_run_pallas_sorted); the chunked-segment layout remains the
-default because it measures faster on v5e (see the status note on
-_build_sorted and dev/probe_sorted.py).
+default (see the status note on _build_sorted). dev/probe_pallas.py runs
+both kernels compiled on the chip against numpy.
 """
 
 from __future__ import annotations
@@ -34,6 +34,15 @@ def pallas_available() -> bool:
         return True
     except Exception:
         return False
+
+
+def _interpret_by_default() -> bool:
+    """Mosaic compiles for the TPU only; on the CPU (the test lane) the
+    kernels run in the Pallas interpreter. The platform is the established
+    one (ops/device.py), not a guess."""
+    from ballista_tpu.ops import device
+
+    return device.establish().platform != "tpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -57,9 +66,14 @@ def _build(num_groups: int, n_values: int, interpret: bool):
         onehot = (
             codes[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, G), 1)
         ).astype(jnp.float32)                     # [B, G]
-        # the group-by: [G, B] @ [B, A] on the MXU
+        # the group-by: [G, B] @ [B, A] on the MXU. HIGHEST keeps the value
+        # products at effectively f32: at the default precision the MXU
+        # rounds the values to bf16, and the compiled kernel was 1.7e-3 off
+        # numpy at 6M rows on a v5e (my chip run, PR 21) where the
+        # interpreter, which multiplies in f32, had always agreed.
         out_ref[:] += jnp.dot(
-            onehot.T, vals, preferred_element_type=jnp.float32
+            onehot.T, vals, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     @jax.jit
@@ -103,13 +117,14 @@ def _build_sorted(n_values_padded: int, block: int, interpret: bool):
     Precision: one-hot entries are exact in bf16; HIGHEST precision keeps
     value products at effectively f32, accumulation is f32 adds.
 
-    Status: measured ~107ms for 6M rows on v5e (MXU utilization is capped by
-    the skinny value dimension, and the RMW DMA serializes the grid). The
-    chunked-segment layout (ops/layout.py + stage._sorted_core) does the
-    same job in ~0.15ms of device time and is the default; this kernel is
-    selectable with ballista.tpu.sorted_kernel=pallas (sum/count/avg
-    stages, stage.py::_run_pallas_sorted) and dev/probe_sorted.py keeps the
-    perf comparison honest.
+    Status: compiles under Mosaic (JAX 0.9.0) and agrees with numpy at 6M
+    rows for G=6 and G=1.5M; one call took 40 ms on a v5e (my chip run, PR
+    21, dev/probe_pallas.py) — MXU utilization is capped by the skinny
+    value dimension, and the RMW DMA serializes the grid. The
+    chunked-segment layout (ops/layout.py + stage._sorted_core) is the
+    default and has not been timed against it on the current machine; this
+    kernel is selectable with ballista.tpu.sorted_kernel=pallas
+    (sum/count/avg stages, stage.py::_run_pallas_sorted).
     """
     import jax
     import jax.numpy as jnp
@@ -187,11 +202,10 @@ def sorted_grouped_sum(
     device array [n_values, num_groups]; pure jit-compatible pieces, one
     pallas_call.
     """
-    import jax
     import jax.numpy as jnp
 
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = _interpret_by_default()
     nv, n = values.shape
     assert codes.shape == (n,)
     B = SORT_BLOCK
@@ -222,11 +236,10 @@ def grouped_aggregate(
     """
     if not pallas_available() or num_groups > 128:
         return None
-    import jax
     import jax.numpy as jnp
 
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = _interpret_by_default()
     n = len(codes)
     if n == 0:
         return np.zeros((num_groups, values.shape[1]), dtype=np.float32)

@@ -28,3 +28,16 @@ def build_mesh(shape: Optional[Dict[str, int]] = None, devices=None):
         raise ValueError(f"mesh {shape} needs {total} devices, have {len(devices)}")
     devs = np.array(devices[:total]).reshape(tuple(shape.values()))
     return Mesh(devs, tuple(shape.keys()))
+
+
+def put_sharded(mesh, arr: np.ndarray, axis: str = "data"):
+    """Upload a host array split on its leading dimension along `axis`,
+    block by block: each device receives only its own rows. `jnp.asarray`
+    would land the WHOLE array on device 0 and leave the jitted mesh program
+    to redistribute it, so one chip's peak memory would be the entire
+    input. (The multi-process path assembles the same layout from per-host
+    blocks, multihost.make_sharded.)"""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(arr, NamedSharding(mesh, P(axis)))

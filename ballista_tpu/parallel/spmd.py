@@ -34,7 +34,7 @@ def build_psum_aggregate(mesh, num_groups: int,
     """
     import jax
     import jax.numpy as jnp
-    from ballista_tpu.parallel.meshcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def per_shard(codes, *cols):
@@ -80,7 +80,7 @@ def build_all_to_all_exchange_aggregate(mesh, axis: str = "data"):
     """
     import jax
     import jax.numpy as jnp
-    from ballista_tpu.parallel.meshcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = mesh.shape[axis]

@@ -104,17 +104,11 @@ class SpmdJoinExec(ExecutionPlan):
 
     # ------------------------------------------------------------------
     def _build_mesh(self, ctx: TaskContext):
-        import jax
-
         from ballista_tpu.parallel.mesh import build_mesh
 
-        if self._mesh is not None:
-            return self._mesh
-        shape = ctx.config.mesh_shape() or None
-        try:
-            self._mesh = build_mesh(shape)
-        except ValueError:
-            self._mesh = build_mesh({"data": len(jax.devices())})
+        if self._mesh is None:
+            # raises when the mesh asks for more devices than there are
+            self._mesh = build_mesh(ctx.config.mesh_shape() or None)
         return self._mesh
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
@@ -124,56 +118,41 @@ class SpmdJoinExec(ExecutionPlan):
         if ctx.backend != "tpu":
             yield from self._execute_host(ctx)
             return
+        from ballista_tpu.ops.runtime import (
+            UnsupportedOnDevice,
+            record_join_path,
+            record_routing,
+        )
+
+        declined = None
         try:
             self._inline_host = False
             self._mesh_cost = (None, None)
             out = self._execute_mesh(ctx)
-            self.last_path = "host-inline" if self._inline_host else "mesh"
-            tracing.incr(
-                "spmd.join_host_inline" if self._inline_host
-                else "spmd.join_mesh"
-            )
-            if not self._inline_host:
-                from ballista_tpu.ops.runtime import (
-                    record_join_path,
-                    record_routing,
-                )
-
-                predicted, observed = self._mesh_cost
-                record_join_path("device")
-                record_routing(
-                    "device", "join.mesh",
-                    predicted_s=predicted, observed_s=observed,
-                )
-        except Exception:
-            import logging
-            import sys
-
-            from ballista_tpu.ops.runtime import (
-                UnsupportedOnDevice,
-                record_join_path,
-            )
-
-            exc = sys.exc_info()[1]
+        except UnsupportedOnDevice as e:
+            # only a reasoned decline goes to the host join; any other
+            # error of the mesh program fails the task (see
+            # SpmdAggregateExec.execute)
+            declined = e
+        if declined is not None:
             tracing.incr("spmd.join_host_fallback")
-            # reasoned declines carry their (bounded) reason text; arbitrary
-            # errors record only the exception type, or a long-lived
-            # executor's reason map would grow one key per distinct message
-            record_join_path(
-                "host_fallback",
-                f"mesh join: {exc}" if isinstance(exc, UnsupportedOnDevice)
-                else f"mesh join error: {type(exc).__name__}",
-            )
-            from ballista_tpu.ops.runtime import record_routing
-
+            record_join_path("host_fallback", f"mesh join: {declined}")
             record_routing("host", "join.mesh")
-            if not isinstance(exc, UnsupportedOnDevice):
-                logging.getLogger("ballista.spmd").warning(
-                    "mesh join failed, host fallback: %s", exc
-                )
             self.last_path = "host"
             yield from self._execute_host(ctx)
             return
+        self.last_path = "host-inline" if self._inline_host else "mesh"
+        tracing.incr(
+            "spmd.join_host_inline" if self._inline_host
+            else "spmd.join_mesh"
+        )
+        if not self._inline_host:
+            predicted, observed = self._mesh_cost
+            record_join_path("device")
+            record_routing(
+                "device", "join.mesh",
+                predicted_s=predicted, observed_s=observed,
+            )
         yield from batch_table(out, ctx.batch_size)
 
     def _execute_host(self, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
@@ -182,9 +161,9 @@ class SpmdJoinExec(ExecutionPlan):
     # ------------------------------------------------------------------
     def _execute_mesh(self, ctx: TaskContext) -> pa.Table:
         import jax
-        import jax.numpy as jnp
 
         from ballista_tpu.ops.runtime import UnsupportedOnDevice, readback
+        from ballista_tpu.parallel.mesh import put_sharded
         from ballista_tpu.physical.joinutil import (
             combined_key_codes,
             take_table,
@@ -332,7 +311,7 @@ class SpmdJoinExec(ExecutionPlan):
         predicted = mesh_pred
         t_mesh0 = _time.perf_counter()
         outs = program(
-            jnp.asarray(lc), jnp.asarray(lr), jnp.asarray(pc_), jnp.asarray(pr)
+            *(put_sharded(mesh, a) for a in (lc, lr, pc_, pr))
         )
         # the matching plane comes back over d2h: account for it, or the
         # bench readback fields undercount the mesh-join path
@@ -413,7 +392,7 @@ class SpmdJoinExec(ExecutionPlan):
         import jax
         import jax.numpy as jnp
         from ballista_tpu.ops.join import gather_matches, match_runs
-        from ballista_tpu.parallel.meshcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def a2a(x):

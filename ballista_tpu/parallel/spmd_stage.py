@@ -142,10 +142,11 @@ def _np_dtype_for(dtype: pa.DataType) -> np.dtype:
 class SpmdAggregateExec(ExecutionPlan):
     """Executes Final(Repartition(Partial(input))) as one mesh program.
 
-    Falls back to executing the wrapped subplan on the host when the mesh
-    can't be built or the stage doesn't lower (high cardinality, exprs the
-    device path declines, non-TPU backend) — the wrapped subplan is the
-    untouched original subtree, so behavior is identical minus the fusion.
+    Falls back to executing the wrapped subplan on the host when the stage
+    declines to lower (UnsupportedOnDevice: high cardinality, exprs the
+    device path declines) or the backend is not tpu — the wrapped subplan
+    is the untouched original subtree, so behavior is identical minus the
+    fusion. Any other error of the mesh program fails the task.
     """
 
     def __init__(self, subplan: ExecutionPlan) -> None:
@@ -196,16 +197,10 @@ class SpmdAggregateExec(ExecutionPlan):
     def _build_mesh(self, ctx: TaskContext):
         from ballista_tpu.parallel.mesh import build_mesh
 
-        import jax
-
-        if self._mesh is not None:
-            return self._mesh
-        shape = ctx.config.mesh_shape() or None
-        try:
-            self._mesh = build_mesh(shape)
-        except ValueError:
-            # fewer devices than the configured mesh: use all local devices
-            self._mesh = build_mesh({"data": len(jax.devices())})
+        if self._mesh is None:
+            # a mesh larger than the device count raises: a program that
+            # asked for four chips must not quietly answer from one
+            self._mesh = build_mesh(ctx.config.mesh_shape() or None)
         return self._mesh
 
     def fingerprint(self) -> str:
@@ -219,8 +214,6 @@ class SpmdAggregateExec(ExecutionPlan):
 
         text = "\n".join(walk(self.subplan))
         return hashlib.sha1(text.encode()).hexdigest()[:12]
-
-    _warned_fingerprints: set = set()
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
         from ballista_tpu.utils import tracing
@@ -261,38 +254,34 @@ class SpmdAggregateExec(ExecutionPlan):
                 out = collect_all(self.subplan, ctx)
             yield from batch_table(out, ctx.batch_size)
             return
+        from ballista_tpu.ops.runtime import UnsupportedOnDevice
+
         try:
             with costmodel.timed(op, routing_op="mesh.agg"):
                 out = self._execute_mesh(ctx)
             self.last_path = "mesh"
             tracing.incr("spmd.mesh")
-        except Exception:  # device decline of any kind -> host subplan
-            from ballista_tpu.ops.runtime import UnsupportedOnDevice
+        except UnsupportedOnDevice as declined:
+            # a reasoned decline is the ONLY way to the host: anything else
+            # the mesh program raises (an XLA compile error, an exhausted
+            # device, a sharding error) fails the task, as the single-chip
+            # ladder does (ops/kernels.py::hash_aggregate)
             import logging
-            import sys
 
-            exc = sys.exc_info()[1]
-            tracing.incr("spmd.host_fallback")
-            if not isinstance(exc, UnsupportedOnDevice):
-                tracing.incr("spmd.host_fallback_error")
-                fp = self.fingerprint()
-                if fp not in self._warned_fingerprints:
-                    self._warned_fingerprints.add(fp)
-                    logging.getLogger("ballista.spmd").warning(
-                        "mesh aggregation failed (stage %s), host fallback: %s",
-                        fp, exc,
-                    )
             from ballista_tpu.ops.runtime import record_routing
 
+            logging.getLogger("ballista.spmd").info(
+                "mesh aggregation declined (stage %s), host subplan: %s",
+                self.fingerprint(), declined,
+            )
+            tracing.incr("spmd.host_fallback")
             record_routing("host", "mesh.agg")
             self.last_path = "host"
             # the forced fallback still warms the host-side rate the
             # admission check above compares against (predictive=False: a
-            # run the mesh error forced must not re-tier on surprise)
+            # run the decline forced must not re-tier on surprise)
             with costmodel.timed(host_op, engine="host", predictive=False):
                 out = collect_all(self.subplan, ctx)
-            yield from batch_table(out, ctx.batch_size)
-            return
         yield from batch_table(out, ctx.batch_size)
 
     def _execute_host(self, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
@@ -687,7 +676,7 @@ class SpmdAggregateExec(ExecutionPlan):
         """G <= MAX_GROUPS: per-shard unrolled reductions + psum exchange.
         Shard blocks are padded to a common size and laid out contiguously,
         so shard d's rows live exactly in block d of the sharded arrays."""
-        import jax.numpy as jnp
+        from ballista_tpu.parallel.mesh import put_sharded
 
         from ballista_tpu.ops.runtime import bucket_rows, readback
 
@@ -703,7 +692,7 @@ class SpmdAggregateExec(ExecutionPlan):
                 if d is not None:
                     npcol = d["npcols"][idx]
                     big[si * S: si * S + len(npcol)] = npcol
-            cols[idx] = jnp.asarray(big)
+            cols[idx] = put_sharded(mesh, big)
         codes_big = np.zeros(total, dtype=np.int32)
         valid_big = np.zeros(total, dtype=np.bool_)
         for si, d in enumerate(shards):
@@ -716,7 +705,8 @@ class SpmdAggregateExec(ExecutionPlan):
         seg = int(bucket_rows(n_groups, 16)) + 1  # +1 dump slot
         program = self._get_program(mesh, stage, seg, set(cols.keys()), len(aux))
         stacked = readback(
-            program(cols, aux, jnp.asarray(codes_big), jnp.asarray(valid_big))
+            program(cols, aux, put_sharded(mesh, codes_big),
+                    put_sharded(mesh, valid_big))
         )
         rows = stage._decode_stacked(stacked)
         return rows[0][:n_groups], [r[:n_groups] for r in rows[1:]]
@@ -726,7 +716,7 @@ class SpmdAggregateExec(ExecutionPlan):
         partials folded to dense [G] in-program (sorted segment ops over a
         small V), then psum/pmin/pmax over the mesh. Cardinality-independent:
         device work is O(rows + G), never O(G) serial passes."""
-        import jax.numpy as jnp
+        from ballista_tpu.parallel.mesh import put_sharded
 
         from ballista_tpu.ops.layout import SortedSegmentLayout
         from ballista_tpu.ops.runtime import bucket_rows, readback
@@ -758,7 +748,7 @@ class SpmdAggregateExec(ExecutionPlan):
                     big[si * V_pad: si * V_pad + l.V] = l.materialize(
                         d["npcols"][idx]
                     )
-            cols[idx] = jnp.asarray(big)
+            cols[idx] = put_sharded(mesh, big)
         clen_big = np.zeros(n_dev * V_pad, dtype=np.int16)
         # padding chunks carry identity partials (clen=0 -> empty mask), so
         # any segment may absorb them — use G_pad-1 to keep each shard's
@@ -773,7 +763,8 @@ class SpmdAggregateExec(ExecutionPlan):
             mesh, stage, G_pad, L1, set(cols.keys()), len(aux)
         )
         stacked = readback(
-            program(cols, aux, jnp.asarray(clen_big), jnp.asarray(owner_big))
+            program(cols, aux, put_sharded(mesh, clen_big),
+                    put_sharded(mesh, owner_big))
         )
         rows = stage._decode_stacked(stacked)
         return rows[0][:n_groups], [r[:n_groups] for r in rows[1:]]
@@ -787,7 +778,7 @@ class SpmdAggregateExec(ExecutionPlan):
 
         import jax
         import jax.numpy as jnp
-        from ballista_tpu.parallel.meshcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ballista_tpu.ops.stage import jnp_unpack_i32
@@ -848,7 +839,7 @@ class SpmdAggregateExec(ExecutionPlan):
 
         import jax
         import jax.numpy as jnp
-        from ballista_tpu.parallel.meshcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ballista_tpu.ops.stage import jnp_unpack_i32

@@ -83,7 +83,16 @@ class TaskContext:
 
     @property
     def backend(self) -> str:
-        return self.config.backend()
+        """The kernel backend this task runs on. "tpu" is only ever
+        returned once the device behind it is established (ops/device.py):
+        every device dispatch branches on this property, so none can run
+        on a platform nobody checked."""
+        backend = self.config.backend()
+        if backend == "tpu":
+            from ballista_tpu.ops import device
+
+            device.establish(self.config)
+        return backend
 
 
 class ExecutionPlan:

@@ -83,10 +83,8 @@ def _load_manifest() -> Tuple[Dict[str, int], frozenset, frozenset]:
         path = os.path.join(root, "dev", "analysis", "lockorder.toml")
         if not os.path.exists(path):
             return {}, frozenset(), frozenset()
-        try:
-            import tomllib as toml  # py3.11+
-        except ImportError:  # pragma: no cover - py3.10 fallback
-            import tomli as toml  # type: ignore
+        import tomllib as toml
+
         with open(path, "rb") as f:
             data = toml.load(f)
         ranks = {n: i for i, n in enumerate(data.get("order", ()))}

@@ -315,28 +315,49 @@ _CHUNK_KEYS = {
 }
 
 
-def _chunked_write(out_dir, name, total, parts, seed, gen_chunk) -> None:
-    """Write `total` keys of table `name`, generated in fixed-size chunks
-    seeded from rng([seed, tag, k]). Chunking depends ONLY on the table's
-    cap — never on `parts` — so the DATA is deterministic for a given
-    (seed, sf); `parts` only controls the file layout (generated chunks are
-    sliced into sub-files when fewer chunks than parts exist)."""
-    import zlib
-
-    d = os.path.join(out_dir, name)
-    os.makedirs(d, exist_ok=True)
+def _chunk_jobs(name: str, total: int, parts: int) -> List[tuple]:
+    """(name, k, lo, n, subsplit) for every fixed-size chunk of `total` keys
+    of table `name`. Chunking depends ONLY on the table's cap — never on
+    `parts` or on how many workers generate — so the DATA is deterministic
+    for a given (seed, sf); `parts` only controls the file layout
+    (generated chunks are sliced into sub-files when fewer chunks than
+    parts exist)."""
     cap = _CHUNK_KEYS[name]
     n_chunks = max(1, -(-total // cap))
     step = -(-total // n_chunks)
     subsplit = max(1, -(-max(1, parts) // n_chunks))
-    tag = zlib.crc32(name.encode())  # stable across processes (hash() is not)
+    jobs = []
     for k in range(n_chunks):
         lo = k * step
         n = min(step, total - lo)
-        if n <= 0:
-            break
-        rng = np.random.default_rng([seed, tag, k])
-        gen_chunk(rng, lo, n, d, k, subsplit)
+        if n > 0:
+            jobs.append((name, k, lo, n, subsplit))
+    return jobs
+
+
+def _write_chunk(out_dir: str, sf: float, seed: int, job: tuple) -> None:
+    """Generate and write one chunk, seeded from rng([seed, tag, k]). A
+    top-level function of plain arguments: worker processes import this
+    module afresh and receive the job pickled."""
+    import zlib
+
+    name, k, lo, n, subsplit = job
+    d = os.path.join(out_dir, name)
+    tag = zlib.crc32(name.encode())  # stable across processes (hash() is not)
+    rng = np.random.default_rng([seed, tag, k])
+    if name == "orders":
+        # orders + lineitem ride the same chunk (lineitem rows derive from
+        # the chunk's orders)
+        o = gen_orders(sf, rng, lo, n)
+        _write_split(o, d, k, subsplit)
+        _write_split(
+            gen_lineitem(sf, rng, o), os.path.join(out_dir, "lineitem"),
+            k, subsplit,
+        )
+        return
+    gen = {"part": gen_part, "partsupp": gen_partsupp,
+           "customer": gen_customer}[name]
+    _write_split(gen(sf, rng, lo, n), d, k, subsplit)
 
 
 def _write_split(table: pa.Table, d: str, k: int, subsplit: int) -> None:
@@ -349,7 +370,12 @@ def _write_split(table: pa.Table, d: str, k: int, subsplit: int) -> None:
             pq.write_table(chunk, os.path.join(d, f"part-{k:03d}-{s:02d}.parquet"))
 
 
-def generate(out_dir: str, sf: float = 0.01, parts: int = 2, seed: int = 20260728) -> None:
+def generate(out_dir: str, sf: float = 0.01, parts: int = 2,
+             seed: int = 20260728, workers: int = 1) -> None:
+    """Write the eight tables under `out_dir`. `workers` > 1 generates the
+    chunks in that many spawned processes (numpy and pyarrow only — a
+    worker never imports JAX, so the caller keeps the chip); the files are
+    the same for any worker count."""
     import shutil
 
     os.makedirs(out_dir, exist_ok=True)
@@ -367,32 +393,31 @@ def generate(out_dir: str, sf: float = 0.01, parts: int = 2, seed: int = 2026072
     write_partitioned(gen_nation(), out_dir, "nation", 1)
     write_partitioned(gen_supplier(sf, rng), out_dir, "supplier", 1)
 
-    _chunked_write(
-        out_dir, "part", max(1, int(200_000 * sf)), parts, seed,
-        lambda r, lo, n, d, k, ss: _write_split(gen_part(sf, r, lo, n), d, k, ss),
-    )
-    _chunked_write(
-        out_dir, "partsupp", max(1, int(200_000 * sf)), parts, seed,
-        lambda r, lo, n, d, k, ss: _write_split(gen_partsupp(sf, r, lo, n), d, k, ss),
-    )
-    _chunked_write(
-        out_dir, "customer", max(1, int(150_000 * sf)), parts, seed,
-        lambda r, lo, n, d, k, ss: _write_split(gen_customer(sf, r, lo, n), d, k, ss),
-    )
+    jobs = []
+    # the orders chunks carry ~4x their keys in lineitem rows: queue them
+    # first so the longest jobs never start last
+    for name, per_sf in (("orders", 1_500_000), ("part", 200_000),
+                         ("partsupp", 200_000), ("customer", 150_000)):
+        os.makedirs(os.path.join(out_dir, name), exist_ok=True)
+        jobs.extend(_chunk_jobs(name, max(1, int(per_sf * sf)), parts))
+    os.makedirs(os.path.join(out_dir, "lineitem"), exist_ok=True)
+    if workers <= 1 or len(jobs) == 1:
+        for job in jobs:
+            _write_chunk(out_dir, sf, seed, job)
+    else:
+        import multiprocessing
+        from concurrent.futures import ProcessPoolExecutor
 
-    # orders + lineitem ride the same chunk (lineitem rows derive from the
-    # chunk's orders)
-    li_dir = os.path.join(out_dir, "lineitem")
-    os.makedirs(li_dir, exist_ok=True)
-
-    def orders_chunk(r, lo, n, d, k, ss):
-        o = gen_orders(sf, r, lo, n)
-        _write_split(o, d, k, ss)
-        _write_split(gen_lineitem(sf, r, o), li_dir, k, ss)
-
-    _chunked_write(
-        out_dir, "orders", max(1, int(1_500_000 * sf)), parts, seed, orders_chunk
-    )
+        with ProcessPoolExecutor(
+            max_workers=min(workers, len(jobs)),
+            mp_context=multiprocessing.get_context("spawn"),
+        ) as pool:
+            futures = [
+                pool.submit(_write_chunk, out_dir, sf, seed, job)
+                for job in jobs
+            ]
+            for f in futures:
+                f.result()
     # completeness marker: generation streams for hours at SF=100; consumers
     # (bench.py ensure_data) must not mistake an interrupted run for a dataset
     with open(os.path.join(out_dir, "_SUCCESS"), "w") as f:
@@ -416,6 +441,7 @@ if __name__ == "__main__":
     ap.add_argument("--out", required=True)
     ap.add_argument("--parts", type=int, default=2)
     ap.add_argument("--seed", type=int, default=20260728)
+    ap.add_argument("--workers", type=int, default=1)
     a = ap.parse_args()
-    generate(a.out, a.sf, a.parts, a.seed)
+    generate(a.out, a.sf, a.parts, a.seed, a.workers)
     print(f"TPC-H sf={a.sf} written to {a.out}")

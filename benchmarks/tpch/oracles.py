@@ -500,3 +500,69 @@ def q22(t: Dict[str, pd.DataFrame]) -> pd.DataFrame:
 
 
 ORACLES = {f"q{i}": globals()[f"q{i}"] for i in range(1, 23)}
+
+
+# -- running an oracle on a data set on disk --------------------------------
+# The served-path smoke (chip_smoke.py) checks every answer at benchmark
+# scale, where handing an oracle all eight tables whole does not fit a host:
+# each oracle gets only the columns it reads.
+
+
+def oracle_columns(name: str) -> Dict[str, list]:
+    """table -> the columns `ORACLES[name]` reads, found by matching the
+    function's source against the TPC-H schema. Every column of a TPC-H
+    table carries its table's prefix, so a name cannot be mistaken for
+    another table's."""
+    import inspect
+    import re
+
+    from benchmarks.tpch.schema import TPCH_TABLES, get_tpch_schema
+
+    words = set(re.findall(r"\b[a-z]{1,2}_[a-z]+\b", inspect.getsource(ORACLES[name])))
+    out = {}
+    for table in TPCH_TABLES:
+        cols = [c for c in get_tpch_schema(table).names if c in words]
+        if cols:
+            out[table] = cols
+    return out
+
+
+def run_on_dir(name: str, data_dir: str) -> pd.DataFrame:
+    """`ORACLES[name]` over the parquet data set at `data_dir` (the layout
+    datagen.generate writes). Top-level and of plain arguments so a worker
+    process can run it; touches pandas and pyarrow only."""
+    import os
+
+    import pyarrow.parquet as pq
+
+    tables = {
+        table: pq.read_table(os.path.join(data_dir, table), columns=cols).to_pandas()
+        for table, cols in oracle_columns(name).items()
+    }
+    return ORACLES[name](tables)
+
+
+def compare_frames(got: pd.DataFrame, want: pd.DataFrame, rtol: float) -> Dict[str, object]:
+    """Hold an engine's answer to the oracle's. Non-float columns must be
+    equal; float columns must agree within `rtol` (relative, and absolute
+    for values near zero). Raises AssertionError on any mismatch, else
+    returns {"exact": [columns equal bit for bit], "max_rel_err": the
+    largest relative error among the others (0.0 when all were exact)}."""
+    assert len(got) == len(want), f"row count {len(got)} != {len(want)}"
+    assert list(got.columns) == list(want.columns), (
+        list(got.columns), list(want.columns))
+    exact, worst = [], 0.0
+    for c in want.columns:
+        g, w = got[c].to_numpy(), want[c].to_numpy()
+        if not np.issubdtype(w.dtype, np.floating):
+            assert list(g) == list(w), f"column {c}: {g[:5]} != {w[:5]}"
+            exact.append(c)
+            continue
+        g, w = g.astype(float), w.astype(float)
+        if np.array_equal(g, w, equal_nan=True):
+            exact.append(c)
+            continue
+        np.testing.assert_allclose(g, w, rtol=rtol, atol=rtol, err_msg=f"column {c}")
+        denom = np.maximum(np.abs(w), 1e-300)
+        worst = max(worst, float(np.nanmax(np.abs(g - w) / denom)))
+    return {"exact": exact, "max_rel_err": worst}

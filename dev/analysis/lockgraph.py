@@ -37,10 +37,7 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Set, Tuple
 
-try:  # py3.11+
-    import tomllib as _toml
-except ImportError:  # pragma: no cover - py3.10 fallback (PR 2 idiom)
-    import tomli as _toml  # type: ignore
+import tomllib as _toml
 
 MANIFEST_BASENAME = "lockorder.toml"
 

@@ -45,10 +45,7 @@ import ast
 import re
 from typing import Dict, List, Optional, Set, Tuple
 
-try:  # py3.11+
-    import tomllib as _toml
-except ImportError:  # pragma: no cover - py3.10 fallback (PR 2 idiom)
-    import tomli as _toml  # type: ignore
+import tomllib as _toml
 
 from dev.analysis.common import dotted, final_name, iter_functions, \
     walk_no_nested_defs

@@ -10,10 +10,7 @@ import pathlib
 import subprocess
 import sys
 
-try:  # py3.11+
-    import tomllib as _toml
-except ImportError:  # pragma: no cover - py3.10 fallback
-    import tomli as _toml  # type: ignore
+import tomllib as _toml
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures" / "lint"

@@ -17,10 +17,7 @@ import random
 import pyarrow as pa
 import pytest
 
-try:  # py3.11+
-    import tomllib as _toml
-except ImportError:  # pragma: no cover - py3.10 fallback
-    import tomli as _toml  # type: ignore
+import tomllib as _toml
 
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend

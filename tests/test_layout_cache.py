@@ -284,8 +284,8 @@ def test_disk_hit_pins_into_device_cache(tmp_path):
 
 def test_batches_path_warm_start(tmp_path, monkeypatch):
     """Low-cardinality stages (the unrolled batches path — q1/q6 shapes)
-    persist too: at SF=100 their full-scan decode is ~400 s per fresh
-    process, which would eat a relay capture window."""
+    persist too: at SF=100 their full-scan decode is minutes of host time
+    per fresh process."""
     rng = np.random.default_rng(4)
     n = 80_000
     table = pa.table(

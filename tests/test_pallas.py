@@ -1,5 +1,7 @@
-"""Pallas kernel tests (interpret mode — semantics identical on TPU;
-real-chip correctness is exercised by the bench/verify flow)."""
+"""Pallas kernel tests, in interpret mode. The interpreter multiplies in f32
+where the MXU rounds to bf16 at default precision, so it cannot vouch for the
+compiled kernels: dev/probe_pallas.py runs them compiled on the chip against
+numpy at 6M rows."""
 
 import numpy as np
 import pytest

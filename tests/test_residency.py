@@ -148,7 +148,7 @@ def test_eviction_preserves_running_consumers():
 
 
 def test_two_real_stages_under_pressure_reach_steady_state(tmp_path):
-    """VERDICT r3 #7: two real parquet-backed sorted stages alternating
+    """Two real parquet-backed sorted stages alternating
     under a budget that fits either but not both. The thrash guards must
     converge: after one thrash cycle the cooldown pins a survivor and the
     other stage streams — NOT the A,B,A,B full re-prepare ping-pong plain

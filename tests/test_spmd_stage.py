@@ -394,53 +394,79 @@ def test_mesh_join_readback_recorded():
     assert out.num_rows == ora.num_rows
 
 
-def test_mesh_failure_falls_back_and_is_surfaced(monkeypatch, caplog):
-    """A broken mesh path must not be invisible: the host fallback still
-    returns correct rows, the tracing counter increments, and a warning
-    with the stage fingerprint is logged once."""
-    import logging
-
-    from ballista_tpu.physical.plan import TaskContext
-    from ballista_tpu.utils import tracing
-
+def _sales_spmd_exec(cfg):
     table = _sales(n=800, seed=9)
-    cfg = BallistaConfig(SPMD_SETTINGS)
     ctx = ExecutionContext(cfg)
     ctx.register_record_batches("t", table, n_partitions=3)
     df = ctx.table("t").aggregate(
         [col("region")], [F.sum(col("amount")).alias("s")]
     )
     phys = ctx.create_physical_plan(df.logical_plan())
-    spmd = _find_spmd(DistributedPlanner(cfg).plan_query_stages("job", phys))
+    return table, _find_spmd(
+        DistributedPlanner(cfg).plan_query_stages("job", phys)
+    )
+
+
+def test_mesh_error_fails_the_task(monkeypatch):
+    """Only a reasoned decline goes to the host. Any other error inside the
+    mesh program (an XLA compile error, an exhausted device, a sharding
+    error) fails the task: on the chip a host answer would hide it."""
+    from ballista_tpu.physical.plan import TaskContext
+    from ballista_tpu.utils import tracing
+
+    cfg = BallistaConfig(SPMD_SETTINGS)
+    _table, spmd = _sales_spmd_exec(cfg)
 
     def boom(ctx):
         raise RuntimeError("injected mesh failure")
 
     monkeypatch.setattr(spmd, "_execute_mesh", boom)
-    SpmdAggregateExec._warned_fingerprints.clear()
     tracing.reset()
     tctx = TaskContext(config=cfg, work_dir="/tmp", job_id="t")
-    with caplog.at_level(logging.WARNING, logger="ballista.spmd"):
-        out = pa.Table.from_batches(list(spmd.execute(0, tctx)))
+    with pytest.raises(RuntimeError, match="injected mesh failure"):
+        list(spmd.execute(0, tctx))
+    c = tracing.counters()
+    assert c.get("spmd.host_fallback") is None
+    assert c.get("spmd.mesh") is None
+
+
+def test_mesh_decline_runs_host_subplan_and_is_counted(monkeypatch):
+    from ballista_tpu.ops.runtime import UnsupportedOnDevice
+    from ballista_tpu.physical.plan import TaskContext
+    from ballista_tpu.utils import tracing
+
+    cfg = BallistaConfig(SPMD_SETTINGS)
+    table, spmd = _sales_spmd_exec(cfg)
+
+    def decline(ctx):
+        raise UnsupportedOnDevice("injected decline")
+
+    monkeypatch.setattr(spmd, "_execute_mesh", decline)
+    tracing.reset()
+    tctx = TaskContext(config=cfg, work_dir="/tmp", job_id="t")
+    out = pa.Table.from_batches(list(spmd.execute(0, tctx)))
     assert spmd.last_path == "host"
     c = tracing.counters()
     assert c.get("spmd.host_fallback") == 1
-    assert c.get("spmd.host_fallback_error") == 1
     assert c.get("spmd.mesh") is None
-    assert any("injected mesh failure" in r.message and spmd.fingerprint()
-               in r.message for r in caplog.records)
     ora = table.group_by("region").aggregate([("amount", "sum")]).sort_by("region")
     got = out.sort_by("region")
     np.testing.assert_allclose(
         got.column("s").to_numpy(), ora.column("amount_sum").to_numpy(),
         rtol=1e-4,
     )
-    # a second failure on the same stage does not re-warn
-    caplog.clear()
-    with caplog.at_level(logging.WARNING, logger="ballista.spmd"):
+
+
+def test_mesh_larger_than_device_count_is_an_error():
+    """The mesh is never shrunk to fit: asking for more devices than the
+    process has fails the task (the suite runs on 8 virtual devices)."""
+    from ballista_tpu.physical.plan import TaskContext
+
+    cfg = BallistaConfig({**SPMD_SETTINGS, "ballista.tpu.mesh": "data:16"})
+    _table, spmd = _sales_spmd_exec(cfg)
+    tctx = TaskContext(config=cfg, work_dir="/tmp", job_id="t")
+    with pytest.raises(ValueError, match="needs 16 devices"):
         list(spmd.execute(0, tctx))
-    assert not caplog.records
-    assert tracing.counters().get("spmd.host_fallback") == 2
 
 
 def test_distributed_spmd_end_to_end(sales_table):

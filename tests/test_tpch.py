@@ -65,16 +65,7 @@ _rtol = 1e-9
 
 
 def assert_frames_close(got: pd.DataFrame, want: pd.DataFrame):
-    assert len(got) == len(want), f"row count {len(got)} != {len(want)}"
-    assert list(got.columns) == list(want.columns), (got.columns, want.columns)
-    for c in want.columns:
-        g, w = got[c].to_numpy(), want[c].to_numpy()
-        if np.issubdtype(w.dtype, np.floating):
-            np.testing.assert_allclose(
-                g.astype(float), w.astype(float), rtol=_rtol, atol=_rtol
-            )
-        else:
-            assert list(g) == list(w), f"column {c}: {g[:5]} != {w[:5]}"
+    oracles.compare_frames(got, want, _rtol)
 
 
 def assert_scalar_close(got: pd.DataFrame, want: pd.DataFrame):
