@@ -30,6 +30,7 @@ from ballista_tpu.logical.builder import LogicalPlanBuilder
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.rpc import SchedulerGrpcClient
 from ballista_tpu.serde.logical import plan_to_proto
+from ballista_tpu.utils import tracing
 
 POLL_INTERVAL = 0.1  # ref context.rs:195
 # status polls start here and double toward POLL_INTERVAL (ISSUE 8): a
@@ -138,6 +139,7 @@ class _JobStatusSource:
         )
         self._interval = POLL_INTERVAL_MIN
         self._polled = False
+        self.via = "poll"  # how the last status came: "push" or "poll"
 
     def next(self, deadline: float) -> pb.JobStatus:
         """The next JobStatus before `deadline` — pushed when the stream
@@ -148,6 +150,7 @@ class _JobStatusSource:
                 min(POLL_INTERVAL, max(0.0, deadline - time.time()))
             )
             if status is not None:
+                self.via = "push"
                 return status
         elif self._polled:
             # pure-poll pacing (push disabled or stream down) between
@@ -156,6 +159,7 @@ class _JobStatusSource:
             time.sleep(self._interval)
             self._interval = min(self._interval * 2, POLL_INTERVAL)
         self._polled = True
+        self.via = "poll"
         res = self._client.get_job_status(
             pb.GetJobStatusParams(job_id=self._job_id)
         )
@@ -225,7 +229,12 @@ class BallistaContext(ExecutionContext):
 
     # -- execution ---------------------------------------------------------
     def collect(self, plan: lp.LogicalPlan, timeout: float = 300.0) -> pa.Table:
-        job_id = self.submit(plan)
+        # the request's root span: its job is known once submit returns
+        with tracing.span("client.collect") as root:
+            return self._collect(plan, timeout, root)
+
+    def _collect(self, plan: lp.LogicalPlan, timeout: float, root) -> pa.Table:
+        job_id = root.job = self.submit(plan)
         try:
             return self._collect_results(job_id, plan.schema(), timeout)
         except _CachedResultLost:
@@ -236,7 +245,7 @@ class BallistaContext(ExecutionContext):
             from ballista_tpu.ops.runtime import record_tenancy
 
             record_tenancy("cache_lost_resubmitted")
-            job_id = self.submit(plan)
+            job_id = root.job = self.submit(plan)
             try:
                 return self._collect_results(job_id, plan.schema(), timeout)
             except _CachedResultLost as e:
@@ -253,17 +262,19 @@ class BallistaContext(ExecutionContext):
     def submit(self, plan: lp.LogicalPlan) -> str:
         """ExecuteQuery only: returns the job id without waiting for (or
         fetching) results — collect() is submit + _collect_results."""
-        params = pb.ExecuteQueryParams()
-        params.logical_plan.CopyFrom(plan_to_proto(plan))
-        # only non-default settings travel: they override scheduler/executor
-        # configs per job without clobbering host-local tuning
-        for k, v in self.config.explicit_settings().items():
-            params.settings.add(key=k, value=v)
-        # tenancy rides first-class fields too (ISSUE 7): admission control
-        # must not depend on parsing the settings map
-        params.tenant = self.config.tenant()
-        params.priority = self.config.tenant_priority()
-        return self._client.execute_query(params).job_id
+        with tracing.span("client.submit") as sp:
+            params = pb.ExecuteQueryParams()
+            params.logical_plan.CopyFrom(plan_to_proto(plan))
+            # only non-default settings travel: they override scheduler/
+            # executor configs per job without clobbering host-local tuning
+            for k, v in self.config.explicit_settings().items():
+                params.settings.add(key=k, value=v)
+            # tenancy rides first-class fields too (ISSUE 7): admission
+            # control must not depend on parsing the settings map
+            params.tenant = self.config.tenant()
+            params.priority = self.config.tenant_priority()
+            sp.job = self._client.execute_query(params).job_id
+        return sp.job
 
     def collect_stream(self, plan: lp.LogicalPlan, timeout: float = 300.0):
         """Streaming collect (ISSUE 8): yield result RecordBatches in
@@ -530,10 +541,17 @@ class BallistaContext(ExecutionContext):
                 ) as r:
                     return r.read_all().cast(schema)
             try:
-                tables = [
-                    self._fetch_partition(loc)
-                    for loc in status.completed.partition_location
-                ]
+                with tracing.span("client.fetch", job=job_id) as sp:
+                    tables = [
+                        self._fetch_partition(loc)
+                        for loc in status.completed.partition_location
+                    ]
+                    table = (
+                        pa.concat_tables(tables).cast(schema)
+                        if tables else schema.empty_table()
+                    )
+                    sp.set(bytes=table.get_total_buffer_size(), partitions=len(tables))
+                return table
             except ShuffleFetchError as e:
                 cached = status.completed.cached
                 result = self._client.report_lost_partition(
@@ -556,10 +574,6 @@ class BallistaContext(ExecutionContext):
                 from ballista_tpu.ops.runtime import record_recovery
 
                 record_recovery("result_fetch_restarted")
-                continue
-            if not tables:
-                return schema.empty_table()
-            return pa.concat_tables(tables).cast(schema)
 
     def _wait_for_job(self, job_id: str, timeout: float) -> pb.JobStatus:
         """Wait for a terminal status — via the SubscribeJobStatus push
@@ -567,20 +581,23 @@ class BallistaContext(ExecutionContext):
         scheduler writes it, no polling floor), with the adaptive poll as
         the automatic fallback whenever the stream is down or refused."""
         deadline = time.time() + timeout
-        source = _JobStatusSource(self._client, self.config, job_id)
-        try:
-            while time.time() < deadline:
-                status = source.next(deadline)
-                which = status.WhichOneof("status")
-                if which == "completed":
-                    return status
-                if which == "failed":
-                    raise ExecutionError(
-                        f"job {job_id} failed: {status.failed.error}"
-                    )
-            raise ExecutionError(f"job {job_id} timed out after {timeout}s")
-        finally:
-            source.close()
+        with tracing.span("client.wait", job=job_id) as sp:
+            source = _JobStatusSource(self._client, self.config, job_id)
+            try:
+                while time.time() < deadline:
+                    status = source.next(deadline)
+                    which = status.WhichOneof("status")
+                    if which == "completed":
+                        # how the terminal status came: the push stream, or a poll
+                        sp.set(via=source.via)
+                        return status
+                    if which == "failed":
+                        raise ExecutionError(
+                            f"job {job_id} failed: {status.failed.error}"
+                        )
+                raise ExecutionError(f"job {job_id} timed out after {timeout}s")
+            finally:
+                source.close()
 
     def _fetch_partition(self, loc: pb.PartitionLocation) -> pa.Table:
         from ballista_tpu.client.flight import BallistaClient

@@ -40,6 +40,7 @@ from ballista_tpu.physical.plan import (
 )
 from ballista_tpu.physical.repartition import hash_rows
 from ballista_tpu.physical.expr import _as_array
+from ballista_tpu.utils import tracing
 
 
 class PartitionStats:
@@ -264,6 +265,15 @@ class ShuffleWriterExec(ExecutionPlan):
         aggregate stats. Piece paths: {base}/{m}.arrow with {base} from
         shuffle_output_base — the executor work dir (local tier) or the
         shared storage dir (shared tier, same atomic publish)."""
+        # self time is the partitioning and the IPC write: the input plan's
+        # execution shows as the spans it opens beneath this one
+        with tracing.span("shuffle.write", job=self.job_id, stage=self.stage_id,
+                          partition=partition) as sp:
+            stats = self._shuffle_write(partition, ctx)
+            sp.set(bytes=stats.num_bytes, rows=stats.num_rows)
+        return stats
+
+    def _shuffle_write(self, partition: int, ctx: TaskContext) -> PartitionStats:
         from ballista_tpu.ops.runtime import record_shuffle_tier
 
         base, storage_uri = shuffle_output_base(
@@ -455,14 +465,15 @@ class ShuffleReaderExec(ExecutionPlan):
         return Partitioning.unknown(self.num_partitions)
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        task = tracing.current()  # the fetches below may run on other threads
         if self.identity:
             loc = self.locations[partition]
-            yield from self._read_piece(loc, 0, ctx)
+            yield from self._fetch_streamed(loc, 0, ctx, task)
             return
         workers = ctx.config.tpu_ingest_workers()
         if workers <= 0 or len(self.locations) <= 1:
             for loc in self.locations:
-                yield from self._read_piece(loc, partition, ctx)
+                yield from self._fetch_streamed(loc, partition, ctx, task)
             return
         # per-location fetches are independent (local disk read or a Flight
         # round-trip to the owning executor, each with its own client):
@@ -477,15 +488,48 @@ class ShuffleReaderExec(ExecutionPlan):
         from ballista_tpu.ops.runtime import ordered_map
 
         def fetch(loc: ShuffleLocation) -> List[pa.RecordBatch]:
-            return list(self._read_piece(loc, partition, ctx))
+            # one map output read whole on a pool thread: a block, so a span
+            via: List[str] = []
+            with tracing.span("shuffle.fetch", parent=task, **self._fetch_ids(loc, ctx)) as sp:
+                batches = list(self._read_piece(loc, partition, ctx, via))
+                # not `nbytes`: it walks every buffer for its sliced extent,
+                # 21 us a batch of six columns against 0.3
+                sp.set(bytes=sum(b.get_total_buffer_size() for b in batches),
+                       via=via[-1] if via else "none")
+            return batches
 
         for piece_batches in ordered_map(
             fetch, self.locations, workers, ctx.config.tpu_ingest_depth()
         ):
             yield from piece_batches
 
+    @staticmethod
+    def _fetch_ids(loc: ShuffleLocation, ctx: TaskContext) -> dict:
+        return {"job": ctx.job_id or None, "map_stage": loc.stage_id,
+                "map_partition": loc.map_partition}
+
+    def _fetch_streamed(
+        self, loc: ShuffleLocation, piece_idx: int, ctx: TaskContext, task
+    ) -> Iterator[pa.RecordBatch]:
+        """One map output streamed to its consumer, recorded as one
+        `shuffle.fetch` where it lay: from the first pull to the last. The
+        consumer's own work between two pulls is inside it (`streamed`
+        says so); a span held open across the yields would adopt the
+        consumer's spans, so the interval is recorded at its end. A stream
+        that its consumer abandons records nothing."""
+        via: List[str] = []
+        start = tracing.now_ns()
+        nbytes = 0
+        for batch in self._read_piece(loc, piece_idx, ctx, via):
+            nbytes += batch.get_total_buffer_size()
+            yield batch
+        tracing.record("shuffle.fetch", start, tracing.now_ns(), parent=task,
+                       bytes=nbytes, via=via[-1] if via else "none", streamed=True,
+                       **self._fetch_ids(loc, ctx))
+
     def _read_piece(
-        self, loc: ShuffleLocation, piece_idx: int, ctx: TaskContext
+        self, loc: ShuffleLocation, piece_idx: int, ctx: TaskContext,
+        via: List[str],
     ) -> Iterator[pa.RecordBatch]:
         from ballista_tpu.errors import RpcError, ShuffleFetchError
         from ballista_tpu.utils.chaos import ChaosInjected, chaos_from_config
@@ -555,6 +599,7 @@ class ShuffleReaderExec(ExecutionPlan):
                 batches, nbytes = hit
                 record_exchange("reupload_skipped")
                 record_exchange("h2d_bytes_saved", nbytes)
+                via.append("resident")
                 yield from batches
                 return
             record_exchange("miss")
@@ -584,6 +629,7 @@ class ShuffleReaderExec(ExecutionPlan):
                 resolved = self._storage_read_path(piece, ctx)
                 if resolved is not None and os.path.exists(resolved):
                     record_shuffle_tier("storage_fetch")
+                    via.append("storage")
                     yield from read_ipc_file(resolved)
                     return
             record_shuffle_tier("storage_fallback_peer")
@@ -603,11 +649,13 @@ class ShuffleReaderExec(ExecutionPlan):
                 )
         resolved = self._local_read_path(piece, ctx)
         if resolved is not None and os.path.exists(resolved):
+            via.append("local")
             yield from read_ipc_file(resolved)
         elif ctx.shuffle_fetcher is not None:
             from ballista_tpu.ops.runtime import record_shuffle_tier
 
             record_shuffle_tier("peer_fetch")
+            via.append("flight")
             try:
                 yield from ctx.shuffle_fetcher(loc, piece_idx)
             except ShuffleFetchError:

@@ -104,10 +104,10 @@ class ExecutionContext:
     def collect(self, plan: lp.LogicalPlan) -> pa.Table:
         from ballista_tpu.utils.tracing import span
 
-        with span("plan"):
+        with span("engine.plan"):
             physical = self.create_physical_plan(plan)
         ctx = TaskContext(config=self.config)
-        with span("execute"):
+        with span("engine.execute"):
             return collect_all(physical, ctx)
 
 

@@ -41,11 +41,19 @@ from ballista_tpu.executor.flight_service import flight_shuffle_fetcher
 from ballista_tpu.physical.plan import TaskContext
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.rpc import SchedulerGrpcClient
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 log = logging.getLogger("ballista.executor")
 
 POLL_INTERVAL_SECS = 0.25  # ref execution_loop.rs:75
+
+
+def _ids(task: pb.TaskDefinition) -> dict:
+    """The identifiers every span of a task carries."""
+    pid = task.task_id
+    return {"job": pid.job_id, "stage": pid.stage_id,
+            "partition": pid.partition_id}
 
 
 class PollLoop:
@@ -102,6 +110,10 @@ class PollLoop:
         # flight: a failed delivery requeues them, so drain() must not
         # declare the executor empty while any are outstanding
         self._delivering = 0  # guarded-by: self._inflight_mu
+        # id(status) -> when report() queued it: `executor.report` is the
+        # status's wait on _finished until a poll takes it to the scheduler
+        # (one set and one pop a status: dict ops, atomic under the GIL)
+        self._reported_ns: dict = {}
         # -- push dispatch (ISSUE 8) ------------------------------------
         self._push_enabled = self.config.push_dispatch()
         self._idle_poll_max = self.config.idle_poll_max_s()
@@ -316,7 +328,15 @@ class PollLoop:
                 except queue.Empty:
                     break
             self._delivering += len(out)
-            return out
+        now = tracing.now_ns()
+        for st in out:
+            queued = self._reported_ns.pop(id(st), None)
+            if queued is not None:
+                pid = st.partition_id
+                tracing.record(
+                    "executor.report", queued, now, job=pid.job_id,
+                    stage=pid.stage_id, partition=pid.partition_id)
+        return out
 
     def poll_once(self) -> bool:
         """One PollWork round; returns True if a task was received.
@@ -386,7 +406,7 @@ class PollLoop:
             # waiting cannot stall heartbeats
             threading.Thread(
                 target=self._run_task,
-                args=(result.task, slot_held),
+                args=(result.task, slot_held, tracing.now_ns()),
                 daemon=True,
             ).start()
             return True
@@ -461,7 +481,8 @@ class PollLoop:
         self._register_inflight(task)
         record_serving("task_pushed")
         threading.Thread(
-            target=self._run_task, args=(task, False), daemon=True
+            target=self._run_task, args=(task, False, tracing.now_ns()),
+            daemon=True,
         ).start()
 
     def _member_setup(self, task: pb.TaskDefinition):
@@ -642,7 +663,8 @@ class PollLoop:
             status.failed.error = f"{type(e).__name__}: {e}"
             status.failed.executor_id = self.metadata.id
 
-    def _run_task(self, task: pb.TaskDefinition, slot_held: bool = True) -> None:
+    def _run_task(self, task: pb.TaskDefinition, slot_held: bool = True,
+                  received_ns: Optional[int] = None) -> None:
         """Run one TaskDefinition — or a shared-scan batch group (ISSUE 13:
         the primary plus task.siblings) under ONE task slot. Each member
         gets its own status; a member failing at any point (setup, chaos,
@@ -651,6 +673,10 @@ class PollLoop:
         shared upload before the members' plans execute."""
         if not slot_held:
             self._available.acquire()
+        if received_ns is not None:
+            # thread start and the wait for a slot of _available
+            tracing.record("executor.receive", received_ns, tracing.now_ns(),
+                           **_ids(task))
         members = [task] + list(task.siblings)
         prepped = []
         reported = 0
@@ -663,6 +689,7 @@ class PollLoop:
             # completion must not wait out member 8's execution — and the
             # wake kicks the poll loop out of any decayed idle wait so no
             # status rides a multi-second heartbeat.
+            self._reported_ns[id(status)] = tracing.now_ns()
             self._finished.put(status)
             pid = td.task_id
             with self._inflight_mu:
@@ -672,32 +699,35 @@ class PollLoop:
             self._wake.set()
 
         try:
-            for td in members:
-                prepped.append(self._member_setup(td))
-            shared = None
-            if len(members) > 1:
-                from ballista_tpu.ops import sharedscan
+            with tracing.span("executor.task", members=len(members), **_ids(task)):
+                for td in members:
+                    with tracing.span("executor.setup", **_ids(td)):
+                        prepped.append(self._member_setup(td))
+                shared = None
+                if len(members) > 1:
+                    from ballista_tpu.ops import sharedscan
 
-                try:
-                    shared = sharedscan.precompute(
-                        [
-                            (plan, td.task_id.partition_id, ctx)
-                            for td, _st, plan, ctx in prepped
-                            if plan is not None
-                        ],
-                        max_batch=len(members),
-                    )
-                except Exception:
-                    # the precompute is an accelerator: any failure means
-                    # every member simply executes solo below
-                    log.warning("shared-scan precompute failed; members "
-                                "run solo", exc_info=True)
-                    shared = None
-            for td, status, plan, ctx in prepped:
-                if plan is not None:
-                    self._member_execute(td, status, plan, ctx, shared)
-                report(td, status)
-                reported += 1
+                    try:
+                        shared = sharedscan.precompute(
+                            [
+                                (plan, td.task_id.partition_id, ctx)
+                                for td, _st, plan, ctx in prepped
+                                if plan is not None
+                            ],
+                            max_batch=len(members),
+                        )
+                    except Exception:
+                        # the precompute is an accelerator: any failure means
+                        # every member simply executes solo below
+                        log.warning("shared-scan precompute failed; members "
+                                    "run solo", exc_info=True)
+                        shared = None
+                for td, status, plan, ctx in prepped:
+                    if plan is not None:
+                        with tracing.span("executor.execute", **_ids(td)):
+                            self._member_execute(td, status, plan, ctx, shared)
+                    report(td, status)
+                    reported += 1
         finally:
             self._available.release()
             # safety net: members never reached (an unexpected raise mid-

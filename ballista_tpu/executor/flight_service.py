@@ -25,6 +25,7 @@ from ballista_tpu.config import BallistaConfig
 from ballista_tpu.distributed.stages import ShuffleLocation
 from ballista_tpu.physical.plan import TaskContext
 from ballista_tpu.proto import ballista_pb2 as pb
+from ballista_tpu.utils import tracing
 
 log = logging.getLogger("ballista.executor.flight")
 
@@ -43,6 +44,17 @@ class BallistaFlightService(flight.FlightServerBase):
     def do_get(self, context, ticket: flight.Ticket) -> flight.RecordBatchStream:
         action = pb.Action()
         action.ParseFromString(ticket.ticket)
+        # a piece lies at <root>/<job>/<stage>/<partition>/<m>.arrow
+        at = action.fetch_partition.path.split(os.sep)[-4:-3]
+        job = action.execute_partition.job_id or (at[0] if at else None)
+        # the handler alone: ticket, confinement, opening the piece (or the
+        # partition's execution). Arrow's C++ server streams the batches
+        # after this returns, where Python sees nothing; the whole transfer
+        # is the client's `shuffle.fetch` / `client.fetch`
+        with tracing.span("flight.do_get", job=job):
+            return self._serve(action)
+
+    def _serve(self, action: pb.Action) -> flight.RecordBatchStream:
         which = action.WhichOneof("action_type")
         if which == "fetch_partition":
             path = self._resolve_work_path(action.fetch_partition.path)

@@ -225,14 +225,29 @@ def _read_artifact(base: str, key: str) -> Optional[bytes]:
         return None
 
 
-def _compile_exported(blob: bytes, leaves_avals):
+def _compile_exported(blob: bytes, leaves_avals, name: str = "call"):
     """Deserialize an exported program and AOT-compile it for the flat
     calling convention. Raises on any mismatch (caller falls back)."""
     import jax
     from jax import export as jax_export
 
     exported = jax_export.deserialize(bytearray(blob))
-    return jax.jit(exported.call).lower(*leaves_avals).compile()
+    return jax.jit(_named(name, exported.call)).lower(*leaves_avals).compile()
+
+
+def _named(name: str, fn):
+    """`fn` under the step's name: the XLA module is `jit_<name>` and every
+    op's metadata carries the scope, so a device trace says which program an
+    operation belongs to. The name is the step's own, the same in every
+    process (it is part of what the persistent compile cache keys on)."""
+    import jax
+
+    def step(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    step.__name__ = step.__qualname__ = name
+    return step
 
 
 def _leaf_aval(leaf):
@@ -267,6 +282,9 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
     import jax
     from jax.tree_util import tree_flatten, tree_unflatten
 
+    from ballista_tpu.utils import tracing
+
+    core = _named(name, core)
     jitfn = jax.jit(core, static_argnums=static_argnums)
     static_set = frozenset(static_argnums)
 
@@ -325,6 +343,11 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             log.debug("aot export failed (key=%s...): %s", key[:16], e)
 
     def wrapped(*args):
+        # the host's side of one dispatch; the device runs on after it closes
+        with tracing.span("runtime.launch", program=name) as launch:
+            return dispatch(launch, args)
+
+    def dispatch(launch, args):
         resolved = signature(args)
         if resolved is None:
             return jitfn(*args)
@@ -335,6 +358,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
         if entry is not None:
             kind, compiled = entry
             _record("compile_hit_memory")
+            launch.set(tier="memory")
             if compiled is None:  # freshly traced this process: jit caches
                 return jitfn(*args)
             out_flat = compiled(*leaves)
@@ -342,7 +366,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
         blob = _read_artifact(base, key)
         if blob is not None:
             try:
-                compiled = _compile_exported(blob, avals)
+                compiled = _compile_exported(blob, avals, name)
                 out_flat = compiled(*leaves)
             except Exception as e:
                 _record("aot_load_error")
@@ -354,6 +378,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
                 with _lock:
                     _mem[key] = ("disk", compiled)
                 _record("compile_hit_disk")
+                launch.set(tier="disk")
                 return out_flat
         # fresh program: run the PLAIN jit first (its persistent-XLA-cache
         # key matches every compile this codebase ever did, so warm
@@ -362,6 +387,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
         # persistent XLA cache differently and recompile from scratch
         # (measured ~15s per big program, a whole-suite stall).
         _record("compile_trace")
+        launch.set(tier="trace")
         out = jitfn(*args)
         with _lock:
             _mem.setdefault(key, ("fresh", None))
@@ -386,7 +412,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
         blob = _read_artifact(base, key)
         if blob is not None:
             try:
-                compiled = _compile_exported(blob, avals)
+                compiled = _compile_exported(blob, avals, name)
             except Exception as e:
                 _record("aot_load_error")
                 log.warning(

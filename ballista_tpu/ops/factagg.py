@@ -55,6 +55,7 @@ import pyarrow.compute as pc
 from ballista_tpu.ops.runtime import (
     UnsupportedOnDevice,
     bucket_rows,
+    copy_out,
     pad_to,
     record_readback,
     widen_cols,
@@ -69,6 +70,7 @@ from ballista_tpu.ops.stage import (
     substitute_columns,
 )
 from ballista_tpu.physical import expr as px
+from ballista_tpu.utils import tracing
 from ballista_tpu.physical.basic import (
     CoalesceBatchesExec,
     FilterExec,
@@ -664,7 +666,7 @@ class FactAggregateStage:
         if self._sec_step is None:
             self._sec_step = self._build_sec_step()
         aux = [jnp.asarray(a) for a in self.inner.compiler.build_aux()]
-        packed = np.asarray(
+        packed = copy_out(
             self._sec_step(
                 ent["layout"].L1, ent["cols"], aux, ent["clen"],
                 ent["derived"]["sec_attr"],
@@ -672,6 +674,10 @@ class FactAggregateStage:
             )
         )
         record_readback(packed.shape[-1], packed.nbytes)
+        with tracing.span("runtime.to_arrow", engine="factagg_sec"):
+            return self._secondary_to_table(packed, info, sec, GA)
+
+    def _secondary_to_table(self, packed, info, sec, GA) -> pa.Table:
         rows = self._decode(packed)
         counts = rows[0][:GA]
         keep = counts > 0
@@ -908,7 +914,7 @@ class FactAggregateStage:
             member = np.zeros(G, dtype=bool)
             member[member_ranks] = True
             bits = np.packbits(member, bitorder="little")
-            packed = np.asarray(
+            packed = copy_out(
                 self._fact_step(ent["layout"].L1, ent["cols"], aux,
                                 ent["clen"], jnp.asarray(bits))
             )
@@ -949,7 +955,8 @@ class FactAggregateStage:
             rank_to_dim = np.full(G, -1, dtype=np.int64)
             rank_to_dim[member_ranks] = dim_rows_for_rank
             dim_idx = rank_to_dim[idx]
-            return self._assemble(sel, idx, dim_idx, dim["table"], ent)
+            with tracing.span("runtime.to_arrow", engine="factagg_topk"):
+                return self._assemble(sel, idx, dim_idx, dim["table"], ent)
         positions = member_ranks.astype(np.int64)
         if len(positions) == 0:
             return self.partial_schema.empty_table()
@@ -964,17 +971,20 @@ class FactAggregateStage:
         pos_pad = pad_to(
             positions.astype(np.int32), bucket_rows(n_pos, 16), 0
         )
-        sel = np.asarray(
+        # the span's `bytes` is what crossed, the padded bucket; the counter
+        # below counts the slice that is kept
+        sel = copy_out(
             self._fact_step(ent["layout"].L1, ent["cols"], aux, ent["clen"],
                             jnp.asarray(pos_pad))
         )[:, :n_pos]
         record_readback(sel.shape[-1], sel.nbytes)
-        rows = self._decode(sel)
-        keep = rows[0] > 0
-        return self._assemble_decoded(
-            [r[keep] for r in rows], positions[keep], dim_rows_for_rank[keep],
-            dim["table"], ent,
-        )
+        with tracing.span("runtime.to_arrow", engine="factagg_select"):
+            rows = self._decode(sel)
+            keep = rows[0] > 0
+            return self._assemble_decoded(
+                [r[keep] for r in rows], positions[keep], dim_rows_for_rank[keep],
+                dim["table"], ent,
+            )
 
     def _decode(self, stacked: np.ndarray) -> List[np.ndarray]:
         return [

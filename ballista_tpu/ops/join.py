@@ -64,6 +64,7 @@ from ballista_tpu.ops.runtime import (
     record_routing_event,
     routing_probe,
 )
+from ballista_tpu.utils import tracing
 
 _PAD_CODE = np.int32(2**31 - 1)  # sorts last, never matches a valid probe
 
@@ -372,7 +373,8 @@ def device_join_indices(
     if tier is not None:
         predicted = costmodel.predict("join.gather", probe_slots * tier)
         mat, dt = _run_gather(order, starts, counts, tier, np_)
-        build_idx, probe_idx = _flatten_matched(mat, counts_h, np_)
+        with tracing.span("runtime.to_arrow", engine="join"):
+            build_idx, probe_idx = _flatten_matched(mat, counts_h, np_)
         record_join_path("device")
         record_routing("device", "join", predicted, dt)
         # gross mispredict either way re-tiers the bucket: a first-call

@@ -420,7 +420,12 @@ def hash_aggregate(exec_node, partition: int, ctx) -> Optional[pa.Table]:
         import hashlib
 
         op = "stage.run|" + hashlib.sha1(stable.encode()).hexdigest()[:12]
-        with costmodel.timed(op, units=max(1.0, unit_size), routing_op="stage"):
+        from ballista_tpu.utils import tracing
+
+        # self time: the stage's host work around its programs (a warm
+        # prepare, the dimension side, the rank maps)
+        with costmodel.timed(op, units=max(1.0, unit_size), routing_op="stage"), \
+                tracing.span("runtime.stage", engine=type(stage).__name__):
             out = stage.run(partition, ctx)
         return out
     except UnsupportedOnDevice:

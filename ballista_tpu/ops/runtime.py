@@ -287,6 +287,46 @@ def fetch_arrays(arrs: list) -> list:
     them if fetched array-by-array. Same-shaped outputs are stacked
     on-device (async dispatch, no extra sync) and fetched as one array.
     """
+    from ballista_tpu.utils import tracing
+
+    with tracing.span("runtime.readback", arrays=len(arrs)) as sp:
+        # the programs that produce `arrs` may still run: their wait is the
+        # child, the span's own time is the stacking and the copy
+        with tracing.span("runtime.device_wait"):
+            for a in arrs:
+                if hasattr(a, "block_until_ready"):
+                    a.block_until_ready()
+        out = _fetch_arrays(arrs)
+        sp.set(bytes=sum(int(a.nbytes) for a in out))
+    return out
+
+
+def _synced_copy(x) -> Tuple[np.ndarray, float]:
+    """(x on the host, the copy's seconds) under `runtime.readback`. The
+    producer is synced first, as the child `runtime.device_wait`: the span's
+    own time is the d2h copy alone, and a host blocked on a busy device is
+    not read as a slow copy."""
+    from ballista_tpu.utils import tracing
+
+    with tracing.span("runtime.readback") as sp:
+        if hasattr(x, "block_until_ready"):
+            with tracing.span("runtime.device_wait"):
+                x.block_until_ready()
+        t0 = time.perf_counter()
+        arr = np.asarray(x)
+        copy_s = time.perf_counter() - t0
+        sp.set(bytes=int(arr.nbytes))
+    return arr, copy_s
+
+
+def copy_out(x) -> np.ndarray:
+    """One program's output as numpy, under the spans of `_synced_copy`. It
+    counts nothing: the caller records rows and bytes (`record_readback`)
+    in its own terms, e.g. the slice of a padded bucket that it keeps."""
+    return _synced_copy(x)[0]
+
+
+def _fetch_arrays(arrs: list) -> list:
     global _stack_jit
     if len(arrs) <= 1:
         return [np.asarray(a) for a in arrs]
@@ -712,14 +752,9 @@ def readback(x, rows: Optional[int] = None) -> np.ndarray:
     to still be in flight."""
     from ballista_tpu.ops import costmodel
 
-    t0 = None
-    if costmodel.enabled():
-        if hasattr(x, "block_until_ready"):
-            x.block_until_ready()
-        t0 = time.perf_counter()
-    arr = np.asarray(x)
-    if t0 is not None and arr.nbytes:
-        costmodel.observe("readback", arr.nbytes, time.perf_counter() - t0)
+    arr, copy_s = _synced_copy(x)
+    if costmodel.enabled() and arr.nbytes:
+        costmodel.observe("readback", arr.nbytes, copy_s)
     record_readback(
         rows if rows is not None else (arr.shape[-1] if arr.ndim else 1),
         arr.nbytes,
@@ -1234,6 +1269,13 @@ def upload_array(arr: np.ndarray):
     path's extra device copy and HBM peak are part of the adaptive tier,
     and its observations would be discarded anyway) — keep the plain async
     jnp.asarray dispatch."""
+    from ballista_tpu.utils import tracing
+
+    with tracing.span("runtime.upload", bytes=int(arr.nbytes)):
+        return _upload_array(arr)
+
+
+def _upload_array(arr: np.ndarray):
     import jax.numpy as jnp
 
     from ballista_tpu.ops import costmodel

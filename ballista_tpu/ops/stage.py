@@ -43,6 +43,7 @@ from ballista_tpu.physical.basic import (
     ProjectionExec,
 )
 from ballista_tpu.physical.scan import CsvScanExec, MemoryScanExec, ParquetScanExec
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 _SCAN_TYPES = (CsvScanExec, ParquetScanExec, MemoryScanExec)
@@ -2039,7 +2040,10 @@ class FusedAggregateStage:
         record_readback(
             sum(f.shape[-1] for f in fetched), sum(f.nbytes for f in fetched)
         )
+        with tracing.span("runtime.to_arrow", engine="unrolled"):
+            return self._batches_to_table(fetched, pending)
 
+    def _batches_to_table(self, fetched, pending) -> pa.Table:
         partial_tables: List[pa.Table] = []
         for stacked_np, (_, ent) in zip(fetched, pending):
             rows = self._decode_stacked(stacked_np)
@@ -2105,19 +2109,20 @@ class FusedAggregateStage:
         return outs
 
     def _run_sorted(self, ent: dict, aux) -> pa.Table:
-        from ballista_tpu.ops.runtime import record_readback
+        from ballista_tpu.ops.runtime import copy_out, record_readback
 
         layout = ent["layout"]
-        stacked = np.asarray(
+        stacked = copy_out(
             self._sorted_step(ent["layout"].L1, ent["cols"], aux, ent["clen"])
         )
         record_readback(stacked.shape[-1], stacked.nbytes)
-        rows = self._decode_stacked(stacked)
-        counts = layout.fold_sum(rows[0])
-        outputs = self._fold_state_rows(layout, rows)
-        return self._assemble_partial(
-            outputs, counts, ent["key_values"], ent["n_groups"]
-        )
+        with tracing.span("runtime.to_arrow", engine="sorted"):
+            rows = self._decode_stacked(stacked)
+            counts = layout.fold_sum(rows[0])
+            outputs = self._fold_state_rows(layout, rows)
+            return self._assemble_partial(
+                outputs, counts, ent["key_values"], ent["n_groups"]
+            )
 
     # -- fused Sort+Limit epilogue (planner _topk_pushdown) -------------
     def _topk_eligible(self, ent: dict) -> bool:
@@ -2281,17 +2286,15 @@ class FusedAggregateStage:
         values) when un-fused trailing sort keys exist AND the k-th and
         (k+1)-th groups tie on every fused lane — the only case where the
         device selection could exclude a group the host order admits."""
-        from ballista_tpu.ops.runtime import record_readback
+        from ballista_tpu.ops.runtime import copy_out, record_readback
 
         import jax.numpy as jnp
 
-        spec = self.topk
-        k = spec["k"]
         layout = ent["layout"]
         if layout.one_chunk_per_group:
             if self._topk_step is None:
                 self._topk_step = self._build_topk_step(fold=False)
-            packed = np.asarray(
+            packed = copy_out(
                 self._topk_step(layout.L1, ent["cols"], aux, ent["clen"])
             )
         else:
@@ -2303,11 +2306,16 @@ class FusedAggregateStage:
                 owner = ent["owner_dev"] = jnp.asarray(
                     layout.owner.astype(np.int32)
                 )
-            packed = np.asarray(
+            packed = copy_out(
                 self._topk_fold_step(layout.L1, ent["cols"], aux, ent["clen"],
                                      ent["n_groups"], owner)
             )
         record_readback(packed.shape[-1], packed.nbytes)
+        with tracing.span("runtime.to_arrow", engine="topk"):
+            return self._topk_to_table(ent, packed)
+
+    def _topk_to_table(self, ent: dict, packed: np.ndarray) -> Optional[pa.Table]:
+        spec = self.topk
         nl = 1 + spec["n_lanes"]
         E = 4 * nl + 2
         sel, tail = packed[:-E], packed[-E:]

@@ -28,6 +28,7 @@ import grpc
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.distributed.planner import DistributedPlanner
+from ballista_tpu.distributed.stages import UnresolvedShuffleExec
 from ballista_tpu.engine.context import ExecutionContext
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import KvBackend, MemoryBackend
@@ -35,9 +36,36 @@ from ballista_tpu.scheduler.rpc import add_scheduler_service
 from ballista_tpu.scheduler.state import SchedulerState
 from ballista_tpu.serde.arrow import schema_to_ipc
 from ballista_tpu.serde.logical import plan_from_proto
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 log = logging.getLogger("ballista.scheduler")
+
+# jobs whose runnable marks are kept for `scheduler.queue` (see _ready_ns)
+_READY_JOBS = 1024
+
+
+def _input_stages(plan) -> set:
+    """The stages whose shuffle output a planned (unbound) stage reads."""
+    if isinstance(plan, UnresolvedShuffleExec):
+        return {plan.stage_id}
+    out: set = set()
+    for child in plan.children():
+        out |= _input_stages(child)
+    return out
+
+
+class _Runnable:
+    """What `scheduler.queue` measures from, for one live job: `since[None]`
+    is the plan's commit and `since[stage]` the fold that completed the
+    stage, in perf_counter_ns; `inputs[stage]` are the stages it reads,
+    found once when the job is planned."""
+
+    __slots__ = ("inputs", "since")
+
+    def __init__(self, stages) -> None:
+        self.inputs = {s.stage_id: tuple(_input_stages(s)) for s in stages}
+        self.since: Dict[Optional[int], int] = {None: tracing.now_ns()}
 
 
 def _job_id() -> str:
@@ -177,6 +205,10 @@ class SchedulerServer:
         # scheduler-side shared-shuffle TTL sweep (ISSUE 20 satellite,
         # ROADMAP residue): same 1h TTL as the executor-side sweep
         self.shuffle_ttl_seconds = 3600.0  # durability: ephemeral(tuning knob)
+        # what `scheduler.queue` measures from, by job. Dropped with the
+        # job's terminal status, and bounded for jobs that never reach one
+        # (dict ops: atomic under the GIL, like _planning)
+        self._ready_ns: Dict[str, _Runnable] = {}  # durability: ephemeral(span bookkeeping of live jobs; a job adopted after a restart records no queue span)
 
     # -- crash simulation ---------------------------------------------------
     def _refuse_if_crashed(self, context) -> None:
@@ -431,6 +463,10 @@ class SchedulerServer:
 
     # -- RPC implementations ------------------------------------------------
     def ExecuteQuery(self, request: pb.ExecuteQueryParams, context=None) -> pb.ExecuteQueryResult:
+        with tracing.span("scheduler.execute_query") as sp:
+            return self._execute_query(request, context, sp)
+
+    def _execute_query(self, request, context, sp) -> pb.ExecuteQueryResult:
         self._refuse_if_crashed(context)
         from ballista_tpu.executor.confine import (
             check_proto_scan_roots,
@@ -508,7 +544,7 @@ class SchedulerServer:
         if fp is None and config.result_cache():
             record_tenancy("cache_unkeyable")
 
-        job_id = _job_id()
+        job_id = sp.job = _job_id()
         if fp is not None and config.result_cache():
             # result-cache lookup + job publish under the global lock so a
             # concurrent completion's cache put cannot interleave
@@ -880,6 +916,15 @@ class SchedulerServer:
     def _plan_job(
         self, job_id: str, plan, config, attempt: int = 0, content_key=None
     ) -> None:
+        with tracing.span("scheduler.plan", job=job_id) as sp:
+            self._plan_and_commit(job_id, plan, config, attempt, content_key, sp)
+        # the whole point of push dispatch: the job's first tasks leave for
+        # subscribed executors the moment planning commits, not after the
+        # next PollWork round-trip
+        with self.state.kv.lock():
+            self._pump_pushes()
+
+    def _plan_and_commit(self, job_id, plan, config, attempt, content_key, sp) -> None:
         physical = self._physical_plan(plan, config, content_key)
         stages = DistributedPlanner(config).plan_query_stages(job_id, physical)
         # all-or-nothing publish: stage plans, pending tasks, and the
@@ -887,23 +932,40 @@ class SchedulerServer:
         # leaves no torn job (the job stays queued with no planning keys
         # and recover() fails it cleanly on restart)
         batch = self.state.stage_job_plan(job_id, attempt)
+        tasks = 0
         for stage in stages:
             batch.add_stage_plan(stage.stage_id, stage)
             n = stage.output_partitioning().partition_count()
             for p in range(n):
                 batch.add_pending_task(stage.stage_id, p)
+            tasks += n
         if self.crashed:
             # last fence before the publish (narrow in-process race left:
             # real restarts are separate processes where the dead
             # scheduler's threads cannot write at all)
             raise RuntimeError("scheduler crashed during planning")
-        batch.commit()
+        while len(self._ready_ns) >= _READY_JOBS:
+            self._ready_ns.pop(next(iter(self._ready_ns)), None)
+        ready = self._ready_ns[job_id] = _Runnable(stages)
+        with tracing.span("scheduler.plan.commit"):
+            batch.commit()
+        ready.since[None] = tracing.now_ns()  # tasks are runnable from here
+        sp.set(stages=len(stages), tasks=tasks)
         log.info("job %s planned into %d stages", job_id, len(stages))
-        # the whole point of push dispatch: the job's first tasks leave for
-        # subscribed executors the moment planning commits, not after the
-        # next PollWork round-trip
-        with self.state.kv.lock():
-            self._pump_pushes()
+
+    def _record_queue(self, status: pb.TaskStatus) -> None:
+        """`scheduler.queue` of a first attempt at its hand-out: from the
+        moment it became runnable (the plan's commit, or the fold that
+        completed the last of the stages it reads) to now."""
+        pid = status.partition_id
+        ready = self._ready_ns.get(pid.job_id)
+        if ready is None or status.attempt:
+            return
+        since = ready.since
+        runnable = max([since[None]] + [since.get(s, 0)
+                                        for s in ready.inputs.get(pid.stage_id, ())])
+        tracing.record("scheduler.queue", runnable, tracing.now_ns(), job=pid.job_id,
+                       stage=pid.stage_id, partition=pid.partition_id)
 
     # -- push dispatch (ISSUE 8) --------------------------------------------
     def _task_definition(self, status: pb.TaskStatus, plan) -> pb.TaskDefinition:
@@ -914,6 +976,7 @@ class SchedulerServer:
 
         from ballista_tpu.config import BALLISTA_DELTA_FOR
 
+        self._record_queue(status)
         td = pb.TaskDefinition()
         td.task_id.CopyFrom(status.partition_id)
         td.attempt = status.attempt
@@ -958,6 +1021,14 @@ class SchedulerServer:
         byte-identical to the last pushed status is suppressed. Each
         subscriber gets its own copy (the caller may keep mutating the
         message)."""
+        self._fan_out_job_status(job_id, status)
+        if status.WhichOneof("status") in ("completed", "failed"):
+            self._ready_ns.pop(job_id, None)
+            fold = tracing.current()  # the `scheduler.status` that ended the job
+            if fold is not None:
+                fold.set(job_done=True, notified_ns=tracing.now_ns())
+
+    def _fan_out_job_status(self, job_id: str, status: pb.JobStatus) -> None:
         with self._status_mu:
             qs = list(self._status_subs.get(job_id, ()))
             if not qs:
@@ -1095,6 +1166,7 @@ class SchedulerServer:
             ):
                 sub.outstanding.discard(key)
         pushed = 0
+        t_assign, first_job = tracing.now_ns(), None
         while len(sub.outstanding) < sub.slots and not sub.closed.is_set():
             speculative = False
             try:
@@ -1155,6 +1227,11 @@ class SchedulerServer:
             sub.queue.put(td)
             record_serving("dispatch_push")
             pushed += 1
+            first_job = first_job or pid.job_id
+        if pushed:
+            # an idle tick hands out nothing and leaves no span
+            tracing.record("scheduler.assign", t_assign, tracing.now_ns(),
+                           job=first_job, tasks=pushed, via="push")
         return pushed
 
     def SubscribeWork(self, request: pb.SubscribeWorkParams, context=None):
@@ -1244,7 +1321,7 @@ class SchedulerServer:
                     continue
                 # stale reports from already-reset attempts are dropped;
                 # accepted ones keep the KV-side attempt history
-                if self.state.accept_task_status(ts):
+                if self._fold_task_status(ts):
                     jobs.add(ts.partition_id.job_id)
                     self._accepted_statuses += 1
                     # generation-rotated key: a restarted scheduler must
@@ -1294,6 +1371,7 @@ class SchedulerServer:
             # riding the 3s orphan grace for nothing
             if request.can_accept_task and not foreign:
                 speculative = False
+                t_assign = tracing.now_ns()
                 assigned = self.state.assign_next_schedulable_task(request.metadata.id)
                 if assigned is None:
                     # idle capacity + no fresh work: offer the slot to the
@@ -1317,8 +1395,15 @@ class SchedulerServer:
                                 self._task_definition(st2, plan2)
                             )
                     record_serving("dispatch_poll")
+                    tracing.record(
+                        "scheduler.assign", t_assign, tracing.now_ns(),
+                        job=status.partition_id.job_id, via="poll",
+                        tasks=1 + len(result.task.siblings))
             for job_id in jobs:
-                self.state.synchronize_job_status(job_id)
+                # the fold of the job's tasks into its status; the hook
+                # (_notify_job_status) marks the one that ends the job
+                with tracing.span("scheduler.status", job=job_id):
+                    self.state.synchronize_job_status(job_id)
             # accepted statuses may have completed upstream stages (or the
             # credit resolution above freed slots): dispatch the newly
             # runnable work NOW instead of waiting for a subscriber tick
@@ -1376,6 +1461,20 @@ class SchedulerServer:
                         context.abort(grpc.StatusCode.UNAVAILABLE, detail)
                     raise RuntimeError(detail)
             return result
+
+    def _fold_task_status(self, ts: pb.TaskStatus) -> bool:
+        """accept_task_status under its span; a completed status that
+        finishes its stage is the moment the stage's readers are runnable."""
+        pid = ts.partition_id
+        with tracing.span("scheduler.status", job=pid.job_id, stage=pid.stage_id,
+                          partition=pid.partition_id):
+            accepted = self.state.accept_task_status(ts)
+            ready = self._ready_ns.get(pid.job_id)
+            if (accepted and ready is not None
+                    and ts.WhichOneof("status") == "completed"
+                    and self.state.stage_done(pid.job_id, pid.stage_id)):
+                ready.since[pid.stage_id] = tracing.now_ns()
+            return accepted
 
     def GetJobStatus(self, request: pb.GetJobStatusParams, context=None) -> pb.GetJobStatusResult:
         self._refuse_if_crashed(context)

@@ -2422,6 +2422,13 @@ class SchedulerState:
             return local[0] / local[1]
         return costmodel.predict(op, 1.0, engine="task")
 
+    def stage_done(self, job_id: str, stage_id: int) -> bool:
+        """Whether every task of the stage has completed, by the task index
+        as it stands: nothing is reseeded for the asking, and without an
+        index the answer is no. Caller holds the global KV lock."""
+        idx = self._task_index
+        return idx is not None and idx.stage_done(job_id, stage_id)
+
     def has_running_tasks(self) -> bool:
         """True while any task is RUNNING in a live job — the autoscaler's
         idle check (a drain must never start under in-flight work it can

@@ -1,74 +1,177 @@
-"""Lightweight tracing spans + optional XLA profiler hook.
+"""Spans and counters of the served path: one recorder, always on.
 
-The reference only has coarse Instant-based timings around planning and
-per-partition execution (SURVEY §5); this gives named nested spans with a
-queryable log, plus jax.profiler integration for device traces.
-
-    with span("physical_planning"):
+    with span("scheduler.plan", job=job_id):
         ...
-    print(report())
+    record("scheduler.queue", t_runnable_ns, t_handed_ns, job=j, stage=s, partition=p)
+    print(timeline(job_id))
 
-Env BALLISTA_TRACE_DIR enables jax.profiler.trace into that directory for
-spans marked device=True (view in TensorBoard / xprof).
+A span keeps its name, start and end (`time.perf_counter_ns`), the thread,
+its parent (the innermost span open on the same thread, or `parent=`) and
+the request's identifiers `job`, `stage`, `partition`, which a child takes
+from its parent where it is given none. Closing a span is one append to a
+bounded ring, with no lock: the oldest span falls out and is counted in
+`tracing.dropped`. `_mu` guards the counters and the swap in `reset()`.
+
+While JAX is imported in the process and a profiler session is on, a span
+is also a `jax.profiler.TraceAnnotation` and lies in the `.xplane.pb` on the
+device's time line; without a session that is one flag test.
+
+`reset()` keeps what it clears; `drained()` returns it. `by_name` and
+`covered_s` are what the readers of a log share.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
+import collections
+import itertools
+import sys
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
+
 from ballista_tpu.utils.locks import make_lock
 
+# The largest window of today's cells closes about 39k spans (185 queries of
+# `scan_agg` in 51 s, some 210 a query; my chip run, PR 26): four of them fit.
+RING = 1 << 18
+
+
+class _Log:
+    """The ring of closed spans and the count of those that fell out of it."""
+
+    __slots__ = ("ring", "overflow", "dropped")
+
+    def __init__(self) -> None:
+        self.ring: "collections.deque[Span]" = collections.deque(maxlen=RING)
+        self.overflow = itertools.count(1)
+        self.dropped = 0
+
+
 _local = threading.local()
-_all_spans: List[Tuple[str, float, int]] = []  # (path, seconds, depth); guarded-by: _mu
+_ids = itertools.count(1)
+_log = _Log()  # swapped whole by reset(); appended to with no lock
 _counters: Dict[str, int] = {}  # guarded-by: _mu
+_last: Dict[str, object] = {"spans": [], "counters": {}}  # guarded-by: _mu
 _mu = make_lock("utils.tracing._mu")
 
 
-def _stack() -> List[str]:
-    if not hasattr(_local, "stack"):
-        _local.stack = []
-    return _local.stack
+class Span:
+    """One interval of one thread's work for one request."""
+
+    __slots__ = ("name", "start_ns", "end_ns", "tid", "id", "parent", "job",
+                 "stage", "partition", "attrs", "_annotation")
+
+    def __init__(self, name: str, job=None, stage=None, partition=None,
+                 parent: Optional["Span"] = None, attrs: Optional[dict] = None):
+        self.name = name
+        self.job, self.stage, self.partition = job, stage, partition
+        self.parent = parent.id if parent is not None else 0
+        self.attrs = attrs or {}
+        self.id = next(_ids)
+        self.tid = threading.get_ident()
+        self.start_ns = self.end_ns = 0
+        self._annotation = None
+        if parent is not None:
+            self._adopt(parent)
+
+    def _adopt(self, parent: "Span") -> None:
+        if self.job is None:
+            self.job = parent.job
+        if self.stage is None:
+            self.stage = parent.stage
+        if self.partition is None:
+            self.partition = parent.partition
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is under way (`bytes`, `via`)."""
+        self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
+
+    def __enter__(self) -> "Span":
+        try:
+            stack = _local.stack
+        except AttributeError:
+            stack = _local.stack = []
+        if stack and not self.parent:
+            self.parent = stack[-1].id
+            self._adopt(stack[-1])
+        annotate = _annotate or _find_annotate()
+        if annotate is not None and annotate.is_enabled():  # a profiler session is on
+            ids = {k: v for k, v in (("job", self.job), ("stage", self.stage),
+                                     ("partition", self.partition)) if v is not None}
+            self._annotation = annotate(self.name, **ids)
+            self._annotation.__enter__()
+        stack.append(self)  # last: nothing above may leave it there unpopped
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end_ns = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        _local.stack.pop()
+        _close(self)
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, {self.seconds * 1e3:.3f} ms, job={self.job!r}, "
+                f"stage={self.stage!r}, partition={self.partition!r}, {self.attrs})")
 
 
-@contextlib.contextmanager
-def span(name: str, device: bool = False) -> Iterator[None]:
-    stack = _stack()
-    stack.append(name)
-    path = "/".join(stack)
-    trace_dir = os.environ.get("BALLISTA_TRACE_DIR")
-    ctx = contextlib.nullcontext()
-    if device and trace_dir:
-        import jax
-
-        ctx = jax.profiler.trace(trace_dir)
-    t0 = time.perf_counter()
-    try:
-        with ctx:
-            yield
-    finally:
-        dt = time.perf_counter() - t0
-        with _mu:
-            _all_spans.append((path, dt, len(stack) - 1))
-        stack.pop()
+_annotate = None  # jax.profiler.TraceAnnotation, once JAX is there to ask
 
 
-def report(reset: bool = False) -> str:
-    with _mu:
-        lines = [
-            f"{'  ' * depth}{path.split('/')[-1]}: {dt * 1000:.2f} ms"
-            for path, dt, depth in _all_spans
-        ]
-        if reset:
-            _all_spans.clear()
-    return "\n".join(lines)
+def _find_annotate():
+    """`jax.profiler.TraceAnnotation` if JAX is imported in this process, and
+    never the start of that import. `jax` is in sys.modules from the first
+    line of another thread's `import jax`, seconds before `jax.profiler`
+    exists: until it does, spans are recorded and not annotated."""
+    global _annotate
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    _annotate = getattr(profiler, "TraceAnnotation", None)
+    return _annotate
 
 
-def spans() -> List[Tuple[str, float, int]]:
-    with _mu:
-        return list(_all_spans)
+def _close(s: Span) -> None:
+    log = _log
+    if len(log.ring) == RING:
+        log.dropped = next(log.overflow)
+    log.ring.append(s)
+
+
+def span(name: str, job=None, stage=None, partition=None,
+         parent: Optional[Span] = None, **attrs) -> Span:
+    """A block of work: `with span(...) as s:`; `s.job = ...` and `s.set(...)`
+    fill in what is known only inside the block."""
+    return Span(name, job, stage, partition, parent, attrs)
+
+
+def record(name: str, start_ns: int, end_ns: int, job=None, stage=None,
+           partition=None, parent: Optional[Span] = None, **attrs) -> Span:
+    """An interval whose two ends lie in different calls or threads."""
+    s = Span(name, job, stage, partition, parent, attrs)
+    s.start_ns, s.end_ns = start_ns, max(start_ns, end_ns)
+    _close(s)
+    return s
+
+
+def current() -> Optional[Span]:
+    """The innermost span open on this thread: what a worker thread is given
+    as `parent=` so that its spans join the request's tree."""
+    stack = getattr(_local, "stack", None)
+    return stack[-1] if stack else None
+
+
+def now_ns() -> int:
+    return time.perf_counter_ns()
+
+
+def spans() -> List[Span]:
+    """The spans closed since the last `reset()`, oldest first."""
+    return list(_log.ring)
 
 
 def incr(name: str, by: int = 1) -> None:
@@ -78,12 +181,104 @@ def incr(name: str, by: int = 1) -> None:
         _counters[name] = _counters.get(name, 0) + by
 
 
+def _with_dropped(counts: Dict[str, int], log: _Log) -> Dict[str, int]:
+    if log.dropped:
+        counts["tracing.dropped"] = log.dropped
+    return counts
+
+
 def counters() -> Dict[str, int]:
     with _mu:
-        return dict(_counters)
+        return _with_dropped(dict(_counters), _log)
 
 
 def reset() -> None:
+    """Clear spans and counters; what was cleared is the last drained log."""
+    global _log, _last
     with _mu:
-        _all_spans.clear()
+        log, _log = _log, _Log()
+        _last = {"spans": list(log.ring),
+                 "counters": _with_dropped(dict(_counters), log)}
         _counters.clear()
+
+
+def drained() -> Dict[str, object]:
+    """{"spans": [Span], "counters": {name: n}} of the last `reset()`."""
+    with _mu:
+        return _last
+
+
+# -- what the readers of a log share ------------------------------------------
+
+Interval = Tuple[int, int]
+
+
+def _union(intervals: Iterable[Interval]) -> List[Interval]:
+    merged: List[Interval] = []
+    for s, e in sorted(intervals):
+        if merged and s <= merged[-1][1]:
+            if e > merged[-1][1]:
+                merged[-1] = (merged[-1][0], e)
+        elif e > s:
+            merged.append((s, e))
+    return merged
+
+
+def covered_s(log: Iterable[Span], within: Iterable[Interval]) -> float:
+    """Seconds of the union of the spans' intervals that lie inside the
+    (disjoint) intervals `within`."""
+    merged = _union((s.start_ns, s.end_ns) for s in log)
+    total = 0
+    for lo, hi in within:
+        total += sum(min(e, hi) - max(s, lo) for s, e in merged
+                     if e > lo and s < hi)
+    return total / 1e9
+
+
+def by_name(log: Iterable[Span]) -> Dict[str, Tuple[int, float, float]]:
+    """{name: (count, total seconds, self seconds)}; self time is a span's
+    duration less the part of it that its children cover."""
+    log = list(log)
+    children: Dict[int, List[Span]] = {}
+    for s in log:
+        if s.parent:
+            children.setdefault(s.parent, []).append(s)
+    out: Dict[str, Tuple[int, float, float]] = {}
+    for s in log:
+        inside = covered_s(children.get(s.id, ()), [(s.start_ns, s.end_ns)])
+        n, total, own = out.get(s.name, (0, 0.0, 0.0))
+        out[s.name] = (n + 1, total + s.seconds, own + s.seconds - inside)
+    return out
+
+
+def leaves(log: Iterable[Span]) -> List[Span]:
+    """The spans of `log` that are no span's parent."""
+    log = list(log)
+    parents = {s.parent for s in log}
+    return [s for s in log if s.id not in parents]
+
+
+def timeline(job: str, log: Optional[Iterable[Span]] = None) -> str:
+    """The spans of one request, ordered by start and indented by parent,
+    with milliseconds from the request's first span: where one query went."""
+    mine = sorted((s for s in (spans() if log is None else log) if s.job == job),
+                  key=lambda s: (s.start_ns, s.id))
+    if not mine:
+        return f"no span of job {job!r}"
+    by_id = {s.id: s for s in mine}
+    t0 = mine[0].start_ns
+
+    def depth(s: Span) -> int:
+        d = 0
+        while s.parent in by_id:
+            s, d = by_id[s.parent], d + 1
+        return d
+
+    lines = []
+    for s in mine:
+        ids = "".join(f" {k}={v}" for k, v in (("stage", s.stage), ("partition", s.partition))
+                      if v is not None)
+        attrs = "".join(f" {k}={v}" for k, v in s.attrs.items())
+        lines.append(f"{(s.start_ns - t0) / 1e6:9.3f} ms {'  ' * depth(s)}{s.name} "
+                     f"{s.seconds * 1e3:.3f} ms{ids}{attrs} [t{s.tid % 10000}]")
+    return "\n".join(lines)

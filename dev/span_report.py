@@ -1,0 +1,69 @@
+"""One run of a benchmark cell, then what its window's spans say.
+
+    python3 dev/span_report.py <out.txt> --workload <cell> --seed <n> --seconds <s> --trace <0|1> [...]
+
+Runs benchmarks/chip/run.py's `main` in this process with the arguments after
+<out.txt>, then reads the window's spans from the recorder's last drained log
+(`utils/tracing.py`) and writes, per text: the `tracing.timeline()` of the
+query of median length; per span name the seconds a query spends in it
+(median over the text's queries: whole and self), and the median of the
+first third of the window's queries against the last third (what grows as
+the process serves more). Texts are matched to jobs by the order of the
+`client.collect` spans: the window sends its texts round-robin.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks", "chip")]
+
+
+def report(cell: str) -> str:
+    import run
+    from ballista_tpu.utils import tracing
+
+    texts = [t["name"] for t in run.load_cell(cell)["traffic"]["texts"]]
+    log = tracing.drained()["spans"]
+    roots = sorted((s for s in log if s.name == "client.collect"), key=lambda s: s.start_ns)
+    by_job = {}
+    for s in log:
+        by_job.setdefault(s.job, []).append(s)
+    out = [f"{cell}: {len(log)} spans, {len(roots)} queries, counters "
+           f"{tracing.drained()['counters']}"]
+    for i, text in enumerate(texts):
+        mine = roots[i::len(texts)]
+        if not mine:
+            continue
+        median = sorted(mine, key=lambda s: s.seconds)[len(mine) // 2]
+        out += [f"\n== {text}: {len(mine)} queries, median {median.seconds * 1e3:.1f} ms "
+                f"(job {median.job})", tracing.timeline(median.job, by_job[median.job])]
+        per_query = [tracing.by_name(by_job[r.job]) for r in mine]
+        third = max(1, len(mine) // 3)
+        out.append(f"{'span':26s} {'n':>5s} {'total ms':>9s} {'self ms':>9s} "
+                   f"{'first third':>11s} {'last third':>10s}")
+        for name in sorted({n for q in per_query for n in q}):
+            rows = [q.get(name, (0, 0.0, 0.0)) for q in per_query]
+            first = statistics.median(r[1] for r in rows[:third]) * 1e3
+            last = statistics.median(r[1] for r in rows[-third:]) * 1e3
+            out.append(f"{name:26s} {statistics.median(r[0] for r in rows):5.0f} "
+                       f"{statistics.median(r[1] for r in rows) * 1e3:9.2f} "
+                       f"{statistics.median(r[2] for r in rows) * 1e3:9.2f} "
+                       f"{first:11.2f} {last:10.2f}")
+    return "\n".join(out)
+
+
+if __name__ == "__main__":
+    import run
+
+    rc = run.main(sys.argv[2:])
+    if rc == 0:
+        cell = sys.argv[sys.argv.index("--workload") + 1]
+        os.makedirs(os.path.dirname(os.path.abspath(sys.argv[1])), exist_ok=True)
+        with open(sys.argv[1], "w") as f:
+            f.write(report(cell) + "\n")
+    sys.exit(rc)

@@ -558,7 +558,7 @@ def test_poll_once_hands_held_slot_to_the_task(tmp_path):
     loop = _poll_loop(sched, tmp_path, concurrent_tasks=1)
     gate = __import__("threading").Event()
 
-    def fake_run(task, slot_held=True):
+    def fake_run(task, slot_held=True, received_ns=None):
         gate.wait(5)
         loop._available.release()
 

@@ -429,11 +429,13 @@ def test_cache_and_tenancy_survive_scheduler_restart(tpath):
         cluster.shutdown()
 
 
-def test_lost_cached_partition_invalidates_and_resubmits(tpath):
+@pytest.mark.parametrize("how", ["collect", "collect_stream"])
+def test_lost_cached_partition_invalidates_and_resubmits(tpath, how):
     """Cached locations outliving their data (executor died under a live
     lease): the fetch fails, ReportLostPartition invalidates the entry and
     fails the cached job, and collect() resubmits transparently — the
-    query still returns the right rows."""
+    query still returns the right rows. The streaming collect gives the
+    same guarantee while it has yielded nothing."""
     cluster = StandaloneCluster(n_executors=2)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr)
@@ -460,7 +462,12 @@ def test_lost_cached_partition_invalidates_and_resubmits(tpath):
             "need a surviving executor to re-execute on"
         )
         tenancy_stats(reset=True)
-        again = ctx.sql(q).collect()
+        if how == "collect":
+            again = ctx.sql(q).collect()
+        else:
+            plan = ctx.sql(q).logical_plan()
+            again = pa.Table.from_batches(
+                list(ctx.collect_stream(plan)), schema=cold.schema)
         assert again.equals(cold)
         stats = tenancy_stats(reset=True)
         assert stats.get("cache_hit") == 1  # served stale, then...

@@ -141,10 +141,14 @@ def test_tracing_spans(sales_table):
     ctx = ExecutionContext()
     ctx.register_record_batches("sales", sales_table)
     ctx.sql("select count(*) as n from sales").collect()
-    paths = [p for p, _dt, _d in tracing.spans()]
-    assert "plan" in paths and "execute" in paths
-    assert "ms" in tracing.report(reset=True)
+    log = {s.name: s for s in tracing.spans()}
+    assert {"engine.plan", "engine.execute"} <= set(log)
+    plan, execute = log["engine.plan"], log["engine.execute"]
+    assert 0 < plan.start_ns <= plan.end_ns <= execute.start_ns <= execute.end_ns
+    assert plan.tid == execute.tid and not plan.parent
+    tracing.reset()
     assert tracing.spans() == []
+    assert {s.name for s in tracing.drained()["spans"]} >= set(log)
 
 
 def test_tpch_cli_benchmark(tmp_path):
