@@ -27,13 +27,16 @@ exactly this should happen).
 
 from __future__ import annotations
 
+import collections
+import functools
+import hashlib
 import logging
 import os
 import queue
 import threading
 import time
 import traceback
-from typing import Optional
+from typing import Optional, Tuple
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.distributed.stages import ShuffleWriterExec
@@ -47,6 +50,21 @@ from ballista_tpu.utils.locks import make_lock
 log = logging.getLogger("ballista.executor")
 
 POLL_INTERVAL_SECS = 0.25  # ref execution_loop.rs:75
+
+
+# stages whose set-up a PollLoop keeps: those that can be in flight on its
+# slots, or the members of one shared-scan batch
+_KEPT_STAGES = 8
+
+
+class _StageSetup:
+    """A stage's decoded, root-checked plan, the job's merged config and the
+    shuffle fetcher bound to it: the same for every task of the stage."""
+
+    __slots__ = ("plan", "config", "fetcher")
+
+    def __init__(self, plan, config: BallistaConfig, fetcher) -> None:
+        self.plan, self.config, self.fetcher = plan, config, fetcher
 
 
 def _ids(task: pb.TaskDefinition) -> dict:
@@ -114,6 +132,11 @@ class PollLoop:
         # status's wait on _finished until a poll takes it to the scheduler
         # (one set and one pop a status: dict ops, atomic under the GIL)
         self._reported_ns: dict = {}
+        # (job, stage, digest of the plan's bytes, settings) -> _StageSetup,
+        # least recently used first (_stage_setup)
+        self._setup_mu = make_lock("executor.execution_loop._setup_mu")
+        self._setups: "collections.OrderedDict[tuple, _StageSetup]" = (
+            collections.OrderedDict())  # guarded-by: self._setup_mu
         # -- push dispatch (ISSUE 8) ------------------------------------
         self._push_enabled = self.config.push_dispatch()
         self._idle_poll_max = self.config.idle_poll_max_s()
@@ -485,16 +508,95 @@ class PollLoop:
             daemon=True,
         ).start()
 
+    # the codec counts its work (tracing.incr) inside the decode; static
+    # resolution does not follow the bare imported name
+    # may-acquire: utils.tracing._mu
+    def _stage_setup(self, task: pb.TaskDefinition) -> Tuple["_StageSetup", bool]:
+        """What of a task's set-up is a function of its stage alone, decoded
+        once per stage: (the kept set-up, whether this call decoded it). The
+        key is content: job, stage, the plan's bytes (which name every
+        location it reads; as a digest, a plan over an in-memory table
+        carries the table) and the job's settings. The lock is held across
+        the decode so that the tasks of a stage that arrive together decode
+        once and not once each; under one GIL the others would gain nothing
+        by decoding beside it. A plan that is refused raises and is not kept:
+        every task of it runs the checks again."""
+        pid = task.task_id
+        key = (pid.job_id, pid.stage_id,
+               hashlib.blake2b(task.plan.SerializeToString(), digest_size=16).digest(),
+               tuple((kv.key, kv.value) for kv in task.settings))
+        with self._setup_mu:
+            kept = self._setups.get(key)
+            if kept is not None:
+                self._setups.move_to_end(key)
+                return kept, False
+            kept = self._decode_stage(task)
+            # a stage's tree pins what it holds (a join's build side): the
+            # least recently used goes as another job's stage comes in
+            while len(self._setups) >= _KEPT_STAGES:
+                self._setups.popitem(last=False)
+            self._setups[key] = kept
+            return kept, True
+
+    def _decode_stage(self, task: pb.TaskDefinition) -> "_StageSetup":
+        from ballista_tpu.executor.confine import (
+            check_proto_scan_roots,
+            check_scan_roots,
+        )
+        from ballista_tpu.serde.physical import phys_plan_from_proto
+
+        pid = task.task_id
+        # allowlist comes from the EXECUTOR's own config; the per-job
+        # settings merged below are client-controlled and must not
+        # widen it. Proto check first: deserializing a parquet source
+        # already reads the file footer.
+        roots = self.config.data_roots()
+        check_proto_scan_roots(task.plan, roots)
+        plan = phys_plan_from_proto(task.plan)
+        check_scan_roots(plan, roots)
+        if not isinstance(plan, ShuffleWriterExec):
+            plan = ShuffleWriterExec(pid.job_id, pid.stage_id, plan, None)
+        cfg = self.config
+        if task.settings:
+            # the submitting client's per-job settings override the
+            # executor's own defaults
+            cfg = BallistaConfig(
+                {**cfg.to_dict(), **{kv.key: kv.value for kv in task.settings}}
+            )
+            # ... except the shuffle WRITE/READ home (ISSUE 15): like
+            # the data_roots allowlist, an executor whose OWN config
+            # pins a shuffle tier keeps it — per-job settings must not
+            # steer os.replace publishes (or confine storage reads) to
+            # a client-chosen host path. An unconfigured executor (the
+            # standalone/local default, tier=local + no dir) lets the
+            # job opt in, mirroring data_roots="" = unrestricted.
+            from ballista_tpu.config import (
+                BALLISTA_SHUFFLE_DIR,
+                BALLISTA_SHUFFLE_TIER,
+            )
+
+            if (
+                self.config.shuffle_dir()
+                or self.config.shuffle_tier() != "local"
+            ):
+                cfg = BallistaConfig({
+                    **cfg.to_dict(),
+                    BALLISTA_SHUFFLE_TIER: self.config.shuffle_tier(),
+                    BALLISTA_SHUFFLE_DIR: self.config.shuffle_dir(),
+                })
+        # bind the merged config so fetch retries honor ballista.rpc.*
+        # (incl. per-job overrides)
+        return _StageSetup(
+            plan, cfg, functools.partial(flight_shuffle_fetcher, config=cfg)
+        )
+
     def _member_setup(self, task: pb.TaskDefinition):
         """Status skeleton + confined, deserialized plan + task context for
         one member of a dispatch. Failures land in the member's OWN failed
         status (plan None) — in a shared-scan batch (ISSUE 13) a bad member
-        must never take its siblings down. Returns (task, status, plan,
-        ctx)."""
-        import functools
-
-        from ballista_tpu.serde.physical import phys_plan_from_proto
-
+        must never take its siblings down. The plan is the stage's, shared
+        by its tasks (_stage_setup); what names the task is built here.
+        Returns (task, status, plan, ctx, decoded)."""
         pid = task.task_id
         status = pb.TaskStatus()
         status.partition_id.CopyFrom(pid)
@@ -505,69 +607,23 @@ class PollLoop:
         status.attempt = task.attempt
         status.speculative = task.speculative
         try:
-            # allowlist comes from the EXECUTOR's own config; the per-job
-            # settings merged below are client-controlled and must not
-            # widen it. Proto check first: deserializing a parquet source
-            # already reads the file footer.
-            from ballista_tpu.executor.confine import (
-                check_proto_scan_roots,
-                check_scan_roots,
-            )
-
-            roots = self.config.data_roots()
-            check_proto_scan_roots(task.plan, roots)
-            plan = phys_plan_from_proto(task.plan)
-            check_scan_roots(plan, roots)
-            if not isinstance(plan, ShuffleWriterExec):
-                plan = ShuffleWriterExec(pid.job_id, pid.stage_id, plan, None)
-            cfg = self.config
-            if task.settings:
-                # the submitting client's per-job settings override the
-                # executor's own defaults
-                cfg = BallistaConfig(
-                    {**cfg.to_dict(), **{kv.key: kv.value for kv in task.settings}}
-                )
-                # ... except the shuffle WRITE/READ home (ISSUE 15): like
-                # the data_roots allowlist, an executor whose OWN config
-                # pins a shuffle tier keeps it — per-job settings must not
-                # steer os.replace publishes (or confine storage reads) to
-                # a client-chosen host path. An unconfigured executor (the
-                # standalone/local default, tier=local + no dir) lets the
-                # job opt in, mirroring data_roots="" = unrestricted.
-                from ballista_tpu.config import (
-                    BALLISTA_SHUFFLE_DIR,
-                    BALLISTA_SHUFFLE_TIER,
-                )
-
-                if (
-                    self.config.shuffle_dir()
-                    or self.config.shuffle_tier() != "local"
-                ):
-                    cfg = BallistaConfig({
-                        **cfg.to_dict(),
-                        BALLISTA_SHUFFLE_TIER: self.config.shuffle_tier(),
-                        BALLISTA_SHUFFLE_DIR: self.config.shuffle_dir(),
-                    })
+            kept, decoded = self._stage_setup(task)
             ctx = TaskContext(
-                config=cfg,
+                config=kept.config,
                 work_dir=self.work_dir,
                 job_id=pid.job_id,
-                # bind the merged config so fetch retries honor
-                # ballista.rpc.* (incl. per-job overrides)
-                shuffle_fetcher=functools.partial(
-                    flight_shuffle_fetcher, config=cfg
-                ),
+                shuffle_fetcher=kept.fetcher,
                 attempt=task.attempt,
                 # keys the HBM-resident exchange registry (ISSUE 16) per
                 # executor, so co-resident executors never cross-hit
                 executor_id=self.metadata.id,
             )
-            return task, status, plan, ctx
+            return task, status, kept.plan, ctx, decoded
         except Exception as e:
             log.error("task %s setup failed: %s", pid, traceback.format_exc())
             status.failed.error = f"{type(e).__name__}: {e}"
             status.failed.executor_id = self.metadata.id
-            return task, status, None, None
+            return task, status, None, None, True
 
     def _member_execute(self, task, status, plan, ctx, shared=None) -> None:
         """Execute one member's plan, filling its status in place. `shared`
@@ -701,8 +757,10 @@ class PollLoop:
         try:
             with tracing.span("executor.task", members=len(members), **_ids(task)):
                 for td in members:
-                    with tracing.span("executor.setup", **_ids(td)):
-                        prepped.append(self._member_setup(td))
+                    with tracing.span("executor.setup", **_ids(td)) as sp:
+                        _td, status, plan, ctx, decoded = self._member_setup(td)
+                        sp.set(decoded=decoded)
+                        prepped.append((td, status, plan, ctx))
                 shared = None
                 if len(members) > 1:
                     from ballista_tpu.ops import sharedscan

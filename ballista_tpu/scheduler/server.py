@@ -972,18 +972,17 @@ class SchedulerServer:
         """Serialize one assignment into the wire TaskDefinition — the ONE
         shape both dispatch paths (PollWork reply, SubscribeWork push) send,
         so the executor cannot tell them apart."""
-        from ballista_tpu.serde.physical import phys_plan_to_proto
-
         from ballista_tpu.config import BALLISTA_DELTA_FOR
 
         self._record_queue(status)
+        pid = status.partition_id
         td = pb.TaskDefinition()
-        td.task_id.CopyFrom(status.partition_id)
+        td.task_id.CopyFrom(pid)
         td.attempt = status.attempt
-        td.plan.CopyFrom(phys_plan_to_proto(plan))
-        for k, v in self.state.get_job_settings(
-            status.partition_id.job_id
-        ).items():
+        # the stage's tasks share one encoding of the plan (state.task_wire)
+        wire, settings = self.state.task_wire(pid.job_id, pid.stage_id, plan)
+        td.plan.ParseFromString(wire)
+        for k, v in settings.items():
             td.settings.add(key=k, value=v)
             if k == BALLISTA_DELTA_FOR:
                 # delta provenance (ISSUE 19) rides first-class too
@@ -1167,6 +1166,7 @@ class SchedulerServer:
                 sub.outstanding.discard(key)
         pushed = 0
         t_assign, first_job = tracing.now_ns(), None
+        encoded = self.state.plan_encodes
         while len(sub.outstanding) < sub.slots and not sub.closed.is_set():
             speculative = False
             try:
@@ -1231,7 +1231,8 @@ class SchedulerServer:
         if pushed:
             # an idle tick hands out nothing and leaves no span
             tracing.record("scheduler.assign", t_assign, tracing.now_ns(),
-                           job=first_job, tasks=pushed, via="push")
+                           job=first_job, tasks=pushed, via="push",
+                           encoded=self.state.plan_encodes - encoded)
         return pushed
 
     def SubscribeWork(self, request: pb.SubscribeWorkParams, context=None):
@@ -1371,7 +1372,7 @@ class SchedulerServer:
             # riding the 3s orphan grace for nothing
             if request.can_accept_task and not foreign:
                 speculative = False
-                t_assign = tracing.now_ns()
+                t_assign, encoded = tracing.now_ns(), self.state.plan_encodes
                 assigned = self.state.assign_next_schedulable_task(request.metadata.id)
                 if assigned is None:
                     # idle capacity + no fresh work: offer the slot to the
@@ -1398,7 +1399,8 @@ class SchedulerServer:
                     tracing.record(
                         "scheduler.assign", t_assign, tracing.now_ns(),
                         job=status.partition_id.job_id, via="poll",
-                        tasks=1 + len(result.task.siblings))
+                        tasks=1 + len(result.task.siblings),
+                        encoded=self.state.plan_encodes - encoded)
             for job_id in jobs:
                 # the fold of the job's tasks into its status; the hook
                 # (_notify_job_status) marks the one that ends the job

@@ -229,6 +229,34 @@ class _TaskIndex:
 TASK_INDEX_RESEED_SECS = 5.0
 
 
+# jobs whose stage plans are kept decoded and encoded (see _stage_plans); as
+# many as the server keeps runnable marks for (server._READY_JOBS)
+_PLANNED_JOBS = 1024
+_UNSET = object()
+
+
+class _StagePlan:
+    """What is a function of one stage alone, computed once per stage: the
+    tree decoded from the stage's KV row, its scan-sharing signature and
+    cost-store op, and, for the upstream locations last bound into it, the
+    bound tree, its `TaskDefinition.plan` bytes and the job's settings. The
+    scheduler never executes these trees, so every task of the stage shares
+    them."""
+
+    __slots__ = ("row", "plan", "locations", "bound", "wire", "settings",
+                 "signature", "task_op")
+
+    def __init__(self, row: bytes, plan) -> None:
+        self.row = row  # the KV row `plan` was decoded from
+        self.plan = plan
+        self.locations = None  # what `bound` bound; None = nothing bound yet
+        self.bound = None
+        self.wire: Optional[bytes] = None  # `bound` encoded, at its first hand-out
+        self.settings: Dict[str, str] = {}
+        self.signature = _UNSET  # _cached_stage_signature
+        self.task_op: Optional[str] = None  # _task_run_op
+
+
 class JobPlanBatch:
     """One job's planning output, published all-or-nothing (ISSUE 6).
 
@@ -431,13 +459,16 @@ class SchedulerState:
         self._batch_members: Dict[Tuple[str, int, int], int] = {}  # durability: ephemeral(cost-model learning, a restarted scheduler re-learns)
         self._batches: Dict[int, dict] = {}  # durability: ephemeral(cost-model learning, a restarted scheduler re-learns)
         self._batch_next_id = 0  # durability: ephemeral(batch ids are process-local handles)
-        # (job, stage) -> scan-sharing signature (or None): stage plans are
-        # immutable once planned, so the signature is computed once — the
-        # candidate scan must not re-deserialize every co-pending stage
-        # plan on every dispatch. Bounded like _task_op_cache.
-        self._shared_sig_cache: Dict[Tuple[str, int], Optional[tuple]] = {}  # durability: ephemeral(content-keyed memo over immutable stage plans, misses recompute)
-        # per-(job, stage) cache of the job-independent task.run cost op
-        self._task_op_cache: Dict[Tuple[str, int], str] = {}  # durability: ephemeral(content-keyed memo, misses recompute)
+        # job -> stage -> _StagePlan: a stage's plan is decoded once per KV
+        # row and encoded once per binding, not once per task or per
+        # candidate scan of a dispatch. Keyed on content (the row's bytes,
+        # the locations bound), dropped with the job's terminal status,
+        # oldest job first past _PLANNED_JOBS.
+        # Access under the global KV lock, like the dispatch paths it serves.
+        self._stage_plans: Dict[str, Dict[int, _StagePlan]] = {}  # durability: ephemeral(content-keyed memo over stage rows and task statuses, misses recompute)
+        # plans encoded for hand-out since process start: `scheduler.assign`
+        # reports the difference across itself as `encoded`
+        self.plan_encodes = 0  # durability: ephemeral(span bookkeeping)
         # scheduler-owned task.run rates (op -> (total seconds, n)): the
         # process-global cost store is cleared by ANY job whose merged
         # per-job settings carry a different cost_model_dir (configure()
@@ -584,6 +615,8 @@ class SchedulerState:
     def _notify_job_status(self, job_id: str, status: pb.JobStatus) -> None:
         """Invoke the push-status hook (ISSUE 11); a subscriber bug must
         never fail the status write it observes."""
+        if status.WhichOneof("status") in ("completed", "failed"):
+            self._stage_plans.pop(job_id, None)
         cb = self.on_job_status
         if cb is not None:
             try:
@@ -1581,13 +1614,48 @@ class SchedulerState:
             self._key("stages", job_id, str(stage_id)), msg.SerializeToString()
         )
 
-    def get_stage_plan(self, job_id: str, stage_id: int):
+    # the codec counts its work (tracing.incr) under the caller's KV lock;
+    # static resolution does not follow the bare imported name
+    # may-acquire: utils.tracing._mu
+    def _stage_entry(self, job_id: str, stage_id: int) -> Optional[_StagePlan]:
+        """The stage's entry, decoded from its KV row as the row reads NOW:
+        the row is read on every call and a changed row is decoded anew."""
         v = self.kv.get(self._key("stages", job_id, str(stage_id)))
         if v is None:
             return None
-        n = pb.PhysicalPlanNode()
-        n.ParseFromString(v)
-        return phys_plan_from_proto(n)
+        stages = self._stage_plans.get(job_id)
+        if stages is None:
+            while len(self._stage_plans) >= _PLANNED_JOBS:
+                self._stage_plans.pop(next(iter(self._stage_plans)), None)
+            stages = self._stage_plans[job_id] = {}
+        entry = stages.get(stage_id)
+        if entry is None or entry.row != v:
+            n = pb.PhysicalPlanNode()
+            n.ParseFromString(v)
+            entry = stages[stage_id] = _StagePlan(v, phys_plan_from_proto(n))
+        return entry
+
+    def get_stage_plan(self, job_id: str, stage_id: int):
+        entry = self._stage_entry(job_id, stage_id)
+        return None if entry is None else entry.plan
+
+    # may-acquire: utils.tracing._mu
+    def task_wire(self, job_id: str, stage_id: int, bound) -> Tuple[bytes, Dict[str, str]]:
+        """`bound` (a _bound_stage_plan result) as `TaskDefinition.plan`
+        bytes, with the job's settings: encoded once per binding, so a first
+        attempt, a speculative duplicate and a shared-scan sibling of one
+        binding carry the same bytes. A tree that is no longer the stage's
+        kept binding (a later call bound other locations) is encoded alone."""
+        entry = self._stage_plans.get(job_id, {}).get(stage_id)
+        kept = entry is not None and entry.bound is bound
+        if kept and entry.wire is not None:
+            return entry.wire, entry.settings
+        self.plan_encodes += 1
+        wire = phys_plan_to_proto(bound).SerializeToString()
+        settings = self.get_job_settings(job_id)
+        if kept:
+            entry.wire, entry.settings = wire, settings
+        return wire, settings
 
     # -- tasks ------------------------------------------------------------------
     def save_task_status(self, status: pb.TaskStatus) -> bool:
@@ -2283,13 +2351,22 @@ class SchedulerState:
         """The stage plan with upstream shuffle locations bound, or None
         while any upstream stage is incomplete (or the plan is missing).
         Factored out of assign_next_schedulable_task so speculative
-        duplicates (ISSUE 11) bind EXACTLY like first attempts."""
-        plan = self.get_stage_plan(job_id, stage_id)
-        if plan is None:
+        duplicates (ISSUE 11) bind EXACTLY like first attempts. The
+        upstream statuses are read on every call; the bound tree is kept
+        per stage for the locations it bound, so the tasks of a stage share
+        one tree (and task_wire one encoding of it) until a location moves."""
+        entry = self._stage_entry(job_id, stage_id)
+        if entry is None:
             return None
+        plan = entry.plan
         unresolved = find_unresolved_shuffles(plan)
-        locations: Dict[int, List[ShuffleLocation]] = {}
+        # upstream stage -> its pieces in map-partition order, each as the
+        # arguments of its ShuffleLocation
+        bound_to: Dict[int, List[tuple]] = {}
+        metas: Dict[str, Optional[pb.ExecutorMetadata]] = {}  # of this call only
         for u in unresolved:
+            if u.stage_id in bound_to:
+                continue  # a stage read twice binds the same pieces
             # O(1) screen: stages the index knows are incomplete skip
             # the KV read entirely (staleness toward "peer completed
             # it" is bounded by the periodic reseed)
@@ -2306,32 +2383,39 @@ class SchedulerState:
                 t.WhichOneof("status") != "completed" for t in upstream
             ):
                 return None
-            locs = []
+            pieces = bound_to[u.stage_id] = []
             for t in sorted(upstream, key=lambda t: t.partition_id.partition_id):
-                meta = self.get_executor_metadata(t.completed.executor_id)
+                eid = t.completed.executor_id
+                if eid not in metas:
+                    metas[eid] = self.get_executor_metadata(eid)
+                meta = metas[eid]
                 host, port = (meta.host, meta.port) if meta else ("", 0)
-                locs.append(
-                    ShuffleLocation(
-                        t.completed.executor_id,
-                        host,
-                        port,
-                        t.completed.path,
-                        stage_id=u.stage_id,
-                        map_partition=t.partition_id.partition_id,
-                        # shared tier (ISSUE 15): a storage-homed piece set
-                        # binds even when its producer's lease lapsed —
-                        # readers resolve it from the mount (host/port stay
-                        # the fallback transport while the producer lives)
-                        storage_uri=t.completed.storage_uri,
-                        # HBM-resident exchange hint + size (ISSUE 16):
-                        # advisory — a consumer landing elsewhere (or after
-                        # eviction) just walks the ordinary piece ladder
-                        resident=t.completed.resident,
-                        nbytes=t.completed.stats.num_bytes,
-                    )
-                )
-            locations[u.stage_id] = locs
-        return remove_unresolved_shuffles(plan, locations) if unresolved else plan
+                pieces.append((
+                    eid, host, port, t.completed.path,
+                    u.stage_id, t.partition_id.partition_id,
+                    # shared tier (ISSUE 15): a storage-homed piece set
+                    # binds even when its producer's lease lapsed —
+                    # readers resolve it from the mount (host/port stay
+                    # the fallback transport while the producer lives)
+                    t.completed.storage_uri,
+                    # HBM-resident exchange hint + size (ISSUE 16):
+                    # advisory — a consumer landing elsewhere (or after
+                    # eviction) just walks the ordinary piece ladder
+                    t.completed.resident, t.completed.stats.num_bytes,
+                ))
+        # the locations are built from what the binding is kept under, so the
+        # key is everything they carry onto the wire: a reset, a duplicate
+        # that won elsewhere or a re-homed piece changes it
+        if entry.locations != bound_to:
+            locations = {
+                sid: [ShuffleLocation(*piece) for piece in pieces]
+                for sid, pieces in bound_to.items()
+            }
+            entry.bound = (
+                remove_unresolved_shuffles(plan, locations) if unresolved else plan
+            )
+            entry.locations, entry.wire = bound_to, None
+        return entry.bound
 
     def _locality_partition_order(
         self, bound, parts, executor_id: str
@@ -2382,20 +2466,16 @@ class SchedulerState:
         sha1 of the stage plan's display with the job id scrubbed, so
         repeated queries of the same shape share one rate across jobs (and
         sibling tasks within one job warm it past MIN_OBSERVATIONS)."""
-        k = (job_id, stage_id)
-        op = self._task_op_cache.get(k)
-        if op is None:
-            from ballista_tpu.ops import costmodel
+        from ballista_tpu.ops import costmodel
 
-            plan = self.get_stage_plan(job_id, stage_id)
-            shape = (
-                plan.display_indent() if plan is not None else f"s{stage_id}"
-            ).replace(job_id, "")
-            op = costmodel.task_run_op(shape)
-            if len(self._task_op_cache) > 10_000:
-                self._task_op_cache.clear()
-            self._task_op_cache[k] = op
-        return op
+        entry = self._stage_entry(job_id, stage_id)
+        if entry is None:
+            return costmodel.task_run_op(f"s{stage_id}")
+        if entry.task_op is None:
+            entry.task_op = costmodel.task_run_op(
+                entry.plan.display_indent().replace(job_id, "")
+            )
+        return entry.task_op
 
     def _observe_task_run(self, job_id: str, stage_id: int, seconds: float) -> None:
         from ballista_tpu.ops import costmodel
@@ -2596,21 +2676,21 @@ class SchedulerState:
 
     def _cached_stage_signature(self, job_id: str, stage_id: int):
         """Scan-sharing signature of a PLANNED stage, computed once per
-        (job, stage) from the stored stage plan — leaf fused-aggregate
+        stage row from the stored stage plan — leaf fused-aggregate
         stages read no shuffles, so the raw plan and the bound plan carry
-        the same signature. None = not batchable (cached too)."""
-        k = (job_id, stage_id)
-        if k in self._shared_sig_cache:
-            return self._shared_sig_cache[k]
+        the same signature. None = not batchable (kept too)."""
         try:
-            plan = self.get_stage_plan(job_id, stage_id)
-            sig = None if plan is None else self._shared_scan_signature(plan)
+            entry = self._stage_entry(job_id, stage_id)
         except Exception:
-            sig = None
-        if len(self._shared_sig_cache) > 10_000:
-            self._shared_sig_cache.clear()
-        self._shared_sig_cache[k] = sig
-        return sig
+            return None  # the row does not decode: not batchable
+        if entry is None:
+            return None
+        if entry.signature is _UNSET:
+            try:
+                entry.signature = self._shared_scan_signature(entry.plan)
+            except Exception:
+                entry.signature = None
+        return entry.signature
 
     def form_shared_batch(
         self, primary: pb.TaskStatus, plan, executor_id: str

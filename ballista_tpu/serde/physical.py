@@ -55,6 +55,7 @@ from ballista_tpu.serde.logical import (
     source_to_proto,
 )
 from ballista_tpu.serde.arrow import dtype_from_ipc, dtype_to_ipc, schema_from_ipc, schema_to_ipc
+from ballista_tpu.utils import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -114,6 +115,11 @@ def uncompile_expr(e: px.PhysicalExpr) -> lx.Expr:
 
 
 def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
+    tracing.incr("serde.plan_encode")  # whole trees encoded, not nodes
+    return _plan_to_proto(plan)
+
+
+def _plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
     n = pb.PhysicalPlanNode()
     if isinstance(plan, (CsvScanExec, ParquetScanExec, MemoryScanExec)):
         n.scan.scan.table_name = ""
@@ -125,15 +131,15 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
         if prune is not None:
             n.scan.prune_predicate.CopyFrom(expr_to_proto(uncompile_expr(prune)))
     elif isinstance(plan, ProjectionExec):
-        n.projection.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.projection.input.CopyFrom(_plan_to_proto(plan.input))
         for e, name in plan.exprs:
             n.projection.exprs.append(expr_to_proto(uncompile_expr(e)))
             n.projection.names.append(name)
     elif isinstance(plan, FilterExec):
-        n.filter.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.filter.input.CopyFrom(_plan_to_proto(plan.input))
         n.filter.predicate.CopyFrom(expr_to_proto(uncompile_expr(plan.predicate)))
     elif isinstance(plan, HashAggregateExec):
-        n.aggregate.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.aggregate.input.CopyFrom(_plan_to_proto(plan.input))
         n.aggregate.mode = plan.mode.value
         for e, name in plan.group_exprs:
             n.aggregate.group_exprs.append(expr_to_proto(uncompile_expr(e)))
@@ -151,8 +157,8 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
             n.aggregate.aggr_input_type_ipc.append(dtype_to_ipc(a.input_type))
         n.aggregate.exact_floats = getattr(plan, "exact_floats", False)
     elif isinstance(plan, HashJoinExec):
-        n.join.left.CopyFrom(phys_plan_to_proto(plan.left))
-        n.join.right.CopyFrom(phys_plan_to_proto(plan.right))
+        n.join.left.CopyFrom(_plan_to_proto(plan.left))
+        n.join.right.CopyFrom(_plan_to_proto(plan.right))
         for l, r in plan.on:
             n.join.left_keys.append(l)
             n.join.right_keys.append(r)
@@ -161,10 +167,10 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
         if plan.filter is not None:
             n.join.filter.CopyFrom(expr_to_proto(uncompile_expr(plan.filter)))
     elif isinstance(plan, CrossJoinExec):
-        n.cross_join.left.CopyFrom(phys_plan_to_proto(plan.left))
-        n.cross_join.right.CopyFrom(phys_plan_to_proto(plan.right))
+        n.cross_join.left.CopyFrom(_plan_to_proto(plan.left))
+        n.cross_join.right.CopyFrom(_plan_to_proto(plan.right))
     elif isinstance(plan, SortExec):
-        n.sort.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.sort.input.CopyFrom(_plan_to_proto(plan.input))
         for e, asc, nf in plan.sort_keys:
             se = lx.SortExpr(uncompile_expr(e), asc, nf)
             n.sort.sort_exprs.append(expr_to_proto(se))
@@ -172,33 +178,33 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
             n.sort.has_fetch = True
             n.sort.fetch = plan.fetch
     elif isinstance(plan, GlobalLimitExec):
-        n.limit.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.limit.input.CopyFrom(_plan_to_proto(plan.input))
         n.limit.limit = plan.limit
         n.limit.skip = plan.skip
         setattr(n.limit, "global", True)  # `global` is a Python keyword
     elif isinstance(plan, LocalLimitExec):
-        n.limit.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.limit.input.CopyFrom(_plan_to_proto(plan.input))
         n.limit.limit = plan.limit
         setattr(n.limit, "global", False)
     elif isinstance(plan, CoalesceBatchesExec):
-        n.coalesce_batches.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.coalesce_batches.input.CopyFrom(_plan_to_proto(plan.input))
         n.coalesce_batches.target_batch_size = plan.target_batch_size
     elif isinstance(plan, MergeExec):
-        n.merge.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.merge.input.CopyFrom(_plan_to_proto(plan.input))
     elif isinstance(plan, EmptyExec):
         n.empty.produce_one_row = plan.produce_one_row
         n.empty.schema_ipc = schema_to_ipc(plan.schema())
     elif isinstance(plan, UnionExec):
         for i in plan.inputs:
-            n.union.inputs.append(phys_plan_to_proto(i))
+            n.union.inputs.append(_plan_to_proto(i))
     elif isinstance(plan, RepartitionExec):
-        n.repartition.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.repartition.input.CopyFrom(_plan_to_proto(plan.input))
         n.repartition.scheme = plan.partitioning.scheme
         n.repartition.n = plan.partitioning.partition_count()
         for e in plan.partitioning.exprs:
             n.repartition.hash_exprs.append(expr_to_proto(uncompile_expr(e)))
     elif isinstance(plan, ShuffleWriterExec):
-        n.shuffle_writer.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.shuffle_writer.input.CopyFrom(_plan_to_proto(plan.input))
         n.shuffle_writer.job_id = plan.job_id
         n.shuffle_writer.stage_id = plan.stage_id
         p = plan.shuffle_output_partitioning
@@ -232,7 +238,7 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
         n.shuffle_reader.num_partitions = plan.num_partitions
         n.shuffle_reader.identity = plan.identity
     elif isinstance(plan, WindowExec):
-        n.window.input.CopyFrom(phys_plan_to_proto(plan.input))
+        n.window.input.CopyFrom(_plan_to_proto(plan.input))
         for f in plan.funcs:
             wf = n.window.funcs.add()
             wf.fn = f.fn
@@ -254,9 +260,9 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
         n.unresolved_shuffle.partition_count = plan.partition_count
         n.unresolved_shuffle.identity = plan.identity
     elif isinstance(plan, SpmdAggregateExec):
-        n.spmd_aggregate.subplan.CopyFrom(phys_plan_to_proto(plan.subplan))
+        n.spmd_aggregate.subplan.CopyFrom(_plan_to_proto(plan.subplan))
     elif isinstance(plan, SpmdJoinExec):
-        n.spmd_join.subplan.CopyFrom(phys_plan_to_proto(plan.subplan))
+        n.spmd_join.subplan.CopyFrom(_plan_to_proto(plan.subplan))
     else:
         raise SerdeError(f"cannot serialize physical plan {type(plan).__name__}")
     return n
@@ -268,6 +274,11 @@ def phys_plan_to_proto(plan: ExecutionPlan) -> pb.PhysicalPlanNode:
 
 
 def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
+    tracing.incr("serde.plan_decode")  # whole trees decoded, not nodes
+    return _plan_from_proto(n)
+
+
+def _plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
     which = n.WhichOneof("plan_type")
     if which == "scan":
         src = source_from_proto(n.scan.scan.source)
@@ -283,11 +294,11 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
             return scan
         return MemoryScanExec(src, projection)
     if which == "spmd_aggregate":
-        return SpmdAggregateExec(phys_plan_from_proto(n.spmd_aggregate.subplan))
+        return SpmdAggregateExec(_plan_from_proto(n.spmd_aggregate.subplan))
     if which == "spmd_join":
-        return SpmdJoinExec(phys_plan_from_proto(n.spmd_join.subplan))
+        return SpmdJoinExec(_plan_from_proto(n.spmd_join.subplan))
     if which == "projection":
-        input = phys_plan_from_proto(n.projection.input)
+        input = _plan_from_proto(n.projection.input)
         schema = input.schema()
         exprs = [
             (create_physical_expr(expr_from_proto(e), schema), name)
@@ -295,12 +306,12 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
         ]
         return ProjectionExec(input, exprs)
     if which == "filter":
-        input = phys_plan_from_proto(n.filter.input)
+        input = _plan_from_proto(n.filter.input)
         return FilterExec(
             input, create_physical_expr(expr_from_proto(n.filter.predicate), input.schema())
         )
     if which == "aggregate":
-        input = phys_plan_from_proto(n.aggregate.input)
+        input = _plan_from_proto(n.aggregate.input)
         mode = AggregateMode(n.aggregate.mode)
         # FINAL consumes partial state positionally: expressions are never
         # re-evaluated, so compile placeholders and use the shipped types
@@ -331,8 +342,8 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
         return HashAggregateExec(mode, input, group_exprs, funcs,
                                  exact_floats=n.aggregate.exact_floats)
     if which == "join":
-        left = phys_plan_from_proto(n.join.left)
-        right = phys_plan_from_proto(n.join.right)
+        left = _plan_from_proto(n.join.left)
+        right = _plan_from_proto(n.join.right)
         on = list(zip(n.join.left_keys, n.join.right_keys))
         jt = JoinType(n.join.join_type)
         filt = None
@@ -344,11 +355,11 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
         )
     if which == "cross_join":
         return CrossJoinExec(
-            phys_plan_from_proto(n.cross_join.left),
-            phys_plan_from_proto(n.cross_join.right),
+            _plan_from_proto(n.cross_join.left),
+            _plan_from_proto(n.cross_join.right),
         )
     if which == "sort":
-        input = phys_plan_from_proto(n.sort.input)
+        input = _plan_from_proto(n.sort.input)
         keys = []
         for se in n.sort.sort_exprs:
             e = expr_from_proto(se)
@@ -363,23 +374,23 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
         fetch = n.sort.fetch if n.sort.has_fetch else None
         return SortExec(input, keys, fetch)
     if which == "limit":
-        input = phys_plan_from_proto(n.limit.input)
+        input = _plan_from_proto(n.limit.input)
         if getattr(n.limit, "global"):
             return GlobalLimitExec(input, n.limit.limit, n.limit.skip)
         return LocalLimitExec(input, n.limit.limit)
     if which == "coalesce_batches":
         return CoalesceBatchesExec(
-            phys_plan_from_proto(n.coalesce_batches.input),
+            _plan_from_proto(n.coalesce_batches.input),
             n.coalesce_batches.target_batch_size,
         )
     if which == "merge":
-        return MergeExec(phys_plan_from_proto(n.merge.input))
+        return MergeExec(_plan_from_proto(n.merge.input))
     if which == "empty":
         return EmptyExec(n.empty.produce_one_row, schema_from_ipc(n.empty.schema_ipc))
     if which == "union":
-        return UnionExec([phys_plan_from_proto(i) for i in n.union.inputs])
+        return UnionExec([_plan_from_proto(i) for i in n.union.inputs])
     if which == "repartition":
-        input = phys_plan_from_proto(n.repartition.input)
+        input = _plan_from_proto(n.repartition.input)
         if n.repartition.scheme == "hash":
             exprs = [
                 create_physical_expr(expr_from_proto(e), input.schema())
@@ -392,7 +403,7 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
             part = Partitioning.unknown(n.repartition.n)
         return RepartitionExec(input, part)
     if which == "shuffle_writer":
-        input = phys_plan_from_proto(n.shuffle_writer.input)
+        input = _plan_from_proto(n.shuffle_writer.input)
         sw = n.shuffle_writer
         if sw.scheme == "none":
             part = None
@@ -429,7 +440,7 @@ def phys_plan_from_proto(n: pb.PhysicalPlanNode) -> ExecutionPlan:
     if which == "window":
         from ballista_tpu.physical.window import WindowExec, WindowFuncDesc
 
-        input = phys_plan_from_proto(n.window.input)
+        input = _plan_from_proto(n.window.input)
         schema = input.schema()
         funcs = []
         for wf in n.window.funcs:

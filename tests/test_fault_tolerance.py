@@ -256,6 +256,135 @@ def _two_stage_state(max_retries="3"):
     return s
 
 
+# -- a stage's plan crosses the wire once (ISSUE 27) ---------------------------
+
+def _codec_counts():
+    from ballista_tpu.utils import tracing
+
+    c = tracing.counters()
+    return c.get("serde.plan_decode", 0), c.get("serde.plan_encode", 0)
+
+
+def _served_two_stage():
+    """_two_stage_state behind a SchedulerServer (whose _task_definition is
+    what both dispatch paths send), the map stage complete on e1, eight
+    reduce tasks pending."""
+    from ballista_tpu.distributed.stages import UnresolvedShuffleExec
+    from ballista_tpu.physical.basic import EmptyExec
+    from ballista_tpu.scheduler.server import SchedulerServer
+
+    srv = SchedulerServer(MemoryBackend(), namespace="t")
+    s = srv.state
+    _running_job(s)
+    s.save_executor_metadata(_meta("e1"))
+    schema = pa.schema([("a", pa.int64())])
+    s.save_stage_plan("j", 1, EmptyExec(True, schema))
+    s.save_stage_plan("j", 2, UnresolvedShuffleExec(1, schema, 2))
+    for m in range(2):
+        s.save_task_status(_task("j", 1, m, "completed", "e1"))
+    for p in range(8):
+        s.save_task_status(_task("j", 2, p))
+    return srv, s
+
+
+def _hand_out(srv, executor="e1"):
+    status, plan = srv.state.assign_next_schedulable_task(executor)
+    return srv._task_definition(status, plan)
+
+
+def test_eight_tasks_of_a_stage_cost_one_decode_and_one_encode():
+    srv, s = _served_two_stage()
+    decoded, encoded = _codec_counts()
+    tds = [_hand_out(srv) for _ in range(8)]
+    assert [td.task_id.partition_id for td in tds] == list(range(8))
+    assert _codec_counts() == (decoded + 1, encoded + 1) and s.plan_encodes == 1
+    assert len({td.plan.SerializeToString() for td in tds}) == 1
+    locs = tds[0].plan.shuffle_reader.partition_locations
+    assert [(l.executor_meta.id, l.path) for l in locs] == [("e1", "/x")] * 2
+    # ... and the entry goes with the job's terminal status
+    assert set(s._stage_plans["j"]) == {2}
+    for st in (1, 2):
+        for t in s.get_stage_tasks("j", st):
+            if t.WhichOneof("status") != "completed":
+                done = _task("j", st, t.partition_id.partition_id, "completed", "e1")
+                assert s.accept_task_status(done)
+    s.synchronize_job_status("j")
+    assert s.get_job_metadata("j").WhichOneof("status") == "completed"
+    assert "j" not in s._stage_plans
+
+
+def _rehome(s, field):
+    """Map output 0 is lost and recomputed: what its completed status (or its
+    executor's registration) then says in `field` is new."""
+    moved = _task("j", 1, 0, "completed", "e1")
+    moved.attempt = 1
+    if field == "executor":
+        s.save_executor_metadata(_meta("e2", 2))
+        moved.completed.executor_id = "e2"
+    elif field == "host_port":
+        s.save_executor_metadata(_meta("e1", 7))
+    elif field == "path":
+        moved.completed.path = "/y"
+    elif field == "storage_uri":
+        moved.completed.storage_uri = "/mnt/shuffle/j/1/0"
+    elif field == "resident":
+        moved.completed.resident = True
+    elif field == "nbytes":
+        moved.completed.stats.num_bytes = 4096
+    s.save_task_status(moved)
+
+
+@pytest.mark.parametrize(
+    "field", ["executor", "host_port", "path", "storage_uri", "resident", "nbytes"])
+def test_a_rehomed_upstream_piece_is_in_the_next_task_definition(field):
+    """The kept bytes are keyed on every location they carry: after a
+    lost-task reset lands a map output elsewhere, the next hand-out binds and
+    encodes anew, and while the piece is being recomputed nothing is handed
+    out at all."""
+    srv, s = _served_two_stage()
+    first = _hand_out(srv)
+    s.save_task_status(_task("j", 1, 0))  # the reset: completed -> pending
+    recompute, _plan = s.assign_next_schedulable_task("e1")
+    assert recompute.partition_id.stage_id == 1
+    assert s.assign_next_schedulable_task("e1") is None  # the reduce stage waits
+    _rehome(s, field)
+    _decoded, encoded = _codec_counts()
+    second, third = _hand_out(srv), _hand_out(srv)
+    assert _codec_counts()[1] == encoded + 1  # one new binding, shared again
+    assert second.plan.SerializeToString() == third.plan.SerializeToString()
+    assert second.plan.SerializeToString() != first.plan.SerializeToString()
+    was = first.plan.shuffle_reader.partition_locations
+    now = second.plan.shuffle_reader.partition_locations
+    if field != "host_port":  # (e1 serves both pieces)
+        assert now[1] == was[1]  # map output 1 never moved
+    got = {
+        "executor": now[0].executor_meta.id,
+        "host_port": now[0].executor_meta.port,
+        "path": now[0].path,
+        "storage_uri": now[0].storage_uri,
+        "resident": now[0].resident,
+        "nbytes": now[0].partition_stats.num_bytes,
+    }[field]
+    want = {"executor": "e2", "host_port": 7, "path": "/y",
+            "storage_uri": "/mnt/shuffle/j/1/0", "resident": True,
+            "nbytes": 4096}[field]
+    assert got == want
+
+
+def test_a_replanned_stage_row_is_decoded_anew():
+    """The decoded tree is keyed on the row's bytes, not on (job, stage)."""
+    from ballista_tpu.distributed.stages import UnresolvedShuffleExec
+
+    srv, s = _served_two_stage()
+    before = _hand_out(srv)
+    s.save_stage_plan("j", 2, UnresolvedShuffleExec(1, pa.schema([("b", pa.int64())]), 2))
+    decoded, encoded = _codec_counts()
+    after = _hand_out(srv)
+    # (the save itself encodes the row once)
+    assert _codec_counts() == (decoded + 1, encoded + 1)
+    assert after.plan.SerializeToString() != before.plan.SerializeToString()
+
+
 def test_lineage_completed_map_on_dead_executor_with_running_consumer():
     """Satellite regression (pre-fix-failing): a COMPLETED map task on a
     dead executor while a downstream reduce RUNS on a live executor. Before

@@ -420,6 +420,14 @@ class _FakePlan:
         self.deps = [_FakeShuffle(d) for d in deps]
 
 
+def _stub_entry(plan):
+    """A stub stage plan as `SchedulerState._stage_entry` returns one (it is
+    what get_stage_plan and _bound_stage_plan read the stage through)."""
+    from ballista_tpu.scheduler.state import _StagePlan
+
+    return None if plan is None else _StagePlan(b"", plan)
+
+
 def _linear_scan_assign(s, executor_id):
     """The pre-index reference algorithm (full task scan in KV key order),
     kept verbatim as the differential oracle for the per-stage index."""
@@ -497,8 +505,8 @@ def test_indexed_assignment_matches_linear_scan(monkeypatch, seed):
     monkeypatch.setattr(state_mod, "remove_unresolved_shuffles",
                         lambda plan, locations: plan)
     monkeypatch.setattr(
-        SchedulerState, "get_stage_plan",
-        lambda self, job_id, stage_id: plans.get((job_id, stage_id)),
+        SchedulerState, "_stage_entry",
+        lambda self, job_id, stage_id: _stub_entry(plans.get((job_id, stage_id))),
     )
     monkeypatch.setattr(
         SchedulerState, "get_executor_metadata", lambda self, eid: None
@@ -557,8 +565,8 @@ def test_peer_scheduler_completion_unblocks_downstream(monkeypatch):
     monkeypatch.setattr(state_mod, "remove_unresolved_shuffles",
                         lambda plan, locations: plan)
     monkeypatch.setattr(
-        SchedulerState, "get_stage_plan",
-        lambda self, job_id, stage_id: plans.get((job_id, stage_id)),
+        SchedulerState, "_stage_entry",
+        lambda self, job_id, stage_id: _stub_entry(plans.get((job_id, stage_id))),
     )
     monkeypatch.setattr(
         SchedulerState, "get_executor_metadata", lambda self, eid: None
@@ -606,8 +614,8 @@ def test_peer_lost_task_reset_blocks_downstream(monkeypatch):
     monkeypatch.setattr(state_mod, "remove_unresolved_shuffles",
                         lambda plan, locations: plan)
     monkeypatch.setattr(
-        SchedulerState, "get_stage_plan",
-        lambda self, job_id, stage_id: plans.get((job_id, stage_id)),
+        SchedulerState, "_stage_entry",
+        lambda self, job_id, stage_id: _stub_entry(plans.get((job_id, stage_id))),
     )
     monkeypatch.setattr(
         SchedulerState, "get_executor_metadata", lambda self, eid: None

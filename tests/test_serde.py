@@ -173,236 +173,362 @@ def roundtrip_plan(plan: lp.LogicalPlan):
     return p2
 
 
-def test_roundtrip_scan_projection_filter():
-    plan = (
-        _scan()
-        .filter(col("a") > lit(1))
-        .project([col("a"), (col("b") * lit(2.0)).alias("b2")])
-        .build()
-    )
-    roundtrip_plan(plan)
-
-
-def test_roundtrip_aggregate_sort_limit():
-    plan = (
-        _scan()
-        .aggregate([col("c")], [F.sum(col("a")).alias("s"), F.avg(col("b")).alias("m")])
-        .sort([col("s").sort(ascending=False)])
-        .limit(5)
-        .build()
-    )
-    roundtrip_plan(plan)
-
-
-def test_roundtrip_joins():
+def _joins():
     left = _scan().alias("l")
     right = _scan().alias("r")
-    plan = left.join(
+    inner = left.join(
         right,
         [(lx.Column("a", "l"), lx.Column("a", "r"))],
         lp.JoinType.INNER,
     ).build()
-    roundtrip_plan(plan)
-
     semi = left.join(
         _scan().alias("r2"),
         [(lx.Column("a", "l"), lx.Column("a", "r2"))],
         lp.JoinType.SEMI,
         filter=lx.Column("b", "l") > lit(1.0),
     ).build()
-    roundtrip_plan(semi)
+    return inner, semi
 
 
-def test_roundtrip_repartition_union_distinct():
-    plan = (
+# every logical plan the round-trip cases build, by name
+LOGICAL_PLANS = {
+    "scan_projection_filter": lambda: (
         _scan()
-        .repartition_hash([col("a")], 4)
-        .distinct()
+        .filter(col("a") > lit(1))
+        .project([col("a"), (col("b") * lit(2.0)).alias("b2")])
         .build()
-    )
-    roundtrip_plan(plan)
-    u = _scan().union([_scan()]).build()
-    roundtrip_plan(u)
+    ),
+    "aggregate_sort_limit": lambda: (
+        _scan()
+        .aggregate([col("c")], [F.sum(col("a")).alias("s"), F.avg(col("b")).alias("m")])
+        .sort([col("s").sort(ascending=False)])
+        .limit(5)
+        .build()
+    ),
+    "join_inner": lambda: _joins()[0],
+    "join_semi_filtered": lambda: _joins()[1],
+    "repartition_distinct": lambda: (
+        _scan().repartition_hash([col("a")], 4).distinct().build()
+    ),
+    "union": lambda: _scan().union([_scan()]).build(),
+    "empty": lambda: lp.EmptyRelation(True, pa.schema([pa.field("x", pa.int32())])),
+    "create_external_table": lambda: lp.CreateExternalTable(
+        "t2", "/tmp/x", "csv", True, SCHEMA
+    ),
+    "memory_scan": lambda: _scan().build(),
+}
 
 
-def test_roundtrip_empty_and_ddl():
-    roundtrip_plan(lp.EmptyRelation(True, pa.schema([pa.field("x", pa.int32())])))
-    roundtrip_plan(
-        lp.CreateExternalTable("t2", "/tmp/x", "csv", True, SCHEMA)
-    )
+@pytest.mark.parametrize("name", [n for n in LOGICAL_PLANS if n != "memory_scan"])
+def test_roundtrip_logical(name):
+    roundtrip_plan(LOGICAL_PLANS[name]())
 
 
 def test_roundtrip_memory_scan_preserves_data():
-    plan = _scan().build()
-    p2 = roundtrip_plan(plan)
+    p2 = roundtrip_plan(LOGICAL_PLANS["memory_scan"]())
     # memory partitions carry actual rows over the wire (IPC)
     assert p2.source.num_partitions() == 2
     total = sum(b.num_rows for part in p2.source.partitions for b in part)
     assert total == 3
 
 
-class TestPhysicalRoundtrip:
-    def _physical(self, df_builder):
-        from ballista_tpu.engine import ExecutionContext
+def _physical(df_builder):
+    from ballista_tpu.engine import ExecutionContext
 
-        ctx = ExecutionContext()
-        return ctx.create_physical_plan(df_builder.build())
+    ctx = ExecutionContext()
+    return ctx.create_physical_plan(df_builder.build())
 
-    def roundtrip(self, plan):
-        from ballista_tpu.proto import ballista_pb2 as pb
-        from ballista_tpu.serde.physical import (
-            phys_plan_from_proto,
-            phys_plan_to_proto,
-        )
 
-        msg = phys_plan_to_proto(plan)
-        decoded = pb.PhysicalPlanNode()
-        decoded.ParseFromString(msg.SerializeToString())
-        p2 = phys_plan_from_proto(decoded)
-        if "mode=final" not in str(plan):
-            # FINAL aggregates deserialize with positional placeholder
-            # expressions (they never re-evaluate inputs), so display
-            # equality is only guaranteed elsewhere
-            assert str(p2) == str(plan)
-        assert p2.schema().equals(plan.schema())
-        return p2
+def _join_sort_limit():
+    left = _scan().alias("l")
+    right = _scan().alias("r")
+    return _physical(
+        left.join(right, [(lx.Column("a", "l"), lx.Column("a", "r"))])
+        .sort([lx.Column("a", "l").sort()])
+        .limit(2)
+    )
 
-    def test_filter_project(self):
-        plan = self._physical(
-            _scan().filter(col("a") > lit(1)).project([col("a"), col("c")])
-        )
-        self.roundtrip(plan)
 
-    def test_aggregate_two_phase(self):
-        plan = self._physical(
-            _scan().aggregate([col("c")], [F.sum(col("a")).alias("s"),
-                                           F.avg(col("b")).alias("m"),
-                                           F.count(col("a")).alias("n")])
-        )
-        p2 = self.roundtrip(plan)
+def _shuffle_writer():
+    from ballista_tpu.distributed.stages import ShuffleWriterExec
+    from ballista_tpu.physical.expr import ColumnExpr
+    from ballista_tpu.physical.plan import Partitioning
+
+    return ShuffleWriterExec(
+        "job1", 3, _physical(_scan()), Partitioning.hash([ColumnExpr("a", 0)], 4)
+    )
+
+
+def _shuffle_reader():
+    from ballista_tpu.distributed.stages import ShuffleLocation, ShuffleReaderExec
+
+    return ShuffleReaderExec(
+        [ShuffleLocation("e1", "h", 50051, "/tmp/x", stage_id=3, map_partition=1)],
+        SCHEMA,
+        4,
+    )
+
+
+def _unresolved_shuffle():
+    from ballista_tpu.distributed.stages import UnresolvedShuffleExec
+
+    return UnresolvedShuffleExec(7, SCHEMA, 2)
+
+
+def _basic(kind, *args):
+    """Remaining node variants (ref from_proto.rs:58-345 covers all 15)."""
+    from ballista_tpu.physical import basic
+    from ballista_tpu.physical.join import CrossJoinExec
+    from ballista_tpu.physical.union import UnionExec
+
+    a = _physical(_scan())
+    if kind == "cross_join":
+        return CrossJoinExec(a, _physical(_scan()))
+    if kind == "union":
+        return UnionExec([a, _physical(_scan())])
+    if kind == "empty":
+        return basic.EmptyExec(args[0], SCHEMA)
+    return getattr(basic, kind)(a, *args)
+
+
+def _repartition(scheme):
+    from ballista_tpu.physical.expr import ColumnExpr
+    from ballista_tpu.physical.plan import Partitioning
+    from ballista_tpu.physical.repartition import RepartitionExec
+
+    part = (Partitioning.hash([ColumnExpr("a", 0)], 8) if scheme == "hash"
+            else Partitioning.round_robin(3))
+    return RepartitionExec(_physical(_scan()), part)
+
+
+def _window():
+    from ballista_tpu.physical.expr import ColumnExpr
+    from ballista_tpu.physical.window import WindowExec, WindowFuncDesc
+
+    return WindowExec(
+        _physical(_scan()),
+        [
+            WindowFuncDesc(
+                "row_number", None, [ColumnExpr("c", 2)],
+                [(ColumnExpr("a", 0), True)], "rn", pa.int64(),
+            ),
+            WindowFuncDesc(
+                "sum", ColumnExpr("b", 1), [], [(ColumnExpr("a", 0), False)],
+                "running", pa.float64(),
+            ),
+        ],
+    )
+
+
+def _spmd_aggregate():
+    from ballista_tpu.config import BallistaConfig
+    from ballista_tpu.distributed.planner import DistributedPlanner
+    from ballista_tpu.engine import ExecutionContext
+    from ballista_tpu.parallel.spmd_stage import SpmdAggregateExec
+
+    ctx = ExecutionContext()
+    ctx.register_record_batches(
+        "t",
+        pa.table({"k": pa.array([1, 2, 1]), "v": pa.array([1.0, 2.0, 3.0])}),
+        n_partitions=2,
+    )
+    df = ctx.table("t").aggregate([col("k")], [F.sum(col("v")).alias("s")])
+    phys = ctx.create_physical_plan(df.logical_plan())
+    cfg = BallistaConfig({"ballista.tpu.spmd_stages": "true"})
+    stages = DistributedPlanner(cfg).plan_query_stages("j", phys)
+
+    def find(n):
+        if isinstance(n, SpmdAggregateExec):
+            return n
+        for c in n.children():
+            r = find(c)
+            if r is not None:
+                return r
+        return None
+
+    spmd = next((find(s) for s in stages if find(s) is not None), None)
+    assert spmd is not None
+    return spmd
+
+
+# every physical plan the round-trip cases build, by name
+PHYSICAL_PLANS = {
+    "filter_project": lambda: _physical(
+        _scan().filter(col("a") > lit(1)).project([col("a"), col("c")])
+    ),
+    "aggregate_two_phase": lambda: _physical(
+        _scan().aggregate([col("c")], [F.sum(col("a")).alias("s"),
+                                       F.avg(col("b")).alias("m"),
+                                       F.count(col("a")).alias("n")])
+    ),
+    "join_sort_limit": _join_sort_limit,
+    "shuffle_writer": _shuffle_writer,
+    "shuffle_reader": _shuffle_reader,
+    "unresolved_shuffle": _unresolved_shuffle,
+    "cross_join": lambda: _basic("cross_join"),
+    "union": lambda: _basic("union"),
+    "coalesce_batches": lambda: _basic("CoalesceBatchesExec", 4096),
+    "merge": lambda: _basic("MergeExec"),
+    "local_limit": lambda: _basic("LocalLimitExec", 7),
+    "empty": lambda: _basic("empty", False),
+    "empty_one_row": lambda: _basic("empty", True),
+    "repartition_hash": lambda: _repartition("hash"),
+    "repartition_round_robin": lambda: _repartition("round_robin"),
+    "window": _window,
+    "spmd_aggregate": _spmd_aggregate,
+}
+
+
+def roundtrip_physical(plan):
+    from ballista_tpu.proto import ballista_pb2 as pb
+    from ballista_tpu.serde.physical import (
+        phys_plan_from_proto,
+        phys_plan_to_proto,
+    )
+
+    msg = phys_plan_to_proto(plan)
+    decoded = pb.PhysicalPlanNode()
+    decoded.ParseFromString(msg.SerializeToString())
+    p2 = phys_plan_from_proto(decoded)
+    if "mode=final" not in str(plan):
+        # FINAL aggregates deserialize with positional placeholder
+        # expressions (they never re-evaluate inputs), so display
+        # equality is only guaranteed elsewhere
+        assert str(p2) == str(plan)
+    assert p2.schema().equals(plan.schema())
+    return p2
+
+
+@pytest.mark.parametrize("name", list(PHYSICAL_PLANS))
+def test_roundtrip_physical(name):
+    plan = PHYSICAL_PLANS[name]()
+    p2 = roundtrip_physical(plan)
+    if name == "aggregate_two_phase":
         # execution equivalence after roundtrip
         from ballista_tpu.physical.plan import TaskContext, collect_all
 
         t1 = collect_all(plan, TaskContext()).sort_by("c")
         t2 = collect_all(p2, TaskContext()).sort_by("c")
         assert t1.equals(t2)
-
-    def test_join_sort_limit(self):
-        left = _scan().alias("l")
-        right = _scan().alias("r")
-        df = left.join(right, [(lx.Column("a", "l"), lx.Column("a", "r"))]).sort(
-            [lx.Column("a", "l").sort()]
-        ).limit(2)
-        plan = self._physical(df)
-        self.roundtrip(plan)
-
-    def test_shuffle_nodes(self):
-        from ballista_tpu.distributed.stages import (
-            ShuffleLocation,
-            ShuffleReaderExec,
-            ShuffleWriterExec,
-            UnresolvedShuffleExec,
-        )
-        from ballista_tpu.physical.plan import Partitioning
-
-        inner = self._physical(_scan())
-        w = ShuffleWriterExec(
-            "job1", 3, inner, Partitioning.hash([__import__("ballista_tpu.physical.expr", fromlist=["ColumnExpr"]).ColumnExpr("a", 0)], 4)
-        )
-        self.roundtrip(w)
-        r = ShuffleReaderExec(
-            [ShuffleLocation("e1", "h", 50051, "/tmp/x",
-                             stage_id=3, map_partition=1)],
-            SCHEMA,
-            4,
-        )
-        r2 = self.roundtrip(r)
+    if name == "shuffle_reader":
         # the producing map task's lineage survives the wire: fetch_failed
         # reports name it so the scheduler can recompute the lost partition
-        loc = r2.locations[0]
+        loc = p2.locations[0]
         assert (loc.stage_id, loc.map_partition) == (3, 1)
         assert (loc.executor_id, loc.host, loc.port) == ("e1", "h", 50051)
-        u = UnresolvedShuffleExec(7, SCHEMA, 2)
-        self.roundtrip(u)
 
-    def test_cross_join_union_coalesce_empty(self):
-        """Remaining node variants (ref from_proto.rs:58-345 covers all 15)."""
-        from ballista_tpu.physical.basic import (
-            CoalesceBatchesExec,
-            EmptyExec,
-            LocalLimitExec,
-            MergeExec,
-        )
-        from ballista_tpu.physical.join import CrossJoinExec
-        from ballista_tpu.physical.union import UnionExec
 
-        a = self._physical(_scan())
-        b = self._physical(_scan())
-        self.roundtrip(CrossJoinExec(a, b))
-        self.roundtrip(UnionExec([a, b]))
-        self.roundtrip(CoalesceBatchesExec(a, 4096))
-        self.roundtrip(MergeExec(a))
-        self.roundtrip(LocalLimitExec(a, 7))
-        self.roundtrip(EmptyExec(False, SCHEMA))
-        self.roundtrip(EmptyExec(True, SCHEMA))
+# -- the codec's memos (ISSUE 27): what is kept is what a fresh pass gives ----
 
-    def test_repartition_variants(self):
-        from ballista_tpu.physical.expr import ColumnExpr
-        from ballista_tpu.physical.plan import Partitioning
-        from ballista_tpu.physical.repartition import RepartitionExec
+def _ipc_fields(msg):
+    """Every Arrow-IPC schema or type a message carries, nested ones too."""
+    out = []
+    for fd, value in msg.ListFields():
+        values = value if fd.is_repeated else [value]
+        for v in values:
+            if fd.type == fd.TYPE_MESSAGE:
+                out += _ipc_fields(v)
+            elif fd.name.endswith("_ipc") and fd.name != "partitions_ipc":
+                out.append(v)
+    return out
 
-        a = self._physical(_scan())
-        self.roundtrip(
-            RepartitionExec(a, Partitioning.hash([ColumnExpr("a", 0)], 8))
-        )
-        self.roundtrip(RepartitionExec(a, Partitioning.round_robin(3)))
 
-    def test_window_exec(self):
-        from ballista_tpu.physical.expr import ColumnExpr
-        from ballista_tpu.physical.window import WindowExec, WindowFuncDesc
+def _encoded(kind, name):
+    from ballista_tpu.serde.physical import phys_plan_to_proto
 
-        a = self._physical(_scan())
-        w = WindowExec(
-            a,
-            [
-                WindowFuncDesc(
-                    "row_number", None, [ColumnExpr("c", 2)],
-                    [(ColumnExpr("a", 0), True)], "rn", pa.int64(),
-                ),
-                WindowFuncDesc(
-                    "sum", ColumnExpr("b", 1), [], [(ColumnExpr("a", 0), False)],
-                    "running", pa.float64(),
-                ),
-            ],
-        )
-        self.roundtrip(w)
+    if kind == "logical":
+        return plan_to_proto(LOGICAL_PLANS[name]())
+    return phys_plan_to_proto(PHYSICAL_PLANS[name]())
 
-    def test_spmd_aggregate_node(self):
-        from ballista_tpu.config import BallistaConfig
-        from ballista_tpu.distributed.planner import DistributedPlanner
-        from ballista_tpu.engine import ExecutionContext
-        from ballista_tpu.parallel.spmd_stage import SpmdAggregateExec
 
-        ctx = ExecutionContext()
-        ctx.register_record_batches(
-            "t",
-            pa.table({"k": pa.array([1, 2, 1]), "v": pa.array([1.0, 2.0, 3.0])}),
-            n_partitions=2,
-        )
-        df = ctx.table("t").aggregate([col("k")], [F.sum(col("v")).alias("s")])
-        phys = ctx.create_physical_plan(df.logical_plan())
-        cfg = BallistaConfig({"ballista.tpu.spmd_stages": "true"})
-        stages = DistributedPlanner(cfg).plan_query_stages("j", phys)
+ALL_PLANS = [("logical", n) for n in LOGICAL_PLANS] + [
+    ("physical", n) for n in PHYSICAL_PLANS]
 
-        def find(n):
-            if isinstance(n, SpmdAggregateExec):
-                return n
-            for c in n.children():
-                r = find(c)
-                if r is not None:
-                    return r
-            return None
 
-        spmd = next((find(s) for s in stages if find(s) is not None), None)
-        assert spmd is not None
-        self.roundtrip(spmd)
+@pytest.mark.parametrize("kind,name", ALL_PLANS, ids=lambda v: v)
+def test_memoised_schema_parse_is_the_unmemoised_parse(kind, name):
+    from ballista_tpu.serde import arrow as serde_arrow
+
+    carried = _ipc_fields(_encoded(kind, name))
+    assert carried, name
+    for data in carried:
+        fresh = pa.ipc.read_schema(pa.BufferReader(data))
+        for _ in range(2):  # a miss, then a hit
+            kept = serde_arrow.schema_from_ipc(data)
+            assert kept.equals(fresh, check_metadata=True)
+        assert serde_arrow.schema_from_ipc(bytes(data)) is kept
+        if len(fresh) == 1:
+            assert serde_arrow.dtype_from_ipc(data).equals(
+                fresh.field(0).type, check_metadata=True)
+
+
+def test_a_full_memo_starts_over_and_counts_each_parse(monkeypatch):
+    from ballista_tpu.serde import arrow as serde_arrow
+    from ballista_tpu.utils import tracing
+
+    monkeypatch.setattr(serde_arrow, "_MEMO_ENTRIES", 2)
+    monkeypatch.setattr(serde_arrow, "_schemas", {})
+    blobs = [pa.schema([pa.field(f"f{i}", pa.int64())]).serialize().to_pybytes()
+             for i in range(3)]
+    before = tracing.counters().get("serde.schema_parse", 0)
+    for data in blobs + blobs[2:]:
+        assert serde_arrow.schema_from_ipc(data).names == [
+            pa.ipc.read_schema(pa.BufferReader(data)).names[0]]
+        assert len(serde_arrow._schemas) <= 2
+    assert tracing.counters()["serde.schema_parse"] - before == 3
+
+
+METADATA_CASES = {
+    "schema_metadata": (SCHEMA, SCHEMA.with_metadata({"origin": "b"})),
+    "field_metadata": (
+        pa.schema([pa.field("a", pa.int64())]),
+        pa.schema([pa.field("a", pa.int64(), metadata={"unit": "rows"})]),
+    ),
+    "nested_field_metadata": (
+        pa.struct([pa.field("a", pa.int64())]),
+        pa.struct([pa.field("a", pa.int64(), metadata={"unit": "rows"})]),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", list(METADATA_CASES))
+def test_equal_schemas_with_other_metadata_encode_to_other_bytes(name):
+    """`==` on schemas and types ignores metadata; the wire must not."""
+    from ballista_tpu.serde import arrow as serde_arrow
+
+    plain, tagged = METADATA_CASES[name]
+    assert plain == tagged and not plain.equals(tagged, check_metadata=True)
+    if isinstance(plain, pa.Schema):
+        to_ipc, from_ipc = serde_arrow.schema_to_ipc, serde_arrow.schema_from_ipc
+    else:
+        to_ipc, from_ipc = serde_arrow.dtype_to_ipc, serde_arrow.dtype_from_ipc
+    for _ in range(2):  # the second pass meets whatever the first kept
+        a, b = to_ipc(plain), to_ipc(tagged)
+        assert a != b
+        assert from_ipc(a).equals(plain, check_metadata=True)
+        assert from_ipc(b).equals(tagged, check_metadata=True)
+
+
+@pytest.mark.parametrize("name", list(PHYSICAL_PLANS))
+def test_a_kept_encode_equals_a_fresh_one_byte_for_byte(name):
+    """The scheduler hands every task of a binding the bytes it encoded for
+    the first (SchedulerState.task_wire): they are what a fresh
+    phys_plan_to_proto of the bound tree gives."""
+    from ballista_tpu.proto import ballista_pb2 as pb
+    from ballista_tpu.scheduler.kv import MemoryBackend
+    from ballista_tpu.scheduler.state import SchedulerState
+    from ballista_tpu.serde.physical import phys_plan_to_proto
+
+    s = SchedulerState(MemoryBackend(), "t")
+    s.save_stage_plan("j", 9, PHYSICAL_PLANS[name]())
+    for p in range(2):  # the stage an UnresolvedShuffleExec(7, ..) reads
+        t = pb.TaskStatus()
+        t.partition_id.job_id, t.partition_id.stage_id = "j", 7
+        t.partition_id.partition_id = p
+        t.completed.executor_id, t.completed.path = "e1", f"/w/j/7/{p}"
+        s.save_task_status(t)
+    bound = s._bound_stage_plan("j", 9, s._ensure_task_index())
+    kept, _settings = s.task_wire("j", 9, bound)
+    assert s.task_wire("j", 9, bound)[0] is kept and s.plan_encodes == 1
+    assert kept == phys_plan_to_proto(bound).SerializeToString()
+    assert s._bound_stage_plan("j", 9, s._ensure_task_index()) is bound

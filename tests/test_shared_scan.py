@@ -531,6 +531,55 @@ def test_form_shared_batch_fair_share_sibling_order():
     assert members[0] == "l1", members
 
 
+def test_batch_siblings_carry_their_own_stages_kept_bytes(table_path):
+    """ISSUE 27: a shared-scan sibling's TaskDefinition goes through the same
+    binding and the same kept encoding as a first attempt of its stage: one
+    encode a member stage, and the bytes a fresh encode of the bound tree
+    gives."""
+    from ballista_tpu.distributed.planner import DistributedPlanner
+    from ballista_tpu.engine import ExecutionContext
+    from ballista_tpu.proto import ballista_pb2 as pb
+    from ballista_tpu.scheduler.kv import MemoryBackend
+    from ballista_tpu.scheduler.server import SchedulerServer
+    from ballista_tpu.serde.physical import phys_plan_to_proto
+
+    costmodel.reset()
+    srv = SchedulerServer(
+        MemoryBackend(), namespace="t",
+        config=BallistaConfig({"ballista.tpu.cost_model_dir": ""}))
+    s = srv.state
+    s.save_executor_metadata(pb.ExecutorMetadata(id="e1", host="h", port=1))
+    ctx = ExecutionContext()
+    ctx.register_parquet("t", table_path)
+    stage_of = {}
+    for job, query in zip("abc", QUERIES):
+        physical = ctx.create_physical_plan(ctx.sql(query).logical_plan())
+        stage = DistributedPlanner().plan_query_stages(job, physical)[0]
+        running = pb.JobStatus()
+        running.running.SetInParent()
+        s.save_job_metadata(job, running)
+        s.save_stage_plan(job, stage.stage_id, stage)
+        pending = pb.TaskStatus()
+        pending.partition_id.job_id, pending.partition_id.stage_id = job, stage.stage_id
+        s.save_task_status(pending)
+        stage_of[job] = stage.stage_id
+    status, plan = s.assign_next_schedulable_task("e1")
+    siblings = s.form_shared_batch(status, plan, "e1")
+    assert sorted(st.partition_id.job_id for st, _p in siblings) == ["b", "c"]
+    members = [srv._task_definition(status, plan)] + [
+        srv._task_definition(st, p) for st, p in siblings]
+    assert s.plan_encodes == 3
+    for td in members:
+        job = td.task_id.job_id
+        kept = s._stage_plans[job][stage_of[job]]
+        bound = s._bound_stage_plan(job, stage_of[job], s._ensure_task_index())
+        assert bound is kept.bound
+        assert td.plan.SerializeToString() == kept.wire
+        assert kept.wire == phys_plan_to_proto(bound).SerializeToString()
+        assert td.plan.shuffle_writer.job_id == job
+    assert s.plan_encodes == 3
+
+
 # -- layout-warm members are shared-scan-eligible (ISSUE 15 satellite) -------
 
 def test_layout_warm_member_batches_bit_identical(table_path, tmp_path):

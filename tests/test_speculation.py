@@ -89,13 +89,14 @@ def _completed(job, stage, part, attempt, executor, speculative=False):
     return t
 
 
-def _straggling_state(kv=None, config=None):
+def _straggling_state(kv=None, config=None, state=None):
     """A state with one RUNNING task on e1 (aged 5s into its watch entry),
     a second live executor e2, and a warm task.run prediction of ~1ms —
     grossly exceeded, so the straggler monitor fires on the next idle
     slot."""
     costmodel.reset()
-    s = SchedulerState(kv or MemoryBackend(), "t", config=config or _spec_config())
+    s = state or SchedulerState(
+        kv or MemoryBackend(), "t", config=config or _spec_config())
     _running_job(s)
     s.save_executor_metadata(_meta("e1"))
     s.save_executor_metadata(_meta("e2"))
@@ -348,6 +349,23 @@ def test_lineage_invalidation_retires_instead_of_promoting():
     # the retired duplicate may still be running — a same-number requeue
     # would let its late report impersonate the fresh attempt)
     assert cur.WhichOneof("status") is None and cur.attempt == 2
+
+
+def test_speculative_duplicate_carries_its_primarys_plan_bytes():
+    """A duplicate goes through the same binding and the same kept encoding
+    as the first attempt (ISSUE 27): same bytes, no second encode."""
+    from ballista_tpu.scheduler.server import SchedulerServer
+
+    srv = SchedulerServer(MemoryBackend(), namespace="t", config=_spec_config())
+    s = _straggling_state(state=srv.state)
+    primary = s.get_task_status("j", 1, 0)
+    bound = s._bound_stage_plan("j", 1, s._ensure_task_index())
+    td = srv._task_definition(primary, bound)
+    dup, dup_plan = s.maybe_speculate("e2")
+    td_dup = srv._task_definition(dup, dup_plan)
+    assert dup.speculative and td_dup.attempt == td.attempt + 1
+    assert td_dup.plan.SerializeToString() == td.plan.SerializeToString()
+    assert s.plan_encodes == 1
 
 
 def test_push_status_suppresses_unchanged_rewrites():
