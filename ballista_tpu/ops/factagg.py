@@ -522,12 +522,15 @@ class FactAggregateStage:
         """Row-space static mapped column: S_ATTR of each row's F2 value
         (-1 when the base holds no such key — the row can never qualify)."""
         keys, attrs = self._sec_map
-        f2 = npcols[self.secondary["f2_scan_idx"]].astype(np.int64)
-        if len(keys) == 0:
-            return np.full(len(f2), -1, dtype=np.int32)
-        pos = np.clip(np.searchsorted(keys, f2), 0, len(keys) - 1)
-        matched = keys[pos] == f2
-        return np.where(matched, attrs[pos], -1).astype(np.int32)
+        f2 = npcols[self.secondary["f2_scan_idx"]]
+        tracing.incr("device.map_rows", len(f2))
+        with self._dim_build(attachments=1, rows=len(keys), fact_rows=len(f2)):
+            f2 = f2.astype(np.int64)
+            if len(keys) == 0:
+                return np.full(len(f2), -1, dtype=np.int32)
+            pos = np.clip(np.searchsorted(keys, f2), 0, len(keys) - 1)
+            matched = keys[pos] == f2
+            return np.where(matched, attrs[pos], -1).astype(np.int32)
 
     def _sec_side(self, ctx) -> dict:
         """Query-time secondary plan: allowed S_ATTR classes and the group
@@ -636,26 +639,28 @@ class FactAggregateStage:
         import jax.numpy as jnp
 
         sec = self.secondary
-        info = self._sec_side(ctx)
-        prim = self._dim_side(ctx)
-        if (
-            ent["kind"] == "empty"
-            or len(info["allowed"]) == 0
-            or prim["table"].num_rows == 0
-        ):
-            return self.partial_schema.empty_table()
-        # per-rank coupling value from the primary side (-1 = no match)
-        p_col = prim["table"].column(sec["p"]).to_numpy(zero_copy_only=False)
-        if not np.issubdtype(p_col.dtype, np.integer):
-            raise UnsupportedOnDevice("coupling column must be integer")
-        rank_keys = ent["rank_keys"]
-        pos = np.clip(
-            np.searchsorted(prim["keys_sorted"], rank_keys),
-            0, max(0, len(prim["keys_sorted"]) - 1),
-        )
-        matched = prim["keys_sorted"][pos] == rank_keys
-        p_sorted = p_col[prim["order"]]
-        p_rank = np.where(matched, p_sorted[pos], -1).astype(np.int32)
+        with self._dim_build(attachments=2) as sp:
+            info = self._sec_side(ctx)
+            prim = self._dim_side(ctx)
+            sp.set(rows=prim["table"].num_rows)
+            if (
+                ent["kind"] == "empty"
+                or len(info["allowed"]) == 0
+                or prim["table"].num_rows == 0
+            ):
+                return self.partial_schema.empty_table()
+            # per-rank coupling value from the primary side (-1 = no match)
+            p_col = prim["table"].column(sec["p"]).to_numpy(zero_copy_only=False)
+            if not np.issubdtype(p_col.dtype, np.integer):
+                raise UnsupportedOnDevice("coupling column must be integer")
+            rank_keys = ent["rank_keys"]
+            pos = np.clip(
+                np.searchsorted(prim["keys_sorted"], rank_keys),
+                0, max(0, len(prim["keys_sorted"]) - 1),
+            )
+            matched = prim["keys_sorted"][pos] == rank_keys
+            p_sorted = p_col[prim["order"]]
+            p_rank = np.where(matched, p_sorted[pos], -1).astype(np.int32)
 
         GA = len(info["allowed"])
         ga_pad = 1
@@ -851,7 +856,8 @@ class FactAggregateStage:
         if ent is not None:
             return ent
         if self.secondary is not None:
-            self._ensure_sec_map(ctx)  # the derived column needs the map
+            with self._dim_build(attachments=1):
+                self._ensure_sec_map(ctx)  # the derived column needs the map
         ent = self.inner._prepare_partition_sorted(partition, ctx)
         use_cache = ctx.config.device_cache()
         if ent["kind"] == "sorted":
@@ -885,12 +891,20 @@ class FactAggregateStage:
         return ent
 
     # ------------------------------------------------------------------
+    @staticmethod
+    def _dim_build(**attrs) -> tracing.Span:
+        """The host's building of the dimension side before the program: a
+        child of `runtime.stage`, around the fact's prepare and never it."""
+        return tracing.span("runtime.dim_build", engine="factagg", **attrs)
+
     def run(self, partition: int, ctx) -> pa.Table:
         import jax.numpy as jnp
 
         if self.secondary is not None:
             return self._run_secondary(self._prepare(partition, ctx), ctx)
-        dim = self._dim_side(ctx)
+        with self._dim_build(attachments=1) as sp:
+            dim = self._dim_side(ctx)
+            sp.set(rows=dim["table"].num_rows)
         if self.topk is None and dim["table"].num_rows > MAX_SELECT_MEMBERS:
             # members <= dim rows: decline BEFORE prepare pays the fact
             # upload (the per-query check below would fire after it)
@@ -899,14 +913,16 @@ class FactAggregateStage:
         if ent["kind"] == "empty" or dim["table"].num_rows == 0:
             return self.partial_schema.empty_table()
 
-        rank_keys, rank_order = ent["rank_keys"], ent["rank_order"]
-        sorted_keys = rank_keys[rank_order]
-        pos = np.searchsorted(sorted_keys, dim["keys_sorted"])
-        pos = np.clip(pos, 0, len(sorted_keys) - 1)
-        matched = sorted_keys[pos] == dim["keys_sorted"]
-        member_ranks = rank_order[pos[matched]]
-        # dim row index (into the collected dim table) per matched rank
-        dim_rows_for_rank = dim["order"][matched]
+        # the rank maps: which of the fact's key ranks the dimension side holds
+        with self._dim_build(attachments=1, rows=dim["table"].num_rows):
+            rank_keys, rank_order = ent["rank_keys"], ent["rank_order"]
+            sorted_keys = rank_keys[rank_order]
+            pos = np.searchsorted(sorted_keys, dim["keys_sorted"])
+            pos = np.clip(pos, 0, len(sorted_keys) - 1)
+            matched = sorted_keys[pos] == dim["keys_sorted"]
+            member_ranks = rank_order[pos[matched]]
+            # dim row index (into the collected dim table) per matched rank
+            dim_rows_for_rank = dim["order"][matched]
 
         aux = [jnp.asarray(a) for a in self.inner.compiler.build_aux()]
         G = ent["n_groups"]

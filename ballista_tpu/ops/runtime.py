@@ -654,7 +654,18 @@ def pipelined_map(src, fn, workers: int, depth: int = 2, on_src_time=None):
                 return
         out_q.put(done)
 
-    reader = threading.Thread(target=_reader, name="ingest-reader", daemon=True)
+    from ballista_tpu.utils import tracing
+
+    consumer = tracing.current()
+
+    def _reader_for_consumer() -> None:
+        # spans the pull opens (a mapped scan's dimension build) are
+        # children of the span the consumer has open, the stage's
+        with tracing.adopt(consumer):
+            _reader()
+
+    reader = threading.Thread(target=_reader_for_consumer, name="ingest-reader",
+                              daemon=True)
     reader.start()
     try:
         while True:

@@ -23,6 +23,7 @@ device's time line; without a session that is one flag test.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import sys
 import threading
@@ -163,6 +164,25 @@ def current() -> Optional[Span]:
     as `parent=` so that its spans join the request's tree."""
     stack = getattr(_local, "stack", None)
     return stack[-1] if stack else None
+
+
+@contextlib.contextmanager
+def adopt(parent: Optional[Span]):
+    """On a helper thread: spans opened inside are children of `parent`, a
+    span open on the thread it works for (a reader thread that pulls a
+    generator on behalf of the stage that consumes it)."""
+    if parent is None:
+        yield
+        return
+    try:
+        stack = _local.stack
+    except AttributeError:
+        stack = _local.stack = []
+    stack.append(parent)
+    try:
+        yield
+    finally:
+        stack.pop()
 
 
 def now_ns() -> int:

@@ -92,6 +92,44 @@ def test_an_explicit_parent_joins_a_worker_thread_to_the_request():
     assert tracing.current() is None
 
 
+def test_a_helper_thread_adopts_the_span_it_works_for():
+    """`pipelined_map`'s reader pulls the stage's scan on its own thread:
+    what the pull opens is the stage's child, and the thread is left clean."""
+    from ballista_tpu.ops.runtime import pipelined_map
+
+    def scan():
+        for i in range(3):
+            with tracing.span("runtime.dim_build", rows=i):
+                pass
+            yield i
+
+    left = []
+    with tracing.span("runtime.stage", job="j", stage=2, partition=1) as stage:
+        assert list(pipelined_map(scan(), lambda x: x * 2, workers=2)) == [0, 2, 4]
+
+        def helper():
+            with tracing.adopt(stage):
+                with tracing.span("adopted.child"):
+                    pass
+            with tracing.adopt(None):  # nothing to work for: a root, as before
+                with tracing.span("adopted.none"):
+                    pass
+            left.append(tracing.current())
+
+        t = threading.Thread(target=helper)
+        t.start()
+        t.join()
+    log = tracing.spans()
+    builds = [s for s in log if s.name == "runtime.dim_build"]
+    assert len(builds) == 3 and all(s.tid != stage.tid for s in builds)
+    for s in builds + [s for s in log if s.name == "adopted.child"]:
+        assert s.parent == stage.id and (s.job, s.stage, s.partition) == ("j", 2, 1)
+    assert [s.parent for s in log if s.name == "adopted.none"] == [0]
+    assert left == [None] and tracing.current() is None
+    # the stage's self time is what no child covers, on whichever thread
+    assert tracing.by_name(log)["runtime.stage"][2] <= stage.seconds
+
+
 def test_record_keeps_an_interval_whose_ends_lie_elsewhere():
     t0 = tracing.now_ns()
     s = tracing.record("scheduler.queue", t0, t0 + 5_000_000, job="j", stage=1,
