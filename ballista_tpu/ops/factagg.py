@@ -635,7 +635,26 @@ class FactAggregateStage:
         return aotcache.wrap_step(self, "factagg_sec", step_sec,
                                   static_argnums=(0,))
 
-    def _run_secondary(self, ent: dict, ctx) -> pa.Table:
+    def _coupling_ranks(self, ent: dict, prim: dict) -> dict:
+        """Secondary mode's rank map: the primary side's coupling value at
+        each of the fact's key ranks (-1 = the side holds no such key), as
+        the device array the step takes."""
+        import jax.numpy as jnp
+
+        p_col = prim["table"].column(self.secondary["p"]).to_numpy(zero_copy_only=False)
+        if not np.issubdtype(p_col.dtype, np.integer):
+            raise UnsupportedOnDevice("coupling column must be integer")
+        rank_keys = ent["rank_keys"]
+        pos = np.clip(
+            np.searchsorted(prim["keys_sorted"], rank_keys),
+            0, max(0, len(prim["keys_sorted"]) - 1),
+        )
+        matched = prim["keys_sorted"][pos] == rank_keys
+        p_sorted = p_col[prim["order"]]
+        p_rank = np.where(matched, p_sorted[pos], -1).astype(np.int32)
+        return {"p_rank": jnp.asarray(p_rank)}
+
+    def _run_secondary(self, partition: int, ent: dict, ctx) -> pa.Table:
         import jax.numpy as jnp
 
         sec = self.secondary
@@ -649,18 +668,7 @@ class FactAggregateStage:
                 or prim["table"].num_rows == 0
             ):
                 return self.partial_schema.empty_table()
-            # per-rank coupling value from the primary side (-1 = no match)
-            p_col = prim["table"].column(sec["p"]).to_numpy(zero_copy_only=False)
-            if not np.issubdtype(p_col.dtype, np.integer):
-                raise UnsupportedOnDevice("coupling column must be integer")
-            rank_keys = ent["rank_keys"]
-            pos = np.clip(
-                np.searchsorted(prim["keys_sorted"], rank_keys),
-                0, max(0, len(prim["keys_sorted"]) - 1),
-            )
-            matched = prim["keys_sorted"][pos] == rank_keys
-            p_sorted = p_col[prim["order"]]
-            p_rank = np.where(matched, p_sorted[pos], -1).astype(np.int32)
+            maps = self._rank_maps(partition, ent, prim, sp, ctx, self._coupling_ranks)
 
         GA = len(info["allowed"])
         ga_pad = 1
@@ -675,7 +683,7 @@ class FactAggregateStage:
             self._sec_step(
                 ent["layout"].L1, ent["cols"], aux, ent["clen"],
                 ent["derived"]["sec_attr"],
-                jnp.asarray(p_rank), jnp.asarray(allowed_pad),
+                maps["p_rank"], jnp.asarray(allowed_pad),
             )
         )
         record_readback(packed.shape[-1], packed.nbytes)
@@ -897,12 +905,76 @@ class FactAggregateStage:
         child of `runtime.stage`, around the fact's prepare and never it."""
         return tracing.span("runtime.dim_build", engine="factagg", **attrs)
 
+    def _rank_maps(self, partition: int, ent: dict, dim: dict, sp, ctx, build) -> dict:
+        """What `build(ent, dim)` derives from a prepared partition and the
+        dimension side it is combined with: a join-side index, a pure
+        function of the two. It is built once and kept inside `ent` beside a
+        reference to `dim`, its device arrays part of the partition's
+        reservation, and served only while the side is that very object: so
+        it lives exactly as long as both are kept (never with
+        `ballista.tpu.device_cache` off, where neither is) and goes with the
+        entry's eviction or release. A miss is the uncached path."""
+        from ballista_tpu.ops.runtime import attach_to_pinned
+
+        maps = ent.get("rank_maps")
+        if maps is not None and maps["dim"] is dim:
+            tracing.incr("device.rank_map_hit")
+            sp.set(cached=True)
+            return maps
+        tracing.incr("device.rank_map_build")
+        sp.set(cached=False)
+        maps = build(ent, dim)
+        maps["dim"] = dim
+        attach_to_pinned(self, partition, ent, self._prepared, "rank_maps",
+                         maps, ctx.config.tpu_hbm_budget())
+        return maps
+
+    def _member_ranks(self, ent: dict, dim: dict) -> dict:
+        """Which of the fact's key ranks the dimension side holds and the
+        dimension row at each, with what the epilogue's program takes of
+        them as a device array."""
+        import jax.numpy as jnp
+
+        rank_keys, rank_order = ent["rank_keys"], ent["rank_order"]
+        sorted_keys = rank_keys[rank_order]
+        pos = np.searchsorted(sorted_keys, dim["keys_sorted"])
+        pos = np.clip(pos, 0, len(sorted_keys) - 1)
+        matched = sorted_keys[pos] == dim["keys_sorted"]
+        member_ranks = rank_order[pos[matched]]
+        # dim row index (into the collected dim table) per matched rank
+        dim_rows_for_rank = dim["order"][matched]
+        if self.topk is not None:
+            member = np.zeros(ent["n_groups"], dtype=bool)
+            member[member_ranks] = True
+            bits = np.packbits(member, bitorder="little")
+            # in rank order: a selected rank finds its dim row by bisection
+            by_rank = np.argsort(member_ranks)
+            return {
+                "ranks": member_ranks[by_rank],
+                "dim_rows": dim_rows_for_rank[by_rank],
+                "bits": jnp.asarray(bits),
+            }
+        n_pos = len(member_ranks)
+        if n_pos > MAX_SELECT_MEMBERS:
+            # the non-topk epilogue reads back [state_rows, members] — at
+            # dim cardinalities past this the transfer (and per-query host
+            # re-group) costs more than the host path; decline
+            raise UnsupportedOnDevice("member-select readback too large")
+        # bucket the gather width: an exact-length positions array would
+        # recompile step_select for every distinct member count
+        pos_pad = pad_to(member_ranks.astype(np.int32), bucket_rows(n_pos, 16), 0)
+        return {
+            "ranks": member_ranks,
+            "dim_rows": dim_rows_for_rank,
+            "pos_pad": jnp.asarray(pos_pad),
+        }
+
     def run(self, partition: int, ctx) -> pa.Table:
         import jax.numpy as jnp
 
         if self.secondary is not None:
-            return self._run_secondary(self._prepare(partition, ctx), ctx)
-        with self._dim_build(attachments=1) as sp:
+            return self._run_secondary(partition, self._prepare(partition, ctx), ctx)
+        with self._dim_build(attachments=1, cached=self._dim_cache is not None) as sp:
             dim = self._dim_side(ctx)
             sp.set(rows=dim["table"].num_rows)
         if self.topk is None and dim["table"].num_rows > MAX_SELECT_MEMBERS:
@@ -913,26 +985,14 @@ class FactAggregateStage:
         if ent["kind"] == "empty" or dim["table"].num_rows == 0:
             return self.partial_schema.empty_table()
 
-        # the rank maps: which of the fact's key ranks the dimension side holds
-        with self._dim_build(attachments=1, rows=dim["table"].num_rows):
-            rank_keys, rank_order = ent["rank_keys"], ent["rank_order"]
-            sorted_keys = rank_keys[rank_order]
-            pos = np.searchsorted(sorted_keys, dim["keys_sorted"])
-            pos = np.clip(pos, 0, len(sorted_keys) - 1)
-            matched = sorted_keys[pos] == dim["keys_sorted"]
-            member_ranks = rank_order[pos[matched]]
-            # dim row index (into the collected dim table) per matched rank
-            dim_rows_for_rank = dim["order"][matched]
+        with self._dim_build(attachments=1, rows=dim["table"].num_rows) as sp:
+            maps = self._rank_maps(partition, ent, dim, sp, ctx, self._member_ranks)
 
         aux = [jnp.asarray(a) for a in self.inner.compiler.build_aux()]
-        G = ent["n_groups"]
         if self.topk is not None:
-            member = np.zeros(G, dtype=bool)
-            member[member_ranks] = True
-            bits = np.packbits(member, bitorder="little")
             packed = copy_out(
                 self._fact_step(ent["layout"].L1, ent["cols"], aux,
-                                ent["clen"], jnp.asarray(bits))
+                                ent["clen"], maps["bits"])
             )
             record_readback(packed.shape[-1], packed.nbytes)
             sel, scores, valid = packed[:-4], packed[-4], packed[-1] > 0
@@ -967,38 +1027,27 @@ class FactAggregateStage:
                 and tie_val <= scores[-1]
             ):
                 raise UnsupportedOnDevice("top-k tie at candidate boundary")
-            # map selected ranks back to dim rows
-            rank_to_dim = np.full(G, -1, dtype=np.int64)
-            rank_to_dim[member_ranks] = dim_rows_for_rank
-            dim_idx = rank_to_dim[idx]
+            # map selected ranks back to dim rows: every valid rank is a
+            # member, so the bisection finds it
+            dim_idx = maps["dim_rows"][np.searchsorted(maps["ranks"], idx)]
             with tracing.span("runtime.to_arrow", engine="factagg_topk"):
                 return self._assemble(sel, idx, dim_idx, dim["table"], ent)
-        positions = member_ranks.astype(np.int64)
-        if len(positions) == 0:
-            return self.partial_schema.empty_table()
-        if len(positions) > MAX_SELECT_MEMBERS:
-            # the non-topk epilogue reads back [state_rows, members] — at
-            # dim cardinalities past this the transfer (and per-query host
-            # re-group) costs more than the host path; decline
-            raise UnsupportedOnDevice("member-select readback too large")
-        # bucket the gather width: an exact-length positions array would
-        # recompile step_select for every distinct member count
+        positions = maps["ranks"]
         n_pos = len(positions)
-        pos_pad = pad_to(
-            positions.astype(np.int32), bucket_rows(n_pos, 16), 0
-        )
+        if n_pos == 0:
+            return self.partial_schema.empty_table()
         # the span's `bytes` is what crossed, the padded bucket; the counter
         # below counts the slice that is kept
         sel = copy_out(
             self._fact_step(ent["layout"].L1, ent["cols"], aux, ent["clen"],
-                            jnp.asarray(pos_pad))
+                            maps["pos_pad"])
         )[:, :n_pos]
         record_readback(sel.shape[-1], sel.nbytes)
         with tracing.span("runtime.to_arrow", engine="factagg_select"):
             rows = self._decode(sel)
             keep = rows[0] > 0
             return self._assemble_decoded(
-                [r[keep] for r in rows], positions[keep], dim_rows_for_rank[keep],
+                [r[keep] for r in rows], positions[keep], maps["dim_rows"][keep],
                 dim["table"], ent,
             )
 

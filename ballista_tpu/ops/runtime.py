@@ -197,6 +197,33 @@ def reserve_and_pin(stage, partition: int, entry, cache: dict, nbytes: int, budg
         return True
 
 
+def attach_to_pinned(stage, partition: int, entry: dict, cache: dict,
+                     name: str, value, budget: int) -> bool:
+    """Keep `value` inside a pinned entry as `entry[name]`, its device arrays
+    added to the entry's reservation, so it is evicted and released with the
+    entry and `resident_bytes()` returns to what it was. False, and nothing
+    kept, where `entry` is not (or no longer) the partition's pinned entry —
+    it streams, was evicted or its stage retired — or the budget has no room
+    even after evicting other stages' pins. Replacing a value already kept
+    under `name` reserves the difference only, so two racing builders of the
+    same value reserve it once."""
+    global _resident_bytes
+    token = (id(stage), partition)
+    nbytes = entry_device_bytes(value)
+    with _res_lock:
+        if cache.get(partition) is not entry or token not in _reservations:
+            return False
+        delta = nbytes - entry_device_bytes(entry.get(name))
+        if _resident_bytes + delta > budget:
+            _evict_lru_locked(stage, delta, budget)
+        if _resident_bytes + delta > budget:
+            return False
+        _reservations[token] += delta
+        _resident_bytes += delta
+        entry[name] = value
+        return True
+
+
 # refuse an eviction plan that frees more than this multiple of the bytes
 # requested: re-uploading a 15 GB pin to admit a 2 GB one costs more h2d
 # time than the newcomer streaming ever would, and two such stages

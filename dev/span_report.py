@@ -8,8 +8,10 @@ Runs benchmarks/chip/run.py's `main` in this process with the arguments after
 query of median length; per span name the seconds a query spends in it
 (median over the text's queries: whole and self), and the median of the
 first third of the window's queries against the last third (what grows as
-the process serves more). Texts are matched to jobs by the order of the
-`client.collect` spans: the window sends its texts round-robin.
+the process serves more); above them the window's counters, the `serde.*`
+and how many rank maps were served (`device.rank_map_hit`) and built. Texts
+are matched to jobs by the order of the `client.collect` spans: the window
+sends its texts round-robin.
 """
 
 from __future__ import annotations
@@ -33,8 +35,11 @@ def report(cell: str) -> str:
     by_job = {}
     for s in log:
         by_job.setdefault(s.job, []).append(s)
-    out = [f"{cell}: {len(log)} spans, {len(roots)} queries, counters "
-           f"{tracing.drained()['counters']}"]
+    counters = tracing.drained()["counters"]
+    out = [f"{cell}: {len(log)} spans, {len(roots)} queries, counters {counters}",
+           f"rank maps (fact aggregates, a partition a query): "
+           f"{counters.get('device.rank_map_hit', 0)} served from the prepared partition, "
+           f"{counters.get('device.rank_map_build', 0)} built"]
     for i, text in enumerate(texts):
         mine = roots[i::len(texts)]
         if not mine:

@@ -36,9 +36,9 @@ def star(tmp_path):
     return tmp_path
 
 
-def _ctx(backend, star):
+def _ctx(backend, star, settings=None):
     ctx = ExecutionContext(
-        BallistaConfig({"ballista.executor.backend": backend})
+        BallistaConfig({"ballista.executor.backend": backend, **(settings or {})})
     )
     ctx.register_parquet("fact", str(star / "fact.parquet"))
     ctx.register_parquet("dim", str(star / "dim.parquet"))
@@ -361,8 +361,10 @@ Q_COUPLED = """
 """
 
 
-def _coupled_ctx(backend, star):
-    ctx = ExecutionContext(BallistaConfig({"ballista.executor.backend": backend}))
+def _coupled_ctx(backend, star, settings=None):
+    ctx = ExecutionContext(
+        BallistaConfig({"ballista.executor.backend": backend, **(settings or {})})
+    )
     for t in ("fact", "orders", "supplier", "nation"):
         ctx.register_parquet(t, str(star / f"{t}.parquet"))
     return ctx
@@ -560,3 +562,277 @@ def test_date_minmax_through_factagg(tmp_path):
     t, c = res["tpu"], res["cpu"]
     assert t.column("mn").to_pylist() == c.column("mn").to_pylist()
     assert t.column("mx").to_pylist() == c.column("mx").to_pylist()
+
+
+# -- rank maps kept with the prepared partition --------------------------
+# A stage's rank maps (which fact key ranks the dimension side holds, the
+# dimension row or coupling value at each) are a pure function of the
+# prepared partition and the dimension side: built by the first query, kept
+# inside the partition's pinned entry, served to every later one.
+
+PARTS = 3
+
+
+@pytest.fixture
+def star_parts(tmp_path):
+    """The star as directories of PARTS files each: with the aggregates not
+    coalesced, one partial aggregate (and one prepared partition) a file."""
+    rng = np.random.default_rng(5)
+    nf, nk = 8_000, 3000
+    (tmp_path / "fact").mkdir()
+    (tmp_path / "dim").mkdir()
+    for p in range(PARTS):
+        pq.write_table(
+            pa.table({
+                "fk": pa.array(rng.integers(0, nk, nf), type=pa.int64()),
+                "amount": pa.array(np.round(rng.uniform(1, 500, nf), 2)),
+            }),
+            str(tmp_path / "fact" / f"part-{p}.parquet"),
+        )
+        keys = np.arange(nk)[p::PARTS]
+        pq.write_table(
+            pa.table({
+                "dk": pa.array(keys, type=pa.int64()),
+                "attr": pa.array([f"grp-{i % 37}" for i in keys]),
+            }),
+            str(tmp_path / "dim" / f"part-{p}.parquet"),
+        )
+    return tmp_path
+
+
+def _parts_ctx(backend, star, settings=None):
+    ctx = ExecutionContext(BallistaConfig({
+        "ballista.executor.backend": backend,
+        "ballista.tpu.coalesce_aggregates": "false", **(settings or {})}))
+    ctx.register_parquet("fact", str(star / "fact"))
+    ctx.register_parquet("dim", str(star / "dim"))
+    return ctx
+
+
+def _rewrite_star_dim(star):
+    """Every third key leaves the dimension, the others change groups."""
+    keys = np.arange(3000)[np.arange(3000) % 3 != 1]
+    pq.write_table(
+        pa.table({
+            "dk": pa.array(keys, type=pa.int64()),
+            "attr": pa.array([f"new-{i % 11}" for i in keys]),
+            "region": pa.array([f"r{i % 5}" for i in keys]),
+        }),
+        str(star / "dim.parquet"),
+    )
+    return star / "dim.parquet"
+
+
+def _rewrite_parts_dim(star):
+    keys = np.arange(3000)[1::PARTS][::2]
+    pq.write_table(
+        pa.table({
+            "dk": pa.array(keys, type=pa.int64()),
+            "attr": pa.array([f"new-{i % 11}" for i in keys]),
+        }),
+        str(star / "dim" / "part-1.parquet"),
+    )
+    return star / "dim" / "part-1.parquet"
+
+
+def _rewrite_coupled_orders(star):
+    """The primary side's coupling values and its filter column redrawn."""
+    rng = np.random.default_rng(99)
+    pq.write_table(
+        pa.table({
+            "o_key": pa.array(np.arange(900), type=pa.int64()),
+            "o_flag": pa.array(rng.integers(0, 2, 900), type=pa.int64()),
+            "c_nat": pa.array(rng.integers(0, 8, 900), type=pa.int64()),
+        }),
+        str(star / "orders.parquet"),
+    )
+    return star / "orders.parquet"
+
+
+# path -> (fixture, context, text, partitions, exact columns, float columns,
+#          the rewrite of a DIMENSION file that changes the answer)
+_RANK_MAP_PATHS = {
+    "coupled_secondary": ("coupled_star", _coupled_ctx, Q_COUPLED, 1,
+                          ["nat_name"], ["rev"], _rewrite_coupled_orders),
+    "topk": ("star", _ctx, Q_TOPK, 1, ["fk", "attr"], ["rev"], _rewrite_star_dim),
+    "member_select": ("star_parts", _parts_ctx, Q_FULL.replace("avg(amount) as a, ", ""),
+                      PARTS, ["fk", "attr", "c"], ["s"], _rewrite_parts_dim),
+}
+
+
+def _fresh_stages():
+    from ballista_tpu.ops import runtime
+
+    kernels._stage_cache.clear()
+    kernels._stage_cache_pins.clear()
+    kernels._stage_latest.clear()
+    runtime.reset_residency()
+
+
+def _traced(ctx, sql):
+    """(table, counters, the `cached` of each runtime.dim_build that says)."""
+    from ballista_tpu.utils import tracing
+
+    tracing.reset()
+    table = ctx.sql(sql).collect()
+    cached = [s.attrs["cached"] for s in tracing.spans()
+              if s.name == "runtime.dim_build" and "cached" in s.attrs]
+    counters = tracing.counters()
+    tracing.reset()
+    return table, counters, cached
+
+
+def _the_stage(path):
+    (stage,) = _factagg_stages()
+    assert (stage.secondary is not None) == (path == "coupled_secondary")
+    assert (stage.topk is not None) == (path == "topk")
+    return stage
+
+
+def _assert_same_answer(got, want, exact, floats):
+    assert got.num_rows == want.num_rows > 0
+    for name in exact:
+        assert got.column(name).to_pylist() == want.column(name).to_pylist(), name
+    for name in floats:
+        np.testing.assert_allclose(
+            np.array(got.column(name).to_pylist()),
+            np.array(want.column(name).to_pylist()), rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("path", list(_RANK_MAP_PATHS))
+def test_rank_maps_are_built_by_the_first_query_and_serve_the_later_ones(path, request):
+    fixture, make_ctx, sql, parts, exact, floats, _ = _RANK_MAP_PATHS[path]
+    star = request.getfixturevalue(fixture)
+    _fresh_stages()
+    ctx = make_ctx("tpu", star)
+    (first, counters, cached), *later = [_traced(ctx, sql) for _ in range(3)]
+    stage = _the_stage(path)
+    assert sorted(stage._prepared) == list(range(parts))
+    assert counters.get("device.rank_map_build") == parts
+    assert "device.rank_map_hit" not in counters
+    assert cached.count(False) >= parts
+    for table, counters, cached in later:
+        assert counters.get("device.rank_map_hit") == parts
+        assert "device.rank_map_build" not in counters
+        assert cached and all(cached)
+        # the same program over the same arguments: column for column
+        assert table.equals(first)
+    _assert_same_answer(first, make_ctx("cpu", star).sql(sql).collect(), exact, floats)
+
+
+@pytest.mark.parametrize("path", list(_RANK_MAP_PATHS))
+def test_nothing_is_kept_with_the_device_cache_off(path, request):
+    from ballista_tpu.ops import runtime
+
+    fixture, make_ctx, sql, parts, exact, floats, _ = _RANK_MAP_PATHS[path]
+    star = request.getfixturevalue(fixture)
+    _fresh_stages()
+    kept = make_ctx("tpu", star).sql(sql).collect()
+    _fresh_stages()
+    ctx = make_ctx("tpu", star, {"ballista.tpu.device_cache": "false"})
+    for _ in range(2):
+        table, counters, cached = _traced(ctx, sql)
+        assert counters.get("device.rank_map_build") == parts
+        assert "device.rank_map_hit" not in counters
+        assert cached and not any(cached)
+        assert table.equals(kept)
+    stage = _the_stage(path)
+    assert not stage._prepared and stage._dim_cache is None
+    assert runtime.resident_bytes() == 0
+
+
+@pytest.mark.parametrize("path", list(_RANK_MAP_PATHS))
+def test_a_rewritten_dimension_file_is_answered_from_the_new_rows(path, request):
+    import os
+
+    from ballista_tpu.ops import runtime
+
+    fixture, make_ctx, sql, parts, exact, floats, rewrite = _RANK_MAP_PATHS[path]
+    star = request.getfixturevalue(fixture)
+    _fresh_stages()
+    ctx = make_ctx("tpu", star)
+    before = [_traced(ctx, sql)[0] for _ in range(2)][-1]
+    old = _the_stage(path)
+    assert all("rank_maps" in ent for ent in old._prepared.values())
+
+    changed = rewrite(star)
+    stamp = os.path.getmtime(changed) + 2  # past any clock's granularity
+    os.utime(changed, (stamp, stamp))
+    want = make_ctx("cpu", star).sql(sql).collect()
+    table, counters, _ = _traced(make_ctx("tpu", star), sql)
+    _assert_same_answer(table, want, exact, floats)
+    assert not table.equals(before), "the rewrite did not change the answer"
+    # the stage was superseded whole: no map of the old rows is reachable
+    assert old._retired and not old._prepared
+    new = _the_stage(path)
+    assert new is not old
+    assert counters.get("device.rank_map_build") == parts
+    assert "device.rank_map_hit" not in counters
+    assert runtime.resident_bytes() == sum(
+        runtime.entry_device_bytes(ent) for ent in new._prepared.values())
+
+
+@pytest.mark.parametrize("how", ["evicted", "released"])
+@pytest.mark.parametrize("path", list(_RANK_MAP_PATHS))
+def test_the_maps_go_with_their_partition_and_are_built_again(path, how, request):
+    from ballista_tpu.ops import runtime
+
+    fixture, make_ctx, sql, parts, exact, floats, _ = _RANK_MAP_PATHS[path]
+    star = request.getfixturevalue(fixture)
+    _fresh_stages()
+    ctx = make_ctx("tpu", star)
+    first = [_traced(ctx, sql)[0] for _ in range(2)][-1]
+    stage = _the_stage(path)
+    held = runtime.resident_bytes()
+    assert held == sum(runtime.entry_device_bytes(e) for e in stage._prepared.values())
+    assert sum(runtime.entry_device_bytes(e["rank_maps"])
+               for e in stage._prepared.values()) > 0
+    if how == "evicted":
+        # another stage asks for all of a budget this one fills
+        runtime.make_headroom(object(), held, held)
+    else:
+        runtime.release_stage_residency(stage)
+    assert runtime.resident_bytes() == 0 and not stage._prepared
+    table, counters, _ = _traced(ctx, sql)
+    assert counters.get("device.rank_map_build") == parts
+    assert "device.rank_map_hit" not in counters
+    assert table.equals(first)
+    if how == "evicted":
+        assert runtime.resident_bytes() == held
+        assert _traced(ctx, sql)[1].get("device.rank_map_hit") == parts
+    else:
+        # a retired stage pins nothing again
+        assert runtime.resident_bytes() == 0
+
+
+def test_concurrent_first_queries_keep_one_set_of_maps_a_partition(star_parts):
+    """Queries that meet on a stage nobody has run yet may each build a
+    partition's maps: every one answers the same, and what stays reserved is
+    one set a partition."""
+    import threading
+
+    from ballista_tpu.ops import runtime
+
+    _, make_ctx, sql, parts, *_ = _RANK_MAP_PATHS["member_select"]
+    _fresh_stages()
+    tables, errors = [], []
+
+    def client():
+        try:
+            ctx = make_ctx("tpu", star_parts)
+            tables.extend(ctx.sql(sql).collect() for _ in range(3))
+        except Exception as e:  # the assertion below reports it
+            errors.append(e)
+
+    threads = [threading.Thread(target=client) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads) and not errors, errors
+    assert len(tables) == 12 and all(t.equals(tables[0]) for t in tables)
+    stage = _the_stage("member_select")
+    assert sorted(stage._prepared) == list(range(parts))
+    assert all("rank_maps" in ent for ent in stage._prepared.values())
+    assert runtime.resident_bytes() == sum(
+        runtime.entry_device_bytes(ent) for ent in stage._prepared.values())

@@ -94,6 +94,130 @@ def test_release_stage_clears_lru_bookkeeping():
     assert not runtime.reserve_and_pin(a, 0, {"x": 1}, a._device_cache, 10, 100)
 
 
+def _device(n):
+    import jax.numpy as jnp
+
+    return jnp.zeros(n, dtype=jnp.int8)  # n device bytes
+
+
+def test_attach_to_pinned_grows_the_reservation_and_goes_with_the_entry():
+    a = _FakeStage()
+    ent = {"x": 1}
+    assert runtime.reserve_and_pin(a, 0, ent, a._device_cache, 40, 100)
+    assert runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", {"d": _device(30)}, 100)
+    assert runtime.resident_bytes() == 70 and runtime._reservations[(id(a), 0)] == 70
+    # a second builder of the same thing reserves it once; a smaller one shrinks it
+    assert runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", {"d": _device(30)}, 100)
+    assert runtime.resident_bytes() == 70
+    assert runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", {"d": _device(10)}, 100)
+    assert runtime.resident_bytes() == 50 and ent["m"]["d"].nbytes == 10
+    runtime.release_stage_residency(a)
+    assert runtime.resident_bytes() == 0 and not runtime._reservations
+
+
+@pytest.mark.parametrize("case", ["not_pinned", "another_entry", "evicted", "no_room"])
+def test_attach_to_pinned_keeps_nothing_where_the_entry_is_not_kept(case):
+    a, b = _FakeStage(), _FakeStage()
+    ent = {"x": 1}
+    if case != "not_pinned":
+        assert runtime.reserve_and_pin(a, 0, ent, a._device_cache, 40, 100)
+    target = {"x": 2} if case == "another_entry" else ent
+    if case == "evicted":
+        assert runtime.reserve_and_pin(b, 0, {"y": 1}, b._device_cache, 80, 100)
+        assert 0 not in a._device_cache
+    held = runtime.resident_bytes()
+    size = 70 if case == "no_room" else 10  # 40 + 70 > 100, and its own pin is no victim
+    assert not runtime.attach_to_pinned(a, 0, target, a._device_cache, "m", {"d": _device(size)}, 100)
+    assert "m" not in target and runtime.resident_bytes() == held
+
+
+def test_attach_to_pinned_evicts_another_stage_s_oldest_pin_for_room():
+    a, b = _FakeStage(), _FakeStage()
+    ent = {"x": 1}
+    assert runtime.reserve_and_pin(b, 0, {"y": 1}, b._device_cache, 50, 100)
+    assert runtime.reserve_and_pin(a, 0, ent, a._device_cache, 40, 100)
+    assert runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", {"d": _device(30)}, 100)
+    assert 0 not in b._device_cache and runtime.resident_bytes() == 70
+
+
+def test_racing_builders_of_one_value_reserve_it_once():
+    """Task threads of two queries on one partition may both miss and both
+    attach what they built: the reservation holds it once, whoever wins, and
+    an eviction between two attaches leaves nothing behind."""
+    import sys
+    import threading
+
+    a, b = _FakeStage(), _FakeStage()
+    ent = {"x": 1}
+    assert runtime.reserve_and_pin(a, 0, ent, a._device_cache, 40, 100)
+    value = {"d": _device(30)}
+    kept = []
+
+    def build():
+        for _ in range(200):
+            kept.append(runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", dict(value), 100))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=build) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(kept) == 16 * 200 and all(kept)
+    assert runtime.resident_bytes() == 70 == runtime._reservations[(id(a), 0)]
+    # evicted for another stage: the later attach keeps nothing
+    assert runtime.reserve_and_pin(b, 0, {"y": 1}, b._device_cache, 90, 100)
+    assert not runtime.attach_to_pinned(a, 0, ent, a._device_cache, "m", dict(value), 100)
+    assert runtime.resident_bytes() == 90
+
+
+def test_a_factagg_partition_s_reservation_counts_its_rank_maps(tmp_path):
+    """What a fact aggregate keeps on the device beside its tiles (PR 29:
+    the rank maps built by the first query) is inside the partition's
+    reservation: the budget sees it, and a release gives it back."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from ballista_tpu.config import BallistaConfig
+    from ballista_tpu.engine import ExecutionContext
+    from ballista_tpu.ops import kernels
+    from ballista_tpu.ops.factagg import FactAggregateStage
+
+    rng = np.random.default_rng(3)
+    pq.write_table(
+        pa.table({"fk": pa.array(rng.integers(0, 2000, 30_000), type=pa.int64()),
+                  "v": pa.array(rng.uniform(0, 10, 30_000))}),
+        str(tmp_path / "fact.parquet"))
+    pq.write_table(
+        pa.table({"dk": pa.array(np.arange(2000), type=pa.int64()),
+                  "attr": pa.array([f"a{i % 7}" for i in range(2000)])}),
+        str(tmp_path / "dim.parquet"))
+    kernels._stage_cache.clear()
+    kernels._stage_latest.clear()
+    ctx = ExecutionContext(BallistaConfig({"ballista.executor.backend": "tpu"}))
+    ctx.register_parquet("fact", str(tmp_path / "fact.parquet"))
+    ctx.register_parquet("dim", str(tmp_path / "dim.parquet"))
+    sql = "select fk, sum(v) as s, attr from dim, fact where dk = fk group by fk, attr order by fk"
+    tiles = None
+    for _ in range(2):
+        ctx.sql(sql).collect()
+        (stage,) = [s for s in kernels._stage_cache.values()
+                    if isinstance(s, FactAggregateStage)]
+        (ent,) = stage._prepared.values()
+        maps = runtime.entry_device_bytes(ent["rank_maps"])
+        tiles = tiles or runtime.entry_device_bytes(ent) - maps
+        assert maps > 0
+        assert runtime._reservations[(id(stage), 0)] == tiles + maps
+        assert runtime.resident_bytes() == tiles + maps
+    runtime.release_stage_residency(stage)
+    assert runtime.resident_bytes() == 0
+
+
 def test_stage_past_budget_declines_to_host(tmp_path):
     """A stage whose tiles cannot fit the HBM budget must decline BEFORE
     device allocation (host fallback), not OOM the chip — and results stay
