@@ -19,6 +19,7 @@ import pyarrow.flight as flight
 from ballista_tpu.distributed.stages import PartitionStats
 from ballista_tpu.errors import RpcError
 from ballista_tpu.proto import ballista_pb2 as pb
+from ballista_tpu.utils import tracing
 
 
 class BallistaClient:
@@ -61,9 +62,7 @@ class BallistaClient:
             except flight.FlightError as e:
                 if not self._transient(e) or i + 1 >= attempts:
                     raise RpcError(f"executor {self.host}:{self.port}: {e}") from e
-                from ballista_tpu.ops.runtime import record_recovery
-
-                record_recovery("rpc_retry")
+                tracing.incr("recovery.rpc_retry")
                 import time
 
                 time.sleep(backoff_delay(i, self.backoff_s))
@@ -89,9 +88,7 @@ class BallistaClient:
             except flight.FlightError as e:
                 if yielded or not self._transient(e) or i + 1 >= attempts:
                     raise RpcError(f"executor {self.host}:{self.port}: {e}") from e
-                from ballista_tpu.ops.runtime import record_recovery
-
-                record_recovery("rpc_retry")
+                tracing.incr("recovery.rpc_retry")
                 import time
 
                 time.sleep(backoff_delay(i, self.backoff_s))

@@ -179,10 +179,9 @@ class _ExchangeCapture:
         """Register the captured pieces; `finals` maps piece idx -> the
         published on-disk path. Returns whether anything was kept."""
         from ballista_tpu.ops import exchange
-        from ballista_tpu.ops.runtime import record_exchange
 
         if self.overflow:
-            record_exchange("skipped_budget")
+            tracing.incr("exchange.skipped_budget")
             return False
         kept = False
         for piece, batches in self.pieces.items():
@@ -246,7 +245,6 @@ class ShuffleWriterExec(ExecutionPlan):
             return None
 
         def pre_publish() -> None:
-            from ballista_tpu.ops.runtime import record_shuffle_tier
             from ballista_tpu.utils.chaos import ChaosInjected
 
             try:
@@ -255,7 +253,7 @@ class ShuffleWriterExec(ExecutionPlan):
                     f"w{self.stage_id}/{partition}@a{ctx.attempt}",
                 )
             except ChaosInjected:
-                record_shuffle_tier("storage_publish_torn")
+                tracing.incr("shuffle_tier.storage_publish_torn")
                 raise
 
         return pre_publish
@@ -274,8 +272,6 @@ class ShuffleWriterExec(ExecutionPlan):
         return stats
 
     def _shuffle_write(self, partition: int, ctx: TaskContext) -> PartitionStats:
-        from ballista_tpu.ops.runtime import record_shuffle_tier
-
         base, storage_uri = shuffle_output_base(
             ctx, self.job_id, self.stage_id, partition
         )
@@ -302,9 +298,8 @@ class ShuffleWriterExec(ExecutionPlan):
                 teed(), schema, piece_path, codec=codec,
                 pre_publish=pre_publish,
             )
-            record_shuffle_tier(
-                "storage_publish" if storage_uri else "local_publish"
-            )
+            tracing.incr("shuffle_tier.storage_publish" if storage_uri
+                         else "shuffle_tier.local_publish")
             if capture is not None:
                 # only after the atomic publish: the registry must never
                 # advertise a piece the ladder cannot also produce
@@ -362,9 +357,8 @@ class ShuffleWriterExec(ExecutionPlan):
                     if os.path.exists(tmp):
                         os.unlink(tmp)
         if ok:
-            record_shuffle_tier(
-                "storage_publish" if storage_uri else "local_publish"
-            )
+            tracing.incr("shuffle_tier.storage_publish" if storage_uri
+                         else "shuffle_tier.local_publish")
             if capture is not None:
                 capture.publish(schema, dict(enumerate(finals)))
         return total
@@ -573,7 +567,6 @@ class ShuffleReaderExec(ExecutionPlan):
             # on ctx.executor_id, so a StandaloneCluster's co-resident
             # executors never see false "local" hits.
             from ballista_tpu.ops import exchange
-            from ballista_tpu.ops.runtime import record_exchange
 
             if chaos is not None and chaos.should_inject(
                 "exchange.evict",
@@ -583,26 +576,24 @@ class ShuffleReaderExec(ExecutionPlan):
                 # seeded eviction between produce and consume: drop the
                 # entry and take the ladder — a cache going cold is never
                 # a task failure, so zero retries by construction
-                from ballista_tpu.ops.runtime import record_recovery
-
-                record_recovery("chaos_injected")
+                tracing.incr("recovery.chaos_injected")
                 if exchange.evict(
                     ctx.executor_id, ctx.job_id, loc.stage_id,
                     loc.map_partition, piece_idx,
                 ):
-                    record_exchange("evicted_chaos")
+                    tracing.incr("exchange.evicted_chaos")
             hit = exchange.resolve(
                 ctx.executor_id, ctx.job_id, loc.stage_id,
                 loc.map_partition, piece_idx,
             )
             if hit is not None:
                 batches, nbytes = hit
-                record_exchange("reupload_skipped")
-                record_exchange("h2d_bytes_saved", nbytes)
+                tracing.incr("exchange.reupload_skipped")
+                tracing.incr("exchange.h2d_bytes_saved", nbytes)
                 via.append("resident")
                 yield from batches
                 return
-            record_exchange("miss")
+            tracing.incr("exchange.miss")
         if loc.storage_uri:
             # disaggregated tier (ISSUE 15): the piece's home is a PATH —
             # resolve it from the shared mount first. A shuffle.store READ
@@ -612,27 +603,22 @@ class ShuffleReaderExec(ExecutionPlan):
             # fetch below, then fetch_failed -> lineage recompute — the
             # recomputed map republishes and the requeued consumer's fresh
             # attempt draws a fresh verdict.
-            from ballista_tpu.ops.runtime import (
-                record_recovery,
-                record_shuffle_tier,
-            )
-
             torn = chaos is not None and chaos.should_inject(
                 "shuffle.store",
                 f"r{loc.stage_id}/{loc.map_partition}/piece{piece_idx}"
                 f"@a{ctx.attempt}",
             )
             if torn:
-                record_recovery("chaos_injected")
-                record_shuffle_tier("storage_read_torn")
+                tracing.incr("recovery.chaos_injected")
+                tracing.incr("shuffle_tier.storage_read_torn")
             else:
                 resolved = self._storage_read_path(piece, ctx)
                 if resolved is not None and os.path.exists(resolved):
-                    record_shuffle_tier("storage_fetch")
+                    tracing.incr("shuffle_tier.storage_fetch")
                     via.append("storage")
                     yield from read_ipc_file(resolved)
                     return
-            record_shuffle_tier("storage_fallback_peer")
+            tracing.incr("shuffle_tier.storage_fallback_peer")
             if not loc.host or not loc.port:
                 # no live peer to fall back to (the producing executor is
                 # gone and its metadata never bound): the piece is LOST for
@@ -652,9 +638,7 @@ class ShuffleReaderExec(ExecutionPlan):
             via.append("local")
             yield from read_ipc_file(resolved)
         elif ctx.shuffle_fetcher is not None:
-            from ballista_tpu.ops.runtime import record_shuffle_tier
-
-            record_shuffle_tier("peer_fetch")
+            tracing.incr("shuffle_tier.peer_fetch")
             via.append("flight")
             try:
                 yield from ctx.shuffle_fetcher(loc, piece_idx)

@@ -67,15 +67,14 @@ class BallistaFlightService(flight.FlightServerBase):
                 # indexes paths this executor published itself, so a miss
                 # falls through to the ordinary confined file read.
                 from ballista_tpu.ops import exchange
-                from ballista_tpu.ops.runtime import record_exchange
 
                 hit = exchange.resolve_path(path) or exchange.resolve_path(
                     action.fetch_partition.path
                 )
                 if hit is not None:
                     schema, batches, nbytes = hit
-                    record_exchange("served_from_registry")
-                    record_exchange("d2h_bytes_saved", nbytes)
+                    tracing.incr("exchange.served_from_registry")
+                    tracing.incr("exchange.d2h_bytes_saved", nbytes)
                     return flight.GeneratorStream(schema, iter(batches))
             if not os.path.isfile(path):
                 raise flight.FlightServerError(f"no such shuffle piece: {path}")

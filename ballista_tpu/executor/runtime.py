@@ -24,6 +24,7 @@ from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import KvBackend, MemoryBackend
 from ballista_tpu.scheduler.rpc import SchedulerGrpcClient
 from ballista_tpu.scheduler.server import SchedulerServer, serve
+from ballista_tpu.utils import tracing
 
 log = logging.getLogger("ballista.executor")
 
@@ -232,8 +233,7 @@ class StandaloneCluster:
 
     def autoscale_once(self) -> int:
         """One autoscaler evaluation; returns the executor delta applied
-        (+n grown, -1 drained, 0 no action). Public so tests and the bench
-        harness can drive evaluations deterministically.
+        (+n grown, -1 drained, 0 no action). Public so tests can drive evaluations deterministically.
 
         Policy: desired = clamp(ceil(backlog / target_backlog_s),
         [min, max]) on a loaded queue — a deep backlog grows the fleet in
@@ -243,12 +243,6 @@ class StandaloneCluster:
         starts."""
         import math
 
-        from ballista_tpu.ops.runtime import (
-            record_fleet,
-            record_fleet_gauge,
-            record_recovery,
-        )
-
         fmin, fmax = self.config.fleet_min(), self.config.fleet_max()
         if fmax <= 0:
             return 0
@@ -257,9 +251,7 @@ class StandaloneCluster:
             backlog = state.predicted_backlog_seconds()
             running = state.has_running_tasks()
         size = self.fleet_size()
-        record_fleet("evaluations")
-        record_fleet_gauge("backlog_ms", backlog * 1000.0)
-        record_fleet_gauge("fleet_size", float(size))
+        tracing.incr("fleet.evaluations")
         target = self.config.fleet_target_backlog_s()
         desired = size
         if backlog > target and size < fmax:
@@ -277,8 +269,8 @@ class StandaloneCluster:
             ):
                 # torn BEFORE any executor is touched: the fleet keeps its
                 # size this evaluation; the next draws a fresh verdict
-                record_recovery("chaos_injected")
-                record_fleet("scale_chaos_skipped")
+                tracing.incr("recovery.chaos_injected")
+                tracing.incr("fleet.scale_chaos_skipped")
                 log.warning(
                     "chaos[fleet.scale]: scale %d -> %d skipped",
                     size, desired,
@@ -287,8 +279,7 @@ class StandaloneCluster:
         if desired > size:
             for _ in range(desired - size):
                 self._spawn_executor()
-            record_fleet("scale_up", desired - size)
-            record_fleet_gauge("fleet_size", float(desired))
+            tracing.incr("fleet.scale_up", desired - size)
             log.info("fleet scaled out %d -> %d (backlog %.2fs)",
                      size, desired, backlog)
             return desired - size
@@ -298,14 +289,12 @@ class StandaloneCluster:
         """Gracefully retire the newest executor: drain (stop offering
         slots, finish — and report — running tasks), stop, remove. The ONE
         scale-in mechanism, shared by the autoscaler and operator-driven
-        scale-in (tests/bench drive it mid-job: on the shared shuffle tier
+        scale-in (tests drive it mid-job: on the shared shuffle tier
         the retiree's completed outputs stay readable from storage, so a
         running job finishes with zero task retries). The drain runs
         outside the fleet lock — it can take as long as the executor's
         in-flight work. Returns False when the fleet is already at
         `floor`."""
-        from ballista_tpu.ops.runtime import record_fleet, record_fleet_gauge
-
         with self._fleet_mu:
             if len(self.executors) <= max(1, floor):
                 return False
@@ -325,8 +314,7 @@ class StandaloneCluster:
             if ex in self.executors:
                 self.executors.remove(ex)
             size2 = len(self.executors)
-        record_fleet("scale_down")
-        record_fleet_gauge("fleet_size", float(size2))
+        tracing.incr("fleet.scale_down")
         log.info("fleet scaled in: retired %s (%d -> %d)", ex.id, size, size2)
         return True
 

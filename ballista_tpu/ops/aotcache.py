@@ -43,6 +43,8 @@ import os
 import tempfile
 import threading
 from typing import Dict, List, Optional, Tuple
+
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 log = logging.getLogger("ballista.tpu.aot")
@@ -57,12 +59,6 @@ _chaos = None  # guarded-by: _lock
 _mem: Dict[str, Tuple[str, object]] = {}  # guarded-by: _lock
 _manifest_keys: Optional[set] = None  # lazily loaded; guarded-by: _lock
 _fingerprint_cache: Optional[str] = None
-
-
-def _record(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_serving
-
-    record_serving(event, n)
 
 
 def fingerprint() -> str:
@@ -179,7 +175,7 @@ def _save_artifact(base: str, key: str, name: str, blob: bytes) -> None:
                 with open(_manifest_path(base), "a") as f:
                     f.write(json.dumps({"key": key, "name": name}) + "\n")
                 keys.add(key)
-        _record("aot_saved")
+        tracing.incr("serving.aot_saved")
     except Exception as e:
         log.debug("aot save failed (key=%s...): %s", key[:16], e)
 
@@ -203,7 +199,7 @@ def _read_artifact(base: str, key: str) -> Optional[bytes]:
         header, _, blob = payload.partition(b"\n")
         meta = json.loads(header)
         if meta.get("fingerprint") != fingerprint():
-            _record("aot_load_error")
+            tracing.incr("serving.aot_load_error")
             log.warning(
                 "aot artifact %s... rejected: fingerprint %r != %r "
                 "(recompiling fresh)", key[:16], meta.get("fingerprint"),
@@ -214,12 +210,12 @@ def _read_artifact(base: str, key: str) -> Optional[bytes]:
             raise ValueError("empty program blob")
         return blob
     except ChaosInjected as e:
-        _record("aot_load_error")
+        tracing.incr("serving.aot_load_error")
         log.warning("aot load torn by chaos (key=%s...): %s — recompiling "
                     "fresh", key[:16], e)
         return None
     except Exception as e:
-        _record("aot_load_error")
+        tracing.incr("serving.aot_load_error")
         log.warning("aot artifact %s... unreadable: %s — recompiling fresh",
                     key[:16], e)
         return None
@@ -281,8 +277,6 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
     accounting."""
     import jax
     from jax.tree_util import tree_flatten, tree_unflatten
-
-    from ballista_tpu.utils import tracing
 
     core = _named(name, core)
     jitfn = jax.jit(core, static_argnums=static_argnums)
@@ -357,7 +351,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             entry = _mem.get(key)
         if entry is not None:
             kind, compiled = entry
-            _record("compile_hit_memory")
+            tracing.incr("serving.compile_hit_memory")
             launch.set(tier="memory")
             if compiled is None:  # freshly traced this process: jit caches
                 return jitfn(*args)
@@ -369,7 +363,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
                 compiled = _compile_exported(blob, avals, name)
                 out_flat = compiled(*leaves)
             except Exception as e:
-                _record("aot_load_error")
+                tracing.incr("serving.aot_load_error")
                 log.warning(
                     "aot artifact %s... failed to compile/run: %s — "
                     "recompiling fresh", key[:16], e,
@@ -377,7 +371,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             else:
                 with _lock:
                     _mem[key] = ("disk", compiled)
-                _record("compile_hit_disk")
+                tracing.incr("serving.compile_hit_disk")
                 launch.set(tier="disk")
                 return out_flat
         # fresh program: run the PLAIN jit first (its persistent-XLA-cache
@@ -386,7 +380,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
         # Compiling THROUGH the exported module here would key the
         # persistent XLA cache differently and recompile from scratch
         # (measured ~15s per big program, a whole-suite stall).
-        _record("compile_trace")
+        tracing.incr("serving.compile_trace")
         launch.set(tier="trace")
         out = jitfn(*args)
         with _lock:
@@ -402,7 +396,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             # no AOT tier for this call: still prime jit's executable
             # cache so the next real call neither traces nor compiles
             jitfn.lower(*args).compile()
-            _record("compile_warmed")
+            tracing.incr("serving.compile_warmed")
             return True
         key, statics, treedef, leaves, avals = resolved
         with _lock:
@@ -414,7 +408,7 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             try:
                 compiled = _compile_exported(blob, avals, name)
             except Exception as e:
-                _record("aot_load_error")
+                tracing.incr("serving.aot_load_error")
                 log.warning(
                     "aot artifact %s... failed to compile during warm: %s "
                     "— compiling fresh", key[:16], e,
@@ -422,12 +416,12 @@ def wrap_step(owner, name: str, core, static_argnums: Tuple[int, ...] = (0,)):
             else:
                 with _lock:
                     _mem.setdefault(key, ("disk", compiled))
-                _record("compile_hit_disk")
+                tracing.incr("serving.compile_hit_disk")
                 return True
         jitfn.lower(*args).compile()
         with _lock:
             _mem.setdefault(key, ("fresh", None))
-        _record("compile_warmed")
+        tracing.incr("serving.compile_warmed")
         export_and_save(key, statics, treedef, avals, len(args))
         return True
 
@@ -466,13 +460,13 @@ def prewarm(config) -> int:
                 jax.jit(exported.call).lower(*exported.in_avals).compile()
             )
         except Exception as e:
-            _record("aot_load_error")
+            tracing.incr("serving.aot_load_error")
             log.warning("prewarm of %s... failed: %s", key[:16], e)
             continue
         with _lock:
             _mem[key] = ("prewarm", compiled)
         warmed += 1
-        _record("compile_prewarmed")
+        tracing.incr("serving.compile_prewarmed")
     if warmed:
         log.info("aot prewarm: %d compiled programs ready", warmed)
     return warmed

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 import pyarrow as pa
 
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 _reg_lock = make_lock("ops.exchange._reg_lock")
@@ -119,13 +120,11 @@ def publish(executor_id: str, job_id: str, stage_id: int, map_partition: int,
     tenant's giant shuffle evicts its own cold pieces first and can
     never displace another tenant's to fit itself.
     """
-    from ballista_tpu.ops.runtime import record_exchange
-
     nbytes = sum(b.nbytes for b in batches)
     if nbytes <= 0 or nbytes > budget or (
         0 < tenant_budget < nbytes
     ):
-        record_exchange("skipped_budget")
+        tracing.incr("exchange.skipped_budget")
         return False
     # price the incomer BEFORE the lock: _reg_lock is a leaf and must not
     # reach into the cost model while held
@@ -188,14 +187,14 @@ def publish(executor_id: str, job_id: str, stage_id: int, map_partition: int,
             _total_bytes += nbytes
             _tenant_bytes[tenant] = _tenant_bytes.get(tenant, 0) + nbytes
     if not kept:
-        record_exchange("skipped_budget")
+        tracing.incr("exchange.skipped_budget")
         return False
     if tenant_evicted:
-        record_exchange("evicted_tenant_budget", tenant_evicted)
+        tracing.incr("exchange.evicted_tenant_budget", tenant_evicted)
     if evicted:
-        record_exchange("evicted_budget", evicted)
-    record_exchange("published")
-    record_exchange("publish_bytes", nbytes)
+        tracing.incr("exchange.evicted_budget", evicted)
+    tracing.incr("exchange.published")
+    tracing.incr("exchange.publish_bytes", nbytes)
     return True
 
 

@@ -40,8 +40,9 @@ rows in stable sorted order within a probe key — so device results are
 bit-identical to the host oracle, multiplicity and order included.
 
 Every decline flows through the canonical kernels helpers AND
-runtime.record_join_path, so bench.py's per-config join-path counters
-(device / split / step_aside / host_fallback, with reasons) stay truthful;
+runtime.record_join_path, so the benchmark's `join_paths` (device / split /
+step_aside / host_fallback, with reasons; benchmarks/chip/run.py::
+drain_counters reads join_path_stats) stay truthful;
 every engine choice additionally lands in the routing accumulator
 (runtime.record_routing) with its predicted-vs-observed cost.
 """
@@ -149,7 +150,7 @@ def _gather_kernel(width: int):
 
 
 def _decline(kind: str, reason: str) -> None:
-    """Join decline: record the path for bench's per-config join counters
+    """Join decline: record the path for join_path_stats
     (`kind` distinguishes admission-tier "step_aside" declines from other
     "host_fallback" declines), then route through the canonical
     host_fallback helper — either way the join leaves the device entirely,

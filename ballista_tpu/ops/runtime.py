@@ -234,7 +234,7 @@ _EVICT_COST_RATIO = 4
 # LRU would make EVERY query a full re-prepare (both stages thrash); after
 # one thrash cycle the cooldown pins the survivor and the other streams —
 # the same steady state first-come residency gave that pattern, while
-# sequential workloads (the bench / the 22-query suite) still evict freely
+# sequential workloads (the 22-query suite) still evict freely
 _EVICT_COOLDOWN_S = 60.0
 _evicted_at: dict = {}  # id(stage) -> last eviction time; guarded-by: _res_lock
 
@@ -717,7 +717,8 @@ def pipelined_map(src, fn, workers: int, depth: int = 2, on_src_time=None):
         ex.shutdown(wait=False)
 
 
-# accumulated ingest timings across stage prepares (bench.py reports them):
+# accumulated ingest timings across stage prepares (the benchmark's
+# `prepares` and chip_smoke.py read them):
 # scan_s = prefetch-stage work (parquet read + dictionary decode + group
 # ranking), encode_s = host narrow/encode, upload_s = h2d transfer, wall_s =
 # end-to-end prepare. overlap_frac = 1 - wall / (scan + encode + upload):
@@ -755,8 +756,8 @@ def ingest_stats(reset: bool = False) -> Dict[str, float]:
     return out
 
 
-# accumulated device->host result readback across stage runs (bench.py
-# reports rows/bytes per config): every aggregate-result d2h transfer on
+# accumulated device->host result readback across stage runs (the
+# benchmark's `readback`, benchmarks/chip/run.py::drain_counters): every aggregate-result d2h transfer on
 # the device paths — full-column, fused top-k, fact-agg member/top-k —
 # records its width here. rows = trailing-axis length of each fetched
 # result (groups or selected candidates), bytes = the packed f32 transfer
@@ -783,9 +784,9 @@ def readback(x, rows: Optional[int] = None) -> np.ndarray:
     dev/analysis's readback-discipline pass.
 
     With the cost model enabled (ISSUE 10), the transfer's wall time lands
-    in the cost store as a per-byte readback observation (bench
-    observability + groundwork for transfer-aware admission; no predictor
-    consults it yet). The producing computation is synced FIRST so the
+    in the cost store as a per-byte readback observation (observability +
+    groundwork for transfer-aware admission; no predictor consults it
+    yet). The producing computation is synced FIRST so the
     timer measures the d2h transfer, not whatever async dispatch happens
     to still be in flight."""
     from ballista_tpu.ops import costmodel
@@ -810,12 +811,12 @@ def readback_stats(reset: bool = False) -> Dict[str, int]:
     return out
 
 
-# accumulated join-path outcomes across join executions (bench.py reports
-# them per config): every device-join attempt lands in exactly one bucket —
+# accumulated join-path outcomes across join executions (the benchmark's
+# `join_paths`): every device-join attempt lands in exactly one bucket —
 # "device" (the M:N kernel or the mesh program produced the result),
 # "step_aside" (the multiplicity/gather admission tier declined, host join
 # ran instead), or "host_fallback" (any other decline or error). Reasons are
-# counted verbatim so a bench row says WHY a join left the device path.
+# counted verbatim so a run's record says WHY a join left the device path.
 _join_lock = make_lock("ops.runtime._join_lock")
 # guarded-by: _join_lock
 _join_paths: Dict[str, int] = {}  # path -> count
@@ -846,275 +847,11 @@ def join_path_stats(reset: bool = False) -> Dict[str, Dict[str, int]]:
     return out
 
 
-# accumulated failure-recovery events across scheduler/executor/client
-# (bench.py reports them per config beside readback/join_paths): every
-# retry, lineage recompute, stale-report drop, transient-RPC retry, and
-# chaos injection lands in exactly one named bucket, so a bench row under
-# `ballista.chaos.rate` > 0 shows both the injected faults AND the recovery
-# work they triggered. In-process accumulator like the readback totals —
-# the standalone cluster (scheduler + executors in one process) is where
-# chaos runs live; separate daemons each report their own share.
-_recovery_lock = make_lock("ops.runtime._recovery_lock")
-# guarded-by: _recovery_lock
-_recovery: Dict[str, int] = {}  # event -> count
-
-
-def record_recovery(event: str, n: int = 1) -> None:
-    with _recovery_lock:
-        _recovery[event] = _recovery.get(event, 0) + int(n)
-
-
-def recovery_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated recovery-event counters."""
-    with _recovery_lock:
-        out = dict(_recovery)
-        if reset:
-            _recovery.clear()
-    return out
-
-
-# accumulated multi-tenant serving events (ISSUE 7): result-cache hits /
-# misses / puts / invalidations, plan-cache hits, and admission quota
-# deferrals. Same in-process accumulator pattern as the recovery counters;
-# bench.py's multi-tenant scenario reports cache-hit rate and per-tenant
-# fairness off these plus the scheduler's per-tenant assignment ledger.
-_tenancy_lock = make_lock("ops.runtime._tenancy_lock")
-# guarded-by: _tenancy_lock
-_tenancy: Dict[str, int] = {}  # event -> count
-
-
-def record_tenancy(event: str, n: int = 1) -> None:
-    with _tenancy_lock:
-        _tenancy[event] = _tenancy.get(event, 0) + int(n)
-
-
-def tenancy_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated multi-tenant serving counters."""
-    with _tenancy_lock:
-        out = dict(_tenancy)
-        if reset:
-            _tenancy.clear()
-    return out
-
-
-# accumulated low-latency serving-tier events (ISSUE 8): dispatch-path
-# counts (dispatch_push vs dispatch_poll — the latency harness asserts a
-# warm push-enabled cluster runs with ZERO poll-dispatched tasks),
-# compiled-program cache outcomes (compile_trace = a fresh Python trace +
-# XLA compile happened; compile_hit_memory / compile_hit_disk /
-# compile_prewarmed = the AOT tier served it; aot_load_error = corrupt or
-# version-mismatched artifact fell back, with the reason recorded by the
-# caller's log), push-stream health (push_subscribed counts every
-# successful stream open — re-subscribes included — and push_stream_drop
-# every loss), and streaming-collect progress (stream_partition_early = a result
-# partition fetched before the job completed). Same in-process accumulator
-# pattern as readback/join_paths/recovery/tenancy above.
-_serving_lock = make_lock("ops.runtime._serving_lock")
-# guarded-by: _serving_lock
-_serving: Dict[str, int] = {}  # event -> count
-
-
-def record_serving(event: str, n: int = 1) -> None:
-    with _serving_lock:
-        _serving[event] = _serving.get(event, 0) + int(n)
-
-
-def serving_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated serving-tier counters."""
-    with _serving_lock:
-        out = dict(_serving)
-        if reset:
-            _serving.clear()
-    return out
-
-
-# accumulated speculative-execution events (ISSUE 11): duplicate-attempt
-# launches and their outcomes ("launched" / "won" = the duplicate finished
-# first / "lost" = the primary beat it / "failed" = the duplicate itself
-# died / "promoted" = the primary died and the in-flight duplicate became
-# the current attempt / "orphaned" / "executor_lost"), the duplicated
-# compute discarded when a pair resolves ("wasted_seconds", a float), and
-# per-tenant SLO outcomes ("slo_misses" / "slo_met" — jobs completing past
-# or within their ballista.tenant.slo_ms deadline). Same in-process
-# accumulator pattern as recovery/tenancy/serving above; bench.py reports a
-# per-config `speculation` block off this beside `recovery`/`routing`.
-_speculation_lock = make_lock("ops.runtime._speculation_lock")
-# guarded-by: _speculation_lock
-_speculation: Dict[str, float] = {}  # event -> count/seconds
-
-
-def record_speculation(event: str, n: float = 1) -> None:
-    with _speculation_lock:
-        _speculation[event] = _speculation.get(event, 0) + n
-
-
-def speculation_stats(reset: bool = False) -> Dict[str, float]:
-    """Snapshot of accumulated speculation counters (wasted_seconds is a
-    float total; everything else is an integral count)."""
-    with _speculation_lock:
-        out = dict(_speculation)
-        if reset:
-            _speculation.clear()
-    return out
-
-
-# accumulated shared-scan events (ISSUE 13): scheduler-side batch formation
-# (batches_formed = batched dispatches minted, batched_stages = member tasks
-# riding them, batch_gate_solo = evidence-gate declines, batch_chaos_solo =
-# scheduler.batch-torn formations degraded to solo) and executor-side group
-# execution (shared_groups = groups that actually launched shared,
-# uploads_saved / launches_saved = per-batch member-transfers and
-# member-launches avoided vs solo, device_launches = combined launches run,
-# member_degraded / batch_degraded = members or whole groups that fell back
-# to solo execution — bit-identical either way). Same in-process accumulator
-# pattern as recovery/tenancy/serving above; bench.py reports a per-scenario
-# `shared_scan` block off this.
-_shared_scan_lock = make_lock("ops.runtime._shared_scan_lock")
-# guarded-by: _shared_scan_lock
-_shared_scan: Dict[str, int] = {}  # event -> count
-
-
-def record_shared_scan(event: str, n: int = 1) -> None:
-    with _shared_scan_lock:
-        _shared_scan[event] = _shared_scan.get(event, 0) + int(n)
-
-
-def shared_scan_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated shared-scan counters."""
-    with _shared_scan_lock:
-        out = dict(_shared_scan)
-        if reset:
-            _shared_scan.clear()
-    return out
-
-
-# accumulated disaggregated-shuffle-tier events (ISSUE 15): where pieces
-# were published (storage_publish vs local_publish) and how readers
-# resolved them — storage_fetch = read straight from the shared dir,
-# peer_fetch = the Flight path (the local tier, and the fallback when a
-# storage-homed piece is unreadable, counted storage_fallback_peer beside
-# it), storage_publish_torn = a shuffle.store-chaos-torn publish (the task
-# failed and retried). bench.py's elastic scenario reports
-# storage-vs-peer fetch mix off this. Same in-process accumulator pattern
-# as recovery/tenancy/serving above.
-_shuffle_tier_lock = make_lock("ops.runtime._shuffle_tier_lock")
-# guarded-by: _shuffle_tier_lock
-_shuffle_tier: Dict[str, int] = {}  # event -> count
-
-
-def record_shuffle_tier(event: str, n: int = 1) -> None:
-    with _shuffle_tier_lock:
-        _shuffle_tier[event] = _shuffle_tier.get(event, 0) + int(n)
-
-
-def shuffle_tier_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated shuffle-tier counters."""
-    with _shuffle_tier_lock:
-        out = dict(_shuffle_tier)
-        if reset:
-            _shuffle_tier.clear()
-    return out
-
-
-# accumulated HBM-resident exchange events (ISSUE 16): published /
-# publish_bytes = pieces registered in the residency registry after their
-# authoritative disk publish, reupload_skipped / h2d_bytes_saved = consumer
-# resolutions served straight from the registry (no decode, no re-upload),
-# served_from_registry / d2h_bytes_saved = Flight FetchPartition streams
-# served from memory instead of re-reading the piece off disk,
-# skipped_budget / evicted_budget = budget pressure outcomes at publish,
-# evicted_chaos = exchange.evict verdicts, locality_preferred = scheduler
-# assignments reordered toward the executor advertising residency, miss =
-# registry probes that fell through to the piece ladder. Same in-process
-# accumulator pattern as recovery/shuffle-tier above.
-_exchange_lock = make_lock("ops.runtime._exchange_lock")
-# guarded-by: _exchange_lock
-_exchange: Dict[str, int] = {}  # event -> count
-
-
-def record_exchange(event: str, n: int = 1) -> None:
-    with _exchange_lock:
-        _exchange[event] = _exchange.get(event, 0) + int(n)
-
-
-def exchange_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated exchange-tier counters."""
-    with _exchange_lock:
-        out = dict(_exchange)
-        if reset:
-            _exchange.clear()
-    return out
-
-
-# accumulated incremental-execution events (ISSUE 19): chunks_reused =
-# prepared chunks served byte-for-byte from the chunk-set delta store,
-# chunks_prepared = chunks that paid the scan/encode pipeline,
-# bytes_reprepared_saved = staged bytes those reused chunks would have
-# re-encoded, save_declined_midappend = chunk saves refused because the
-# file's identity moved between the stat and the read (fail-closed bugfix),
-# advance_hits = cached results advanced by a delta fold instead of a full
-# recompute, advance_declined = advancement attempts that fell back to the
-# full run (ineligible shape, torn advance, delta-job failure — recorded,
-# never silent). Same in-process accumulator pattern as the counters above.
-_delta_lock = make_lock("ops.runtime._delta_lock")
-# guarded-by: _delta_lock
-_delta: Dict[str, int] = {}  # event -> count
-
-
-def record_delta(event: str, n: int = 1) -> None:
-    with _delta_lock:
-        _delta[event] = _delta.get(event, 0) + int(n)
-
-
-def delta_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot of accumulated incremental-execution counters."""
-    with _delta_lock:
-        out = dict(_delta)
-        if reset:
-            _delta.clear()
-    return out
-
-
-# accumulated elastic-fleet events (ISSUE 15): autoscaler evaluations and
-# the scale actions they took (scale_up / scale_down by executor count,
-# scale_chaos_skipped = fleet.scale-torn decisions, drain_completed /
-# drain_timeout = graceful scale-in outcomes), plus the running gauges the
-# bench scenario samples (fleet_size = last observed size, backlog_ms =
-# last predicted backlog, peaks kept as fleet_size_peak / backlog_ms_peak).
-# Same in-process accumulator pattern as the counters above; gauges
-# overwrite instead of accumulate.
-_fleet_lock = make_lock("ops.runtime._fleet_lock")
-# guarded-by: _fleet_lock
-_fleet: Dict[str, float] = {}  # event -> count (or gauge value)
-
-
-def record_fleet(event: str, n: float = 1) -> None:
-    with _fleet_lock:
-        _fleet[event] = _fleet.get(event, 0) + n
-
-
-def record_fleet_gauge(gauge: str, value: float) -> None:
-    """Overwrite a fleet gauge, keeping its `_peak` sibling."""
-    with _fleet_lock:
-        _fleet[gauge] = value
-        peak = f"{gauge}_peak"
-        _fleet[peak] = max(_fleet.get(peak, value), value)
-
-
-def fleet_stats(reset: bool = False) -> Dict[str, float]:
-    """Snapshot of accumulated elastic-fleet counters and gauges."""
-    with _fleet_lock:
-        out = dict(_fleet)
-        if reset:
-            _fleet.clear()
-    return out
-
-
 # accumulated adaptive-routing decisions (ISSUE 10): every engine choice
 # the cost-model-aware ladder makes — device / host / split — lands here
 # with its predicted-vs-observed cost when a prediction existed, plus named
 # events (partial-offload splits, skew re-plans, build-side swaps, cost-
-# store health). bench.py reports the per-config `routing` block off this.
+# store health). The benchmark's `engines` reads the engine counts.
 # A decision whose observed cost deviates from its prediction by more than
 # costmodel.MISPREDICT_FACTOR either way counts as a mispredict; the
 # mispredict rate is the model's running honesty meter.

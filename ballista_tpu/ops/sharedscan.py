@@ -69,6 +69,7 @@ import numpy as np
 import pyarrow as pa
 
 from ballista_tpu.ops.runtime import UnsupportedOnDevice
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 log = logging.getLogger("ballista.sharedscan")
@@ -130,12 +131,6 @@ class _Member:
             (not ix) and a.fn in ("sum", "avg")
             for a, ix in zip(stage.aggs, stage.int_exact)
         )
-
-
-def _record(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_shared_scan
-
-    record_shared_scan(event, n)
 
 
 def _find_aggregate(plan):
@@ -261,7 +256,7 @@ def precompute(items, max_batch: int = 8) -> SharedResults:
     for plan, partition, ctx in items:
         m = _member_info(plan, partition, ctx)
         if m is None:
-            _record("member_ineligible")
+            tracing.incr("shared_scan.member_ineligible")
             continue
         groups.setdefault(m.group_key, []).append(m)
     for g in groups.values():
@@ -281,7 +276,7 @@ def precompute(items, max_batch: int = 8) -> SharedResults:
                     "shared-scan group degraded to solo execution",
                     exc_info=True,
                 )
-                _record("batch_degraded")
+                tracing.incr("shared_scan.batch_degraded")
                 for m in chunk:
                     res.drop(m.node, m.partition)
     return res
@@ -380,7 +375,7 @@ def _run_group_locked(members: List[_Member], res: SharedResults) -> None:
     def degrade(m: _Member) -> None:
         if m in live:
             live.remove(m)
-            _record("member_degraded")
+            tracing.incr("shared_scan.member_degraded")
 
     # negotiated narrow choices for the SHARED staged columns (keyed by
     # shared column key): start from the widest of the members' existing
@@ -408,9 +403,9 @@ def _run_group_locked(members: List[_Member], res: SharedResults) -> None:
             # common cases) pass untouched.
             members.remove(m)
             live.remove(m)
-            _record("member_degraded")
+            tracing.incr("shared_scan.member_degraded")
     if len(live) < 2:
-        _record("batch_degraded")
+        tracing.incr("shared_scan.batch_degraded")
         return
 
     batches: List[dict] = []
@@ -536,9 +531,9 @@ def _run_group_locked(members: List[_Member], res: SharedResults) -> None:
             {"staged": staged, "row_valid": row_valid, "recs": recs}
         )
     if len(live) < 2:
-        _record("batch_degraded")
+        tracing.incr("shared_scan.batch_degraded")
         return
-    _record("shared_groups")
+    tracing.incr("shared_scan.shared_groups")
     tables: Dict[int, List[pa.Table]] = {id(m): [] for m in live}
     # per-member aux is batch-independent: build + upload once per group
     # (the solo path builds it once per run too)
@@ -631,8 +626,8 @@ def _run_group_locked(members: List[_Member], res: SharedResults) -> None:
                 # under SYNC_COMPILE (tests / bench warm rounds) this is
                 # what primes the ready set for later async waves
                 _combined_warm.add(sig)
-            _record("device_launches")
-            _record("launches_saved", len(fuse_idx) - 1)
+            tracing.incr("shared_scan.device_launches")
+            tracing.incr("shared_scan.launches_saved", len(fuse_idx) - 1)
             off = 0
             for i in fuse_idx:
                 m, _cp, seg_bucket, _ng, _kv = recs[i]
@@ -647,12 +642,12 @@ def _run_group_locked(members: List[_Member], res: SharedResults) -> None:
                 sum(f.shape[-1] for f in fetched),
                 sum(f.nbytes for f in fetched),
             )
-            _record("device_launches", len(pending))
+            tracing.incr("shared_scan.device_launches", len(pending))
             if not combined_plan and len(recs) > 1:
-                _record("warm_fallback_launches", len(pending))
+                tracing.incr("shared_scan.warm_fallback_launches", len(pending))
             for (i, _dev), arr in zip(pending, fetched):
                 blocks[i] = arr
-        _record("uploads_saved", len(recs) - 1)
+        tracing.incr("shared_scan.uploads_saved", len(recs) - 1)
         for block, (m, _cp, seg_bucket, n_groups, key_values) in zip(
             blocks, recs
         ):

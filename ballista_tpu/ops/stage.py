@@ -1235,7 +1235,7 @@ class FusedAggregateStage:
         import os
         import time as _time
 
-        from ballista_tpu.ops.runtime import record_delta, record_ingest
+        from ballista_tpu.ops.runtime import record_ingest
 
         t_wall0 = _time.perf_counter()
         if self.scan_stride is not None:
@@ -1277,8 +1277,8 @@ class FusedAggregateStage:
                     entries.append(self._upload_record(rec, budget, totals))
                     reused += 1
                 totals["upload_s"] += _time.perf_counter() - t_up0
-                record_delta("chunks_reused", reused)
-                record_delta("bytes_reprepared_saved", nbytes)
+                tracing.incr("delta.chunks_reused", reused)
+                tracing.incr("delta.bytes_reprepared_saved", nbytes)
                 continue
             self._prepare_file_chunks(
                 p, ident, context, ctx, entries, totals, budget
@@ -1381,7 +1381,7 @@ class FusedAggregateStage:
         import time as _time
 
         from ballista_tpu.ops import layout_cache as lc
-        from ballista_tpu.ops.runtime import pipelined_map, record_delta
+        from ballista_tpu.ops.runtime import pipelined_map
 
         path = self.scan.source.files[p]
         t0 = _time.perf_counter()
@@ -1393,7 +1393,7 @@ class FusedAggregateStage:
                 st = os.stat(path)
                 if (str(st.st_mtime), int(st.st_size)) != (ident[1], ident[2]):
                     save = False
-                    record_delta("save_declined_midappend")
+                    tracing.incr("delta.save_declined_midappend")
             except OSError:
                 save = False
         base = ctx.config.tpu_layout_cache_dir()
@@ -1496,7 +1496,7 @@ class FusedAggregateStage:
             rec["staged"] = staged
             entries.append(self._upload_record(rec, budget, totals))
             totals["upload_s"] += _time.perf_counter() - t_up0
-            record_delta("chunks_prepared")
+            tracing.incr("delta.chunks_prepared")
         if not chunks:
             _save_chunk(0, None, None)
 

@@ -16,6 +16,7 @@ from typing import Optional
 import grpc
 
 from ballista_tpu.proto import ballista_pb2 as pb
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 SERVICE_NAME = "ballista.SchedulerGrpc"
@@ -236,7 +237,6 @@ class SchedulerGrpcClient:
         specific method knows to be retryable (e.g. the GetFileMetadata
         throttle hint) even though their status code says otherwise."""
         from ballista_tpu.errors import RpcError
-        from ballista_tpu.ops.runtime import record_recovery
         from ballista_tpu.utils.chaos import ChaosInjected
 
         attempts = self.retries + 1
@@ -266,7 +266,7 @@ class SchedulerGrpcClient:
                 err = e
             if not transient or i + 1 >= attempts:
                 raise RpcError(f"{name} failed: {detail}") from err
-            record_recovery("rpc_retry")
+            tracing.incr("recovery.rpc_retry")
             # replica failover (ISSUE 20): try another endpoint before
             # sleeping — a dead or redirecting replica should cost one
             # backoff step, not the whole retry budget. An ownership

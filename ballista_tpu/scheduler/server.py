@@ -223,10 +223,8 @@ class SchedulerServer:
         raise RuntimeError("scheduler crashed (chaos)")
 
     def _crash(self, context) -> None:
-        from ballista_tpu.ops.runtime import record_recovery
-
-        record_recovery("chaos_injected")
-        record_recovery("chaos_scheduler_crash")
+        tracing.incr("recovery.chaos_injected")
+        tracing.incr("recovery.chaos_scheduler_crash")
         log.warning(
             "chaos[scheduler.crash]: scheduler dying after accepting "
             "status #%d", self._accepted_statuses,
@@ -352,8 +350,6 @@ class SchedulerServer:
         and only after a 2xTTL grace. The failure is a CAS against the
         exact queued bytes — racing the (resurrected) planner's atomic
         commit, exactly one of the two writes lands."""
-        from ballista_tpu.ops.runtime import record_recovery
-
         state = self.state
         now = time.time()
         failed_n = 0
@@ -392,7 +388,7 @@ class SchedulerServer:
                 [(key, failed.SerializeToString())], compare=(key, raw)
             ):
                 failed_n += 1
-                record_recovery("queued_grace_failed")
+                tracing.incr("recovery.queued_grace_failed")
                 log.warning(
                     "queued job %s failed: planner replica %r lapsed "
                     "without committing", job_id, planner,
@@ -514,7 +510,6 @@ class SchedulerServer:
             raise ValueError("ExecuteQueryParams requires a plan or sql")
 
         from ballista_tpu.config import BALLISTA_TENANT, BALLISTA_TENANT_PRIORITY
-        from ballista_tpu.ops.runtime import record_tenancy
         from ballista_tpu.scheduler.fingerprint import (
             plan_file_facts,
             plan_fingerprint,
@@ -542,7 +537,7 @@ class SchedulerServer:
             facts = plan_file_facts(plan)
             fp = plan_fingerprint(plan, settings, file_facts=facts)
         if fp is None and config.result_cache():
-            record_tenancy("cache_unkeyable")
+            tracing.incr("tenancy.cache_unkeyable")
 
         job_id = sp.job = _job_id()
         if fp is not None and config.result_cache():
@@ -623,7 +618,6 @@ class SchedulerServer:
             self._planning.discard(job_id)
 
     def _plan_job_guarded(self, job_id: str, plan, config, content_key=None) -> None:
-        from ballista_tpu.ops.runtime import record_recovery
         from ballista_tpu.utils.chaos import ChaosInjected
 
         limit = self.state.retry_limit(job_id)
@@ -656,7 +650,7 @@ class SchedulerServer:
                     )
                     self.state.save_job_metadata(job_id, failed)
                     return
-                record_recovery("plan_retry")
+                tracing.incr("recovery.plan_retry")
                 log.warning("planning job %s torn by chaos; retrying "
                             "(attempt %d)", job_id, attempt)
             except Exception as e:  # surface planning failure as job failure
@@ -678,7 +672,6 @@ class SchedulerServer:
         worker. Returns False to fall through to ordinary planning. A base
         that exists but cannot fold (float sums, DISTINCT, no total
         order…) is a recorded decline — never a silent one."""
-        from ballista_tpu.ops.runtime import record_delta
         from ballista_tpu.scheduler import delta as delta_mod
 
         base = self.state.result_cache_probe_advance(fp[0], facts)
@@ -686,7 +679,7 @@ class SchedulerServer:
             return False
         spec = delta_mod.fold_spec(plan)
         if spec is None:
-            record_delta("advance_declined")
+            tracing.incr("delta.advance_declined")
             return False
         new_files = delta_mod.new_scan_files(facts, list(base.scan_fact))
         if not new_files:
@@ -741,13 +734,12 @@ class SchedulerServer:
         import time as _time
 
         from ballista_tpu.config import BALLISTA_DELTA_FOR
-        from ballista_tpu.ops.runtime import record_delta
         from ballista_tpu.scheduler import delta as delta_mod
 
         content_key = fp[0] if config.plan_cache() else None
 
         def fall_back(reason: str) -> None:
-            record_delta("advance_declined")
+            tracing.incr("delta.advance_declined")
             log.warning("advancement of job %s declined (%s); planning a "
                         "full recompute", job_id, reason)
             self._plan_job_safe(job_id, plan, config, content_key)
@@ -812,7 +804,7 @@ class SchedulerServer:
                     fp[1], fp[0], facts, ipc, base.advance_epoch
                 )
                 if published:
-                    record_delta("advance_hits")
+                    tracing.incr("delta.advance_hits")
                     completed = pb.JobStatus()
                     completed.completed.cached = True
                     completed.completed.inline_result = ipc
@@ -840,7 +832,6 @@ class SchedulerServer:
         (fresh tree per job — plan nodes are mutable) instead of re-running
         the optimizer, so N tenants submitting the same query plan once."""
         from ballista_tpu.config import BALLISTA_TPU_COALESCE_AGG
-        from ballista_tpu.ops.runtime import record_tenancy
         from ballista_tpu.serde.physical import (
             phys_plan_from_proto,
             phys_plan_to_proto,
@@ -874,7 +865,7 @@ class SchedulerServer:
                         self.state._key("plancache", content_key)
                     )
                 else:
-                    record_tenancy("plan_cache_hit")
+                    tracing.incr("tenancy.plan_cache_hit")
                     if kv_hit:
                         self._plan_cache_insert(content_key, blob)
                     return plan_tree
@@ -1123,7 +1114,6 @@ class SchedulerServer:
         executor re-subscribes. Keyed on a generation-rotated per-process
         sequence (like scheduler.admit) so a restarted scheduler draws
         fresh verdicts."""
-        from ballista_tpu.ops.runtime import record_recovery, record_serving
         from ballista_tpu.utils.chaos import ChaosInjected
 
         if not self.push_enabled or self.crashed:
@@ -1140,7 +1130,6 @@ class SchedulerServer:
         per-subscriber stream tick calls this for its own stream only —
         pumping every subscriber from every tick would be O(N^2) idle KV
         traffic at 4Hz on the scheduler's one lock."""
-        from ballista_tpu.ops.runtime import record_recovery, record_serving
         from ballista_tpu.utils.chaos import ChaosInjected
 
         if not self.push_enabled or self.crashed or sub.closed.is_set():
@@ -1194,8 +1183,8 @@ class SchedulerServer:
                 "scheduler.push",
                 f"g{self.state.generation}/push{self._push_seq}",
             ):
-                record_recovery("chaos_injected")
-                record_recovery("chaos_push_torn")
+                tracing.incr("recovery.chaos_injected")
+                tracing.incr("recovery.chaos_push_torn")
                 log.warning(
                     "chaos[scheduler.push]: tearing delivery of "
                     "%s/%s/%s to %s (stream killed)",
@@ -1225,7 +1214,7 @@ class SchedulerServer:
                         (p2.job_id, p2.stage_id, p2.partition_id, st2.attempt)
                     )
             sub.queue.put(td)
-            record_serving("dispatch_push")
+            tracing.incr("serving.dispatch_push")
             pushed += 1
             first_job = first_job or pid.job_id
         if pushed:
@@ -1381,8 +1370,6 @@ class SchedulerServer:
                     assigned = self.state.maybe_speculate(request.metadata.id)
                     speculative = assigned is not None
                 if assigned is not None:
-                    from ballista_tpu.ops.runtime import record_serving
-
                     status, plan = assigned
                     result.task.CopyFrom(self._task_definition(status, plan))
                     result.task.speculative = speculative
@@ -1395,7 +1382,7 @@ class SchedulerServer:
                             result.task.siblings.add().CopyFrom(
                                 self._task_definition(st2, plan2)
                             )
-                    record_serving("dispatch_poll")
+                    tracing.incr("serving.dispatch_poll")
                     tracing.record(
                         "scheduler.assign", t_assign, tracing.now_ns(),
                         job=status.partition_id.job_id, via="poll",
@@ -1411,10 +1398,8 @@ class SchedulerServer:
             # runnable work NOW instead of waiting for a subscriber tick
             self._pump_pushes()
             if foreign:
-                from ballista_tpu.ops.runtime import record_recovery
-
                 job_id, holder = sorted(foreign.items())[0]
-                record_recovery("ownership_redirected")
+                tracing.incr("recovery.ownership_redirected")
                 detail = (
                     f"job {job_id} owned by peer replica "
                     f"{holder.replica_id!r} at {holder.addr}; re-home"
@@ -1450,9 +1435,7 @@ class SchedulerServer:
                         self._subscribers.pop(request.metadata.id, None)
                     if sub is not None:
                         sub.close()
-                    from ballista_tpu.ops.runtime import record_recovery
-
-                    record_recovery("idle_rehomed")
+                    tracing.incr("recovery.idle_rehomed")
                     detail = (
                         f"job {job_id} owned by peer replica "
                         f"{holder.replica_id!r} at {holder.addr}; re-home"

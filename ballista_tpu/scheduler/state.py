@@ -74,6 +74,7 @@ from ballista_tpu.distributed.stages import (
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import KvBackend
 from ballista_tpu.serde.physical import phys_plan_from_proto, phys_plan_to_proto
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 log = logging.getLogger("ballista.scheduler")
@@ -92,48 +93,11 @@ ORPHANED_ASSIGNMENT_GRACE_SECS = 3.0
 BACKLOG_COLD_TASK_SECONDS = 0.02
 
 
-def _record_recovery(event: str, n: int = 1) -> None:
-    # lazy: scheduler state must stay importable before the ops runtime
-    from ballista_tpu.ops.runtime import record_recovery
-
-    record_recovery(event, n)
-
-
-def _record_tenancy(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_tenancy
-
-    record_tenancy(event, n)
-
-
-def _record_speculation(event: str, n: float = 1) -> None:
-    from ballista_tpu.ops.runtime import record_speculation
-
-    record_speculation(event, n)
-
-
-def _record_shared_scan(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_shared_scan
-
-    record_shared_scan(event, n)
-
-
 def _record_routing(engine: str, op: str = "", predicted_s=None,
                     observed_s=None) -> None:
     from ballista_tpu.ops.runtime import record_routing
 
     record_routing(engine, op, predicted_s, observed_s)
-
-
-def _record_delta(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_delta
-
-    record_delta(event, n)
-
-
-def _record_shuffle_tier(event: str, n: int = 1) -> None:
-    from ballista_tpu.ops.runtime import record_shuffle_tier
-
-    record_shuffle_tier(event, n)
 
 
 def _attempts_error(t: pb.TaskStatus) -> str:
@@ -376,7 +340,7 @@ class SchedulerState:
         # -- multi-tenant bookkeeping (ISSUE 7) -----------------------------
         # read-through cache of the durable tenants/{job} records (a job's
         # tenant is immutable, so cached entries never go stale) and the
-        # per-tenant assignment totals behind bench's fairness report.
+        # per-tenant assignment totals behind tenant_task_shares().
         # Both are touched from PollWork (under the global KV lock) AND from
         # ExecuteQuery / test probes, so they carry their own lock.
         self._tenant_mu = make_lock("scheduler.state._tenant_mu")  # durability: ephemeral(a lock guards state, it is not state)
@@ -734,7 +698,7 @@ class SchedulerState:
                 leases=[(lk, minted, self._lease_ttl)],
             ):
                 self._owned[job_id] = minted
-                _record_recovery("lease_reminted")
+                tracing.incr("recovery.lease_reminted")
                 return True
         self._deposed(job_id)
         return False
@@ -748,7 +712,7 @@ class SchedulerState:
         self._owned.pop(job_id, None)
         self._deposed_jobs.add(job_id)
         self.fence_rejected += 1
-        _record_recovery("fence_rejected")
+        tracing.incr("recovery.fence_rejected")
         holder = self.job_lease(job_id)
         handed_over = len(
             self.kv.get_prefix(self._key("assignments", job_id) + "/")
@@ -789,7 +753,7 @@ class SchedulerState:
             return False
         self._owned[job_id] = minted
         self._deposed_jobs.discard(job_id)
-        _record_recovery("lease_adopted")
+        tracing.incr("recovery.lease_adopted")
         self.recover(jobs={job_id})
         return True
 
@@ -936,7 +900,7 @@ class SchedulerState:
             # superseded set died with the old process; the requeue
             # numbering floor covers its late reports regardless)
             self._spec_launches[key] = max(1, a.attempt - cur.attempt)
-            _record_speculation("restored")
+            tracing.incr("speculation.restored")
             bump("restart_speculation_restored")
 
     def recover(self, jobs=None) -> Dict[str, int]:
@@ -965,13 +929,13 @@ class SchedulerState:
           vouching poll, requeued through the normal retry path if nobody
           vouches in time.
 
-        Returns the recovery counters (also fed into ops.runtime so
-        bench.py's `recovery` field picks them up). A fresh store returns
+        Returns the recovery counters (also counted under `recovery.` in
+        utils/tracing.py). A fresh store returns
         {} without recording anything."""
         stats: Dict[str, int] = {}
 
         def bump(event: str) -> None:
-            _record_recovery(event)
+            tracing.incr(f"recovery.{event}")
             stats[event] = stats.get(event, 0) + 1
 
         now = time.monotonic()
@@ -1190,7 +1154,7 @@ class SchedulerState:
 
     def tenant_task_shares(self) -> Dict[str, int]:
         """Per-tenant totals of tasks assigned by this scheduler instance —
-        the fairness denominator bench's multi-tenant scenario reports."""
+        the fairness denominator tests/test_multitenant.py reads."""
         with self._tenant_mu:
             return dict(self.tenant_assigned)
 
@@ -1266,12 +1230,12 @@ class SchedulerState:
                     ],
                 )
         except ChaosInjected:
-            _record_recovery("chaos_injected")
-            _record_tenancy("cache_put_torn")
+            tracing.incr("recovery.chaos_injected")
+            tracing.incr("tenancy.cache_put_torn")
             log.warning("result-cache put torn by chaos (fp=%s...)",
                         fingerprint[:16])
             return False
-        _record_tenancy("cache_put")
+        tracing.incr("tenancy.cache_put")
         return True
 
     def _ensure_rc_count(self) -> int:
@@ -1348,7 +1312,7 @@ class SchedulerState:
                 self._gc_cached_result(v)
                 self.kv.delete(k)
                 evicted += 1
-                _record_tenancy("cache_evicted")
+                tracing.incr("tenancy.cache_evicted")
         # authoritative re-derivation: surviving others + the incoming entry
         self._rc_count = (len(live) - evicted) + 1
         if evicted:
@@ -1371,7 +1335,7 @@ class SchedulerState:
         key = self._key("resultcache", fingerprint)
         v = self.kv.get(key)
         if v is None:
-            _record_tenancy("cache_miss")
+            tracing.incr("tenancy.cache_miss")
             return None
         entry = pb.ResultCacheEntry()
         entry.ParseFromString(v)
@@ -1380,7 +1344,7 @@ class SchedulerState:
             # hit — a hot entry over stale-but-mtime-identical data still
             # re-executes once per TTL window
             self._result_cache_delete(fingerprint)
-            _record_tenancy("cache_expired")
+            tracing.incr("tenancy.cache_expired")
             log.info("result-cache entry %s... expired (ttl %.0fs)",
                      fingerprint[:16], self.config.result_cache_ttl_s())
             return None
@@ -1393,7 +1357,7 @@ class SchedulerState:
             )
             entry.last_hit = time.time()
             self.kv.put(key, entry.SerializeToString())
-            _record_tenancy("cache_hit")
+            tracing.incr("tenancy.cache_hit")
             return completed
         # storage-homed locations (ISSUE 15) outlive their producer: only
         # locations whose pieces live in an executor work dir need the
@@ -1405,7 +1369,7 @@ class SchedulerState:
         }:
             if self.get_executor_metadata(eid) is None:
                 self._result_cache_delete(fingerprint)
-                _record_tenancy("cache_invalidated")
+                tracing.incr("tenancy.cache_invalidated")
                 log.info(
                     "result-cache entry %s... invalidated (executor %s gone)",
                     fingerprint[:16], eid,
@@ -1418,12 +1382,12 @@ class SchedulerState:
         # durable as the cache itself (scheduler restarts keep it)
         entry.last_hit = time.time()
         self.kv.put(key, entry.SerializeToString())
-        _record_tenancy("cache_hit")
+        tracing.incr("tenancy.cache_hit")
         return completed
 
     def result_cache_invalidate(self, fingerprint: str) -> None:
         self._result_cache_delete(fingerprint)
-        _record_tenancy("cache_invalidated")
+        tracing.incr("tenancy.cache_invalidated")
 
     # -- result-cache advancement (ISSUE 19) ----------------------------------
     def result_cache_probe_advance(self, content_key: str, facts: List[str]):
@@ -1497,11 +1461,11 @@ class SchedulerState:
             if prior is not None:
                 self._gc_cached_result(prior)
         except ChaosInjected:
-            _record_recovery("chaos_injected")
+            tracing.incr("recovery.chaos_injected")
             log.warning("result-cache advancement torn by chaos (fp=%s...)",
                         result_key[:16])
             return False
-        _record_tenancy("cache_put")
+        tracing.incr("tenancy.cache_put")
         return True
 
     # -- shared-store GC (ISSUE 16 satellite) -------------------------------
@@ -1562,7 +1526,7 @@ class SchedulerState:
                 uri, stage, t.partition_id.partition_id, job_id=job_id
             )
         if swept:
-            _record_shuffle_tier("gc_stage_swept", swept)
+            tracing.incr("shuffle_tier.gc_stage_swept", swept)
             log.info(
                 "shared-store GC: swept %d piece dir(s) of job %s", swept,
                 job_id,
@@ -1598,7 +1562,7 @@ class SchedulerState:
                 uri, pl.partition_id.stage_id, pl.partition_id.partition_id
             )
         if swept:
-            _record_shuffle_tier("gc_result_swept", swept)
+            tracing.incr("shuffle_tier.gc_result_swept", swept)
             log.info(
                 "shared-store GC: swept %d cached-result piece dir(s)", swept
             )
@@ -1729,7 +1693,7 @@ class SchedulerState:
                 and status.attempt == current.attempt
                 and status.completed.executor_id == current.completed.executor_id
             ):
-                _record_recovery("stale_status_dropped")
+                tracing.incr("recovery.stale_status_dropped")
                 log.info(
                     "dropping late status for resolved task %s/%s/%s "
                     "(attempt %d%s; completion already stands)",
@@ -1739,7 +1703,7 @@ class SchedulerState:
                 )
                 return False
         if current is not None and status.attempt < current.attempt:
-            _record_recovery("stale_status_dropped")
+            tracing.incr("recovery.stale_status_dropped")
             log.info(
                 "dropping stale status for %s/%s/%s (attempt %d < %d)",
                 pid.job_id, pid.stage_id, pid.partition_id,
@@ -1763,7 +1727,7 @@ class SchedulerState:
             if not sup:
                 self._spec_superseded.pop(key3, None)
             if w in ("failed", "fetch_failed"):
-                _record_speculation("superseded_failed")
+                tracing.incr("speculation.superseded_failed")
                 if w == "fetch_failed":
                     # like a live duplicate's fetch failure: the named map
                     # output is gone for EVERY future consumer — recompute
@@ -1781,7 +1745,7 @@ class SchedulerState:
                 return False
             if w == "completed":
                 superseded_completion = True
-                _record_speculation("superseded_won")
+                tracing.incr("speculation.superseded_won")
         if spec is not None:
             spec_exec, spec_attempt, spec_t0, _v, _r = spec
             if status.attempt == spec_attempt and w in ("failed", "fetch_failed"):
@@ -1789,7 +1753,7 @@ class SchedulerState:
                 # the speculation without touching the task (a failed
                 # duplicate never consumes the task's retry budget)
                 self._spec_del(key3)
-                _record_speculation("failed")
+                tracing.incr("speculation.failed")
                 if w == "fetch_failed":
                     # the report still carries actionable lineage: the named
                     # map output is gone for EVERY future consumer. Recompute
@@ -1814,20 +1778,20 @@ class SchedulerState:
                 now = time.monotonic()
                 if status.attempt == spec_attempt:
                     prim = self._running_since.get(key3)
-                    _record_speculation("won")
-                    _record_speculation(
-                        "wasted_seconds",
+                    tracing.incr("speculation.won")
+                    tracing.incr(
+                        "speculation.wasted_seconds",
                         now - (prim[2] if prim is not None else spec_t0),
                     )
                 elif superseded_completion:
                     # an ABANDONED duplicate crossed the line first: still
                     # a speculative WIN (the duplicate rescued the task) —
                     # the live successor's effort is what got wasted
-                    _record_speculation("won")
-                    _record_speculation("wasted_seconds", now - spec_t0)
+                    tracing.incr("speculation.won")
+                    tracing.incr("speculation.wasted_seconds", now - spec_t0)
                 else:
-                    _record_speculation("lost")
-                    _record_speculation("wasted_seconds", now - spec_t0)
+                    tracing.incr("speculation.lost")
+                    tracing.incr("speculation.wasted_seconds", now - spec_t0)
                 self._spec_del(key3)
                 log.info(
                     "speculation resolved for %s/%s/%s: %s attempt %d won",
@@ -2022,7 +1986,7 @@ class SchedulerState:
             # it like any in-flight assignment
             self._ledger_put(key3, spec[0], spec[1])
             self._spec_del(key3)
-            _record_speculation("promoted")
+            tracing.incr("speculation.promoted")
             log.warning(
                 "promoted speculative attempt %d of %s/%s/%s on %s "
                 "(primary attempt %d lost: %s)",
@@ -2035,7 +1999,7 @@ class SchedulerState:
             # exhausted: the job fails — retire any in-flight duplicate's
             # record with it (its late report is dropped by the guards)
             if spec is not None:
-                _record_speculation("failed")
+                tracing.incr("speculation.failed")
             self._spec_resolve(key3)
             return False
         # any in-flight assignment of the superseded attempt is now stale;
@@ -2062,9 +2026,9 @@ class SchedulerState:
             return True
         self._ledger_del((pid0.job_id, pid0.stage_id, pid0.partition_id))
         if spec is not None:
-            _record_speculation("failed")
+            tracing.incr("speculation.failed")
         self._spec_resolve(key3)
-        _record_recovery("task_retry")
+        tracing.incr("recovery.task_retry")
         pid = t.partition_id
         log.warning(
             "requeued task %s/%s/%s for attempt %d (%s)",
@@ -2076,7 +2040,7 @@ class SchedulerState:
         failed = pb.JobStatus()
         failed.failed.error = error
         self.save_job_metadata(job_id, failed)
-        _record_recovery("job_failed_exhausted")
+        tracing.incr("recovery.job_failed_exhausted")
         log.error("job %s failed: %s", job_id, error)
 
     def get_job_stage_ids(self, job_id: str) -> List[int]:
@@ -2171,7 +2135,7 @@ class SchedulerState:
                 # invalidation, no task retries. (A piece that really did
                 # vanish from storage surfaces later as a reader's
                 # fetch_failed and recovers through lineage as usual.)
-                _record_recovery("storage_home_retained")
+                tracing.incr("recovery.storage_home_retained")
                 continue
             error = (
                 f"executor {owner} lease expired while the task ran"
@@ -2186,7 +2150,7 @@ class SchedulerState:
                 self._fail_job(job_id, _attempts_error(exhausted))
                 finished_jobs[job_id] = True
                 continue
-            _record_recovery("lost_task_reset")
+            tracing.incr("recovery.lost_task_reset")
             reset += 1
             if w == "completed":
                 lost_outputs.setdefault(job_id, set()).add(t.partition_id.stage_id)
@@ -2220,7 +2184,7 @@ class SchedulerState:
                         self._fail_job(job_id, _attempts_error(exhausted))
                         finished_jobs[job_id] = True
                         break
-                    _record_recovery("downstream_invalidated")
+                    tracing.incr("recovery.downstream_invalidated")
                     reset += 1
         # prune watch entries of finished jobs (ISSUE 11): a job that
         # failed with tasks still marked running would otherwise pin its
@@ -2246,7 +2210,7 @@ class SchedulerState:
         budget is exhausted (caller fails the job)."""
         ff = t.fetch_failed
         pid = t.partition_id
-        _record_recovery("fetch_failed")
+        tracing.incr("recovery.fetch_failed")
         reporter_error = (
             f"fetch_failed: shuffle output {ff.map_executor_id}:{ff.path} "
             f"(map {ff.map_stage_id}/{ff.map_partition_id}) unreachable: {ff.error}"
@@ -2283,7 +2247,7 @@ class SchedulerState:
                 f"shuffle output lost (fetch_failed reported by {reporter})",
                 limit,
             ):
-                _record_recovery("map_recomputed")
+                tracing.incr("recovery.map_recomputed")
 
     def restart_completed_job(self, job_id: str, executor_id: str) -> int:
         """Restart a job whose result partitions died with their executor
@@ -2332,13 +2296,13 @@ class SchedulerState:
                 exhausted.failed.executor_id = executor_id
                 self._fail_job(job_id, _attempts_error(exhausted))
                 return restarted
-            _record_recovery("result_partition_restarted")
+            tracing.incr("recovery.result_partition_restarted")
             restarted += 1
         if restarted and was_completed:
             running = pb.JobStatus()
             running.running.SetInParent()
             self.save_job_metadata(job_id, running)
-            _record_recovery("completed_job_restarted")
+            tracing.incr("recovery.completed_job_restarted")
         if restarted:
             log.warning(
                 "restarting job %s: %d result partition(s) lost with "
@@ -2809,7 +2773,7 @@ class SchedulerState:
         ]
         if predicted is not None and all(s is not None for s in solo):
             if predicted >= sum(solo):
-                _record_shared_scan("batch_gate_solo")
+                tracing.incr("shared_scan.batch_gate_solo")
                 _record_routing("solo", "stage.batch")
                 log.info(
                     "shared-scan gate: batch of %d predicted %.4fs >= solo "
@@ -2826,7 +2790,7 @@ class SchedulerState:
             except ChaosInjected:
                 # torn BEFORE any write: the primary dispatches solo and
                 # the would-be siblings stay pending for the next slot
-                _record_shared_scan("batch_chaos_solo")
+                tracing.incr("shared_scan.batch_chaos_solo")
                 log.warning(
                     "chaos[scheduler.batch]: batch formation torn; "
                     "dispatching %s/%s/%s solo",
@@ -2876,8 +2840,8 @@ class SchedulerState:
         }
         for key in keys:
             self._batch_members[key] = bid
-        _record_shared_scan("batches_formed")
-        _record_shared_scan("batched_stages", k)
+        tracing.incr("shared_scan.batches_formed")
+        tracing.incr("shared_scan.batched_stages", k)
         log.info(
             "shared-scan batch %d: %d stages over one scan -> %s "
             "(primary %s/%s/%s)", bid, k, executor_id,
@@ -2912,7 +2876,7 @@ class SchedulerState:
             for k, entry in list(self._speculative.items()):
                 if entry[0] not in alive:
                     self._spec_del(k)
-                    _record_speculation("executor_lost")
+                    tracing.incr("speculation.executor_lost")
         job_live: Dict[str, bool] = {}
         inflight: Optional[Dict[str, int]] = None
         for key3 in self._straggler_candidates(now):
@@ -2967,7 +2931,7 @@ class SchedulerState:
                 if inflight is None:
                     inflight = self._tenant_inflight(self._ensure_task_index())
                 if inflight.get(tenant, 0) >= self._tenant_quota:
-                    _record_tenancy("speculate_quota_deferred")
+                    tracing.incr("tenancy.speculate_quota_deferred")
                     continue
             # re-verify from the KV before dispatching: the watch map is
             # in-memory and a peer (or a racing status) may have moved on
@@ -2999,13 +2963,13 @@ class SchedulerState:
                 dup.attempt = spec[1] + 1
                 self._spec_superseded.setdefault(key3, set()).add(spec[1])
                 self._spec_launches[key3] = self._spec_launches.get(key3, 1) + 1
-                _record_speculation("relaunched")
+                tracing.incr("speculation.relaunched")
             else:
                 dup.attempt = cur.attempt + 1
                 self._spec_launches[key3] = 1
             self._spec_put(key3, executor_id, dup.attempt)
             self.note_tenant_assigned(self.job_tenant(job_id)[0])
-            _record_speculation("launched")
+            tracing.incr("speculation.launched")
             log.warning(
                 "speculating %s/%s/%s on %s (attempt %d%s): elapsed %.3fs > "
                 "%.1fx predicted %.3fs (primary %s)",
@@ -3034,12 +2998,12 @@ class SchedulerState:
         if slo is None or created <= 0.0:
             return
         if (time.time() - created) * 1000.0 > slo:
-            _record_speculation("slo_misses")
+            tracing.incr("speculation.slo_misses")
             log.warning(
                 "job %s (tenant %s) missed its %.0fms SLO", job_id, tenant, slo
             )
         else:
-            _record_speculation("slo_met")
+            tracing.incr("speculation.slo_met")
 
     def _tenant_inflight(self, idx: _TaskIndex) -> Dict[str, int]:
         """Per-tenant totals of currently RUNNING tasks, via the index's
@@ -3106,7 +3070,7 @@ class SchedulerState:
                         # long enough that the prior episode ended (a
                         # sub-5s gap is a stage boundary draining the
                         # pending set, not relief)
-                        _record_tenancy("admit_slo_boosted")
+                        tracing.incr("tenancy.admit_slo_boosted")
                     self._slo_boosted[tenant] = now
             for t in by_tenant:
                 # evaluated this scan and NOT overdue: episode over
@@ -3122,7 +3086,7 @@ class SchedulerState:
         )
         for tenant in tenant_rank:
             if quota > 0 and inflight.get(tenant, 0) >= quota:
-                _record_tenancy("admit_quota_deferred")
+                tracing.incr("tenancy.admit_quota_deferred")
                 continue
             order.extend(sorted(
                 by_tenant[tenant],
@@ -3222,9 +3186,7 @@ class SchedulerState:
                 running.running.executor_id = executor_id
                 if partition in resident_pref:
                     # the pick landed where its inputs are HBM-resident
-                    from ballista_tpu.ops.runtime import record_exchange
-
-                    record_exchange("locality_preferred")
+                    tracing.incr("exchange.locality_preferred")
                 if not self.save_task_status(running):
                     # fenced out mid-assignment (ISSUE 20): a peer adopted
                     # the job between the liveness check and the claim —
@@ -3282,11 +3244,11 @@ class SchedulerState:
                 if not vouched:
                     self._speculative[key] = (ex, at, t0, True, restored)
                     if restored:
-                        _record_recovery("restart_speculation_readopted")
+                        tracing.incr("recovery.restart_speculation_readopted")
                 continue
             if not vouched and now - t0 > ORPHANED_ASSIGNMENT_GRACE_SECS:
                 self._spec_del(key)
-                _record_speculation("orphaned")
+                tracing.incr("speculation.orphaned")
                 log.warning(
                     "speculative attempt %d of %s/%s/%s never reached %s; "
                     "dropped (primary still runs)",
@@ -3308,7 +3270,7 @@ class SchedulerState:
                 # status/lease machinery takes over from here
                 self._ledger_del(key)
                 if restored:
-                    _record_recovery("restart_readopted")
+                    tracing.incr("recovery.restart_readopted")
                     log.info(
                         "restart reconciliation: executor %s re-adopted "
                         "task %s/%s/%s (attempt %d)",
@@ -3341,7 +3303,7 @@ class SchedulerState:
                 "(PollWork response lost in transit)"
             )
             if self.requeue_task(cur, owner, error, self.retry_limit(key[0])):
-                _record_recovery("orphan_reassigned")
+                tracing.incr("recovery.orphan_reassigned")
                 reclaimed += 1
             else:
                 exhausted = pb.TaskStatus()
@@ -3394,7 +3356,7 @@ class SchedulerState:
                 all_completed = False
         if any_failed is not None:
             status.failed.error = any_failed
-            _record_recovery("job_failed_exhausted")
+            tracing.incr("recovery.job_failed_exhausted")
         elif all_completed:
             final_stage = max(t.partition_id.stage_id for t in tasks)
             for t in sorted(tasks, key=lambda t: t.partition_id.partition_id):

@@ -18,6 +18,44 @@ device's time line; without a session that is one flag test.
 
 `reset()` keeps what it clears; `drained()` returns it. `by_name` and
 `covered_s` are what the readers of a log share.
+
+A counter's name is `<family>.<event>`. `device.`, `spmd.` and `serde.` are
+the served path's, and the benchmark prints the first two. Nine families
+count control-plane events; `counters("<family>", reset=True)` reads and
+clears one of them alone, and only tests do:
+
+recovery, in scheduler/{state,server,rpc}, client/*, executor/*, distributed/stages, utils/chaos:
+    task_retry, fetch_failed, map_recomputed, lost_task_reset, stale_status_dropped, rpc_retry,
+    plan_retry, ownership_redirected, lease_* and restart_* (a scheduler's recover());
+    chaos_injected with every injected fault, and chaos_<what> beside it
+tenancy, in scheduler/{state,server}, client/context:
+    the result cache's cache_hit / _miss / _put / _put_torn / _expired / _evicted / _invalidated /
+    _unkeyable / _lost_resubmitted; plan_cache_hit; admit_quota_deferred, admit_slo_boosted,
+    speculate_quota_deferred
+serving, in scheduler/server, executor/execution_loop, client/context, ops/aotcache:
+    dispatch_push / dispatch_poll (how a task reached its executor), push_subscribed,
+    push_stream_drop, task_pushed; the client's status_push* and stream_partition_early;
+    compile_trace / _hit_memory / _hit_disk / _prewarmed / _warmed, aot_saved, aot_load_error
+speculation, in scheduler/state:
+    launched, relaunched, won, lost, failed, promoted, orphaned, executor_lost, restored,
+    superseded_won / _failed; wasted_seconds (a float: duplicated compute thrown away);
+    slo_met / slo_misses (jobs that ended within or past their tenant's slo_ms)
+shared_scan, in scheduler/state (a batch forms) and ops/sharedscan (it runs):
+    batches_formed, batched_stages, batch_gate_solo, batch_chaos_solo; shared_groups,
+    uploads_saved, launches_saved, device_launches, warm_fallback_launches, member_ineligible,
+    member_degraded, batch_degraded
+shuffle_tier, in distributed/stages, client/context, scheduler/state:
+    storage_publish / local_publish, storage_fetch / peer_fetch, storage_fallback_peer,
+    storage_publish_torn / _read_torn, client_storage_fetch / _miss, gc_stage_swept / _result_swept
+exchange, in ops/exchange, distributed/stages, executor/flight_service, scheduler/state:
+    published, publish_bytes, reupload_skipped, h2d_bytes_saved, served_from_registry,
+    d2h_bytes_saved, miss, skipped_budget, evicted_budget / _tenant_budget / _chaos,
+    locality_preferred
+delta, in ops/stage (chunks) and scheduler/server (a cached result advanced):
+    chunks_reused, chunks_prepared, bytes_reprepared_saved, save_declined_midappend,
+    advance_hits, advance_declined
+fleet, in executor/{runtime,execution_loop}:
+    evaluations, scale_up, scale_down, scale_chaos_skipped, drain_completed, drain_timeout
 """
 
 from __future__ import annotations
@@ -51,7 +89,7 @@ class _Log:
 _local = threading.local()
 _ids = itertools.count(1)
 _log = _Log()  # swapped whole by reset(); appended to with no lock
-_counters: Dict[str, int] = {}  # guarded-by: _mu
+_counters: Dict[str, float] = {}  # guarded-by: _mu
 _last: Dict[str, object] = {"spans": [], "counters": {}}  # guarded-by: _mu
 _mu = make_lock("utils.tracing._mu")
 
@@ -194,22 +232,30 @@ def spans() -> List[Span]:
     return list(_log.ring)
 
 
-def incr(name: str, by: int = 1) -> None:
+def incr(name: str, by: float = 1) -> None:
     """Monotonic named counter (e.g. spmd.mesh vs spmd.host_fallback, so a
-    permanently-broken mesh path is visible in ops, not just test asserts)."""
+    permanently-broken mesh path is visible in ops, not just test asserts).
+    `by` is a count, or seconds where the name says so."""
     with _mu:
         _counters[name] = _counters.get(name, 0) + by
 
 
-def _with_dropped(counts: Dict[str, int], log: _Log) -> Dict[str, int]:
+def _with_dropped(counts: Dict[str, float], log: _Log) -> Dict[str, float]:
     if log.dropped:
         counts["tracing.dropped"] = log.dropped
     return counts
 
 
-def counters() -> Dict[str, int]:
+def counters(family: Optional[str] = None, reset: bool = False) -> Dict[str, float]:
+    """{name: n} of every counter; with a family, {event: n} of the names
+    `<family>.<event>`. `reset=True` clears the names returned and no other."""
+    prefix = f"{family}." if family else ""
     with _mu:
-        return _with_dropped(dict(_counters), _log)
+        out = {k[len(prefix):]: v for k, v in _counters.items() if k.startswith(prefix)}
+        if reset:
+            for event in out:
+                del _counters[prefix + event]
+        return out if family else _with_dropped(out, _log)
 
 
 def reset() -> None:

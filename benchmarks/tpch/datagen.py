@@ -419,7 +419,7 @@ def generate(out_dir: str, sf: float = 0.01, parts: int = 2,
             for f in futures:
                 f.result()
     # completeness marker: generation streams for hours at SF=100; consumers
-    # (bench.py ensure_data) must not mistake an interrupted run for a dataset
+    # (chip_smoke.py, tests) must not mistake an interrupted run for a dataset
     with open(os.path.join(out_dir, "_SUCCESS"), "w") as f:
         f.write(f"sf={sf} parts={parts} seed={seed}\n")
 

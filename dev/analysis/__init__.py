@@ -7,8 +7,8 @@ cannot see; this package turns them into machine-checked gates
 - **readback-discipline** — every device->host materialization of a
   compiled-program result inside `ballista_tpu/ops/` or
   `ballista_tpu/parallel/` must pair with `record_readback` (or the
-  `readback` helper) in the same function, or bench.py's readback_rows/
-  readback_bytes undercount and the O(limit)-readback claim is unmeasured.
+  `readback` helper) in the same function, or readback_stats' rows/bytes
+  (the benchmark's `readback`) undercount and the O(limit)-readback claim is unmeasured.
 - **tracer-hygiene** — code reached from a jit/shard_map/pallas decoration
   site must never branch (`if`/`while`) on, or host-materialize
   (`bool()`/`int()`/`float()`/`.item()`), a value derived from `jnp.*`/

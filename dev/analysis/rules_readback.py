@@ -1,7 +1,7 @@
 """readback-discipline: device->host materializations of compiled-program
 results in ballista_tpu/ops/ and ballista_tpu/parallel/ must pair with
 record_readback (or the runtime.readback helper) in the same function —
-otherwise bench.py's readback_rows/readback_bytes undercount and the
+otherwise readback_stats' rows/bytes (the benchmark's `readback`) undercount and the
 paper's O(limit)-readback claim goes unmeasured."""
 
 from __future__ import annotations

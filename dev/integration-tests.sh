@@ -14,7 +14,7 @@ SF=${SF:-0.01}
 # ladder) and the scheduler durability contract (KV write-through,
 # recover() coverage, replica-coherence classification — ISSUE 18) are
 # machine-checked before anything executes — a violation fails the tier
-# in seconds instead of surfacing as a wrong bench number later.
+# in seconds instead of surfacing as a wrong number later.
 # --jobs 8 (ISSUE 15 satellite, PR 14 residue): per-file analysis fans out
 # over a process pool — 5.2s -> 1.6s cold on a 24-core box — with output
 # and cache semantics identical to serial (pinned by
@@ -109,30 +109,6 @@ JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
     tests/test_costmodel.py \
     "tests/test_fuzz_device.py::test_fuzz_routing"
 
-# adaptive-execution bench smoke (ISSUE 10): the skewed join past the
-# static ladder must SPLIT at the tier boundary instead of declining
-# wholesale, results bit-identical across cold/warm/off, and the routing
-# block's mispredict accounting must sum (mispredicts <= predictions <=
-# total decisions; rate == mispredicts/predictions).
-JAX_PLATFORMS=cpu BENCH_ROUTING_ONLY=1 python bench.py \
-    > /tmp/_ballista_routing_smoke.json
-python - /tmp/_ballista_routing_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["routing"]
-assert rec is not None, "routing smoke returned no record"
-assert rec["bit_identical"], "routing changed results"
-assert rec["splits"] >= 1, f"no partial-offload split: {rec}"
-assert rec["engines"].get("split", 0) >= 1, rec
-total = sum(rec["engines"].values())
-assert 0 <= rec["mispredicts"] <= rec["predictions"] <= total, rec
-want = rec["mispredicts"] / rec["predictions"] if rec["predictions"] else 0.0
-assert abs(rec["mispredict_rate"] - want) < 1e-4, rec
-assert rec["events"].get("split", 0) == rec["splits"], rec
-assert rec["skew_replans"] == rec["events"].get("skew_replan", 0), rec
-print("routing smoke OK:", {k: rec[k] for k in
-                            ("engines", "mispredict_rate", "splits")})
-PY
-
 # strict gate on speculative execution (ISSUE 11): cost-model straggler
 # detection launching duplicates through the durable speculation ledger,
 # first-completion-wins in both directions (the losing sibling's report
@@ -146,32 +122,6 @@ JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
     tests/test_speculation.py \
     "tests/test_fuzz_device.py::test_fuzz_speculation_straggler"
 
-# speculation bench smoke (ISSUE 11): seeded task.slow chaos in the
-# closed-loop latency harness (multi-process client driver) — p99 with
-# speculation ON must land STRICTLY below OFF, results bit-identical to
-# the fault-free baseline in both modes, counters emitted, and the
-# fault-free warm passes must launch nothing.
-JAX_PLATFORMS=cpu BENCH_SPECULATION_ONLY=1 BENCH_SPEC_DURATION=4 \
-    BENCH_SPEC_SLOW_MS=800 python bench.py > /tmp/_ballista_spec_smoke.json
-python - /tmp/_ballista_spec_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["speculation"]
-assert rec is not None, "speculation scenario returned no record"
-assert rec["bit_identical"], "speculation changed results"
-on, off = rec["on"], rec["off"]
-assert on["p99_ms"] < off["p99_ms"], (
-    f"speculation ON p99 {on['p99_ms']}ms not below OFF {off['p99_ms']}ms")
-assert on["speculation"].get("launched", 0) > 0, on
-assert on["speculation"].get("won", 0) >= 1, on
-assert off["speculation"].get("launched", 0) == 0, off
-# fault-free runs launch nothing: both modes' warm passes stayed silent
-assert on["warm_launched"] == 0 and off["warm_launched"] == 0, rec
-print("speculation smoke OK:",
-      {"on_p99_ms": on["p99_ms"], "off_p99_ms": off["p99_ms"],
-       "p99_speedup": rec["p99_speedup"],
-       "counters": on["speculation"]})
-PY
-
 # strict gate on shared-scan multi-query execution (ISSUE 13): batched
 # dispatch bit-identical to solo on the same backend (evidence gate on/off,
 # mixed compatible/incompatible groups, scheduler.batch chaos, one member's
@@ -180,30 +130,6 @@ PY
 # tuned h2d chunk size riding the same tier via their own suites above.
 JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
     tests/test_shared_scan.py
-
-# shared-scan bench smoke (ISSUE 13): concurrent distinct aggregate queries
-# over one table on a saturated single-slot cluster — batches must form,
-# at least one member upload must be SAVED by the shared scan, and every
-# batched result must be bit-identical to the never-batched reference.
-JAX_PLATFORMS=cpu BENCH_SHAREDSCAN_ONLY=1 BENCH_SS_DURATION=6 \
-    BENCH_SS_TENANTS=1,4 python bench.py > /tmp/_ballista_ss_smoke.json
-python - /tmp/_ballista_ss_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["shared_scan"]
-assert rec is not None, "shared-scan scenario returned no record"
-assert rec["bit_identical"], "shared-scan batching changed results"
-by = {r["tenants"]: r for r in rec["sweep"]}
-assert 4 in by, rec
-ss = by[4]["shared_scan"]
-assert ss.get("batches_formed", 0) >= 1, rec
-assert ss.get("batched_stages", 0) >= 2, rec
-assert ss.get("uploads_saved", 0) >= 1, rec
-# solo tenants must never batch
-assert by.get(1, {}).get("shared_scan", {}) == {}, rec
-print("shared-scan smoke OK:",
-      {"qps": {t: r["qps"] for t, r in by.items()},
-       "counters": ss})
-PY
 
 # strict gate on the disaggregated shuffle tier + elastic fleet (ISSUE 15):
 # shared-storage piece publish (atomic tmp-then-replace, shuffle.store
@@ -219,34 +145,6 @@ PY
 JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
     tests/test_elastic_shuffle.py \
     "tests/test_fuzz_device.py::test_fuzz_shared_tier_chaos"
-
-# elastic-fleet bench smoke (ISSUE 15): a burst of concurrent jobs on the
-# shared tier against an autoscaled cluster — the fleet must GROW under
-# the injected (cost-model-predicted) backlog, drain back to min when
-# idle, fetch shuffle pieces from storage, and complete every job
-# bit-identical with zero task retries.
-JAX_PLATFORMS=cpu BENCH_ELASTIC_ONLY=1 python bench.py \
-    > /tmp/_ballista_elastic_smoke.json
-python - /tmp/_ballista_elastic_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["elastic"]
-assert rec is not None, "elastic scenario returned no record"
-assert rec["bit_identical"], "elastic fleet changed results"
-assert rec["fleet_peak"] > rec["fleet_min"], f"fleet never grew: {rec}"
-assert rec["fleet_final"] == rec["fleet_min"], f"fleet never drained: {rec}"
-assert rec["backlog_ms_peak"] > 0, rec
-assert rec["task_retries"] == 0, rec
-fl, tier = rec["fleet"], rec["shuffle_tier"]
-assert fl.get("scale_up", 0) >= 1 and fl.get("scale_down", 0) >= 1, fl
-assert fl.get("drain_completed", 0) >= fl.get("scale_down", 0), fl
-assert tier.get("storage_publish", 0) > 0, tier
-assert tier.get("storage_fetch", 0) > 0, tier
-print("elastic smoke OK:",
-      {"fleet_peak": rec["fleet_peak"], "fleet_final": rec["fleet_final"],
-       "backlog_ms_peak": rec["backlog_ms_peak"],
-       "storage_fetch": tier.get("storage_fetch"),
-       "peer_fetch": tier.get("peer_fetch", 0)})
-PY
 
 # scale-in chaos e2e under the dynamic lock witness (ISSUE 15 satellite):
 # the graceful drain/retire path — autoscaler decision machinery included,
@@ -304,8 +202,7 @@ import ballista_tpu.scheduler.state as state_mod
 from ballista_tpu.client import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.executor.runtime import StandaloneCluster
-from ballista_tpu.ops.runtime import recovery_stats
-from ballista_tpu.utils import locks
+from ballista_tpu.utils import locks, tracing
 from ballista_tpu.utils.chaos import ChaosInjector
 
 def find_death_seed():
@@ -330,7 +227,7 @@ pq.write_table(pa.table({
 }), os.path.join(tmp, "t.parquet"))
 locks.reset_witness(); locks.enable_witness()
 state_mod.EXECUTOR_LEASE_SECS = 1.0
-recovery_stats(reset=True)
+tracing.counters("recovery", reset=True)
 cluster = StandaloneCluster(n_executors=2, config=BallistaConfig({
     "ballista.debug.lock_witness": "1",
     "ballista.chaos.rate": "0.005",
@@ -346,13 +243,13 @@ ctx.register_parquet("t", os.path.join(tmp, "t.parquet"))
 sql = "select g, sum(v) as s, count(*) as c from t group by g order by g"
 first = ctx.sql(sql).collect()
 deadline = time.time() + 10
-while time.time() < deadline and not recovery_stats().get("chaos_executor_death"):
+while time.time() < deadline and not tracing.counters("recovery").get("chaos_executor_death"):
     time.sleep(0.1)
 cluster.restart_scheduler()
 second = ctx.sql(sql).collect()
 assert first.to_pydict() == second.to_pydict(), "restart changed results"
 ctx.close(); cluster.shutdown()
-stats = recovery_stats(reset=True)
+stats = tracing.counters("recovery", reset=True)
 assert stats.get("chaos_executor_death", 0) >= 1, stats
 assert stats.get("scheduler_restart", 0) >= 1, stats
 violations = locks.witness_violations()
@@ -365,107 +262,6 @@ print("witness smoke: %d runtime edge(s), 0 violations -> %s"
 PY
 # the cross-check: exit 1 on any runtime edge the static analyzer missed
 python -m dev.analysis --check-witness /tmp/_ballista_witness.json ballista_tpu
-
-# latency harness smoke (ISSUE 8): tiny QPS, 2s budget per level — the
-# p50/p99 + time-to-first-batch + dispatch/compile-counter pipeline is
-# exercised end-to-end on CPU images even though the absolute numbers only
-# mean something on chip. The jq-less assertion: the harness must emit a
-# non-null latency record with zero poll dispatches and a warm compile-hit
-# rate of 1.0.
-JAX_PLATFORMS=cpu BENCH_LATENCY_ONLY=1 BENCH_LAT_DURATION=2 \
-    BENCH_LAT_CLIENTS=1 python bench.py > /tmp/_ballista_lat_smoke.json
-python - /tmp/_ballista_lat_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["latency"]
-assert rec is not None, "latency harness returned no record"
-assert rec["sweep"], "empty QPS sweep"
-for row in rec["sweep"]:
-    for f in ("qps", "p50_ms", "p95_ms", "p99_ms", "ttfb_p50_ms"):
-        assert f in row, f"sweep row missing {f}"
-assert rec["dispatch_poll"] == 0, f"poll-dispatched tasks: {rec}"
-assert rec["dispatch_push"] > 0, f"no push dispatches: {rec}"
-assert rec["compile_trace"] == 0, f"warm sweep traced: {rec}"
-assert rec["compile_hit_rate"] == 1.0, rec
-print("latency smoke OK:", rec["sweep"][0])
-PY
-
-# HBM-resident exchange bench smoke (ISSUE 16): the 2-stage aggregation
-# must actually SKIP re-uploads on the same-executor consume path
-# (registry hits, not ladder reads), stay bit-identical to the
-# exchange-off oracle, and degrade to the ladder with zero task retries
-# when every consume-time probe is torn by seeded exchange.evict chaos.
-JAX_PLATFORMS=cpu BENCH_EXCHANGE_ONLY=1 python bench.py \
-    > /tmp/_ballista_exchange_smoke.json
-python - /tmp/_ballista_exchange_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["exchange"]
-assert rec is not None, "exchange scenario returned no record"
-assert rec["bit_identical"], "exchange tier changed results"
-assert rec["reupload_skipped"] >= 1, rec
-assert rec["h2d_bytes_saved"] > 0, rec
-assert rec["off_stats_empty"], "exchange-off run touched the registry"
-assert rec["task_retries"] == 0, rec
-ch = rec["chaos"]
-assert ch["evicted_chaos"] >= 1, ch
-assert ch["injected"] >= 1, ch
-assert ch["task_retries"] == 0, "registry loss caused task retries"
-print("exchange smoke OK:",
-      {"reupload_skipped": rec["reupload_skipped"],
-       "h2d_bytes_saved": rec["h2d_bytes_saved"],
-       "d2h_bytes_saved": rec["d2h_bytes_saved"],
-       "chaos_evicted": ch["evicted_chaos"],
-       "digest": rec["digest"]})
-PY
-
-# incremental-execution bench smoke (ISSUE 19): appending a file to a
-# cached query's chunk set must (a) reload every existing chunk's tiles
-# from the persisted layout store, (b) serve the new result by FOLDING
-# delta partials into the cached aggregate state — strictly faster than a
-# cold full run over the grown set and bit-identical to it, (c) decline
-# to a full recompute when every advanced publish is torn by seeded
-# cache.advance chaos, and (d) keep serving the advanced entry as a plain
-# cache hit across a scheduler restart on a durable KV.
-JAX_PLATFORMS=cpu BENCH_DELTA_ONLY=1 python bench.py \
-    > /tmp/_ballista_delta_smoke.json
-python - /tmp/_ballista_delta_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["delta"]
-assert rec is not None, "delta scenario returned no record"
-assert rec["bit_identical"], "incremental execution changed results"
-assert rec["chunks_reused"] >= 1, rec
-assert rec["advance_hits"] >= 1, rec
-assert rec["advance_ms"] < rec["cold_ms"], (
-    f"advancement not faster than cold: {rec}")
-ch = rec["chaos"]
-assert ch["advance_hits"] == 0, "torn publish still served an advance"
-assert ch["advance_declined"] >= 1, ch
-assert rec["restart_advanced"] and rec["restart_cache_hit"], rec
-print("delta smoke OK:",
-      {"advance_ms": rec["advance_ms"], "cold_ms": rec["cold_ms"],
-       "chunks_reused": rec["chunks_reused"],
-       "advance_hits": rec["advance_hits"], "digest": rec["digest"]})
-PY
-
-# replicated control-plane bench smoke (ISSUE 20): closed-loop admission
-# from 4 client processes, homed round-robin, against one scheduler and
-# then two lease-sharded replicas over the same KV. Two replicas must
-# admit strictly more completed queries per second, and the union of
-# result digests must be IDENTICAL across both configs — the throughput
-# win never rides a correctness regression.
-JAX_PLATFORMS=cpu BENCH_REPLICA_ONLY=1 BENCH_REPLICA_DURATION=4 \
-    python bench.py > /tmp/_ballista_replica_smoke.json
-python - /tmp/_ballista_replica_smoke.json <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1]))["replica"]
-assert rec is not None, "replica scenario returned no record"
-assert rec["digests_identical"], "replicated admission changed results"
-assert rec["n_digests"] >= 1, rec
-assert rec["two"]["qps"] > rec["one"]["qps"], (
-    f"2-replica admission not faster than 1-replica: {rec}")
-print("replica smoke OK:",
-      {"one_qps": rec["one"]["qps"], "two_qps": rec["two"]["qps"],
-       "speedup": rec["speedup"], "n_digests": rec["n_digests"]})
-PY
 
 # full tier-1 under the dynamic lock witness (ISSUE 16 satellite): every
 # fast test — the exchange registry, scheduler GC, chaos ladders, SPMD

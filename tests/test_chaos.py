@@ -13,6 +13,7 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.errors import RpcError
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.chaos import (
     SITES,
     ChaosInjected,
@@ -143,12 +144,10 @@ def test_chaos_run_is_bit_identical_to_fault_free_run(sales_table):
     distributed group-by and join queries completes with results
     bit-identical to the fault-free run, and the recovery counters show the
     faults actually fired and were recovered from."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     clean = _run_queries(CLEAN_SETTINGS, sales_table)
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     chaotic = _run_queries(CHAOS_SETTINGS, sales_table)
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     for name in ("group_by", "join"):
         assert chaotic[name].equals(clean[name]), (
             name, chaotic[name].to_pydict(), clean[name].to_pydict(),
@@ -213,7 +212,6 @@ def test_chaos_executor_death_recovers_bit_identical(sales_table):
     import ballista_tpu.scheduler.state as state_mod
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     death_seed = _find_death_seed()
     clean = _run_queries(CLEAN_SETTINGS, sales_table)
@@ -225,7 +223,7 @@ def test_chaos_executor_death_recovers_bit_identical(sales_table):
     })
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=2, config=cluster_config)
     cluster.scheduler_impl.lost_task_check_interval = 0.3
     try:
@@ -243,7 +241,7 @@ def test_chaos_executor_death_recovers_bit_identical(sales_table):
             assert out[name].equals(clean[name]), (
                 name, out[name].to_pydict(), clean[name].to_pydict(),
             )
-        stats = recovery_stats(reset=True)
+        stats = tracing.counters("recovery", reset=True)
         assert stats.get("chaos_injected", 0) > 0, stats
         # the dying executor's chaos verdict is deterministic; whether its
         # death interrupts live work depends on scheduling, so only the

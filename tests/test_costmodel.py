@@ -40,6 +40,7 @@ from ballista_tpu.ops.runtime import (
     routing_stats,
 )
 from ballista_tpu.physical.joinutil import join_indices
+from ballista_tpu.utils import tracing
 
 TOP_TIER = JOIN_MULTIPLICITY_TIERS[-1]
 
@@ -410,8 +411,6 @@ def test_failed_build_swap_records_one_decision(cm):
     attempt's outcome lands, so one join counts exactly one decision.
     The tracing counters must agree: an uncommitted probe's declines
     leave no phantom device.host_fallback/step_aside trace either."""
-    from ballista_tpu.utils import tracing
-
     rng = np.random.default_rng(17)
     # planned build: unique keys, > 4x the probe -> the swap triggers;
     # swapped build (= the probe) has 20 hot keys x 300 — multiplicity
@@ -573,7 +572,6 @@ def test_join_programs_aot_disk_tier(tmp_path):
     cold process (compile_hit_disk, zero fresh traces), bit-identically."""
     from ballista_tpu.ops import aotcache
     from ballista_tpu.ops import join as jmod
-    from ballista_tpu.ops.runtime import serving_stats
 
     aotcache.reset(clear_disk_dir=True)
     aotcache.configure(BallistaConfig({
@@ -583,9 +581,9 @@ def test_join_programs_aot_disk_tier(tmp_path):
     jmod._gather_kernel.cache_clear()
     build = np.repeat(np.arange(50, dtype=np.int64), 3)
     probe = np.arange(-5, 60, dtype=np.int64)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     first = device_join_indices(build, probe)
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_trace", 0) >= 2  # runs + gather traced fresh
     assert s.get("aot_saved", 0) >= 2
     # cold process: fresh wrappers + empty memory map -> disk hits
@@ -593,7 +591,7 @@ def test_join_programs_aot_disk_tier(tmp_path):
     jmod._runs_kernel.cache_clear()
     jmod._gather_kernel.cache_clear()
     second = device_join_indices(build, probe)
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_hit_disk", 0) >= 2, s
     assert not s.get("compile_trace"), s
     for a, b in zip(first, second):

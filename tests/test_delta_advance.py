@@ -26,8 +26,8 @@ import pytest
 from ballista_tpu.client import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.executor.runtime import StandaloneCluster
-from ballista_tpu.ops.runtime import delta_stats, tenancy_stats
 from ballista_tpu.scheduler.kv import SqliteBackend
+from ballista_tpu.utils import tracing
 
 # the canonical advancement-eligible shape: filter below the aggregate,
 # order-insensitive members only (int sum / count / min), sort on the
@@ -95,13 +95,13 @@ def test_advance_on_append_bit_identical(tdir):
             settings={"ballista.cache.advance": "true"},
         )
         ctx.register_parquet("t", tdir)
-        delta_stats(reset=True)
+        tracing.counters("delta", reset=True)
         cold = ctx.sql(QUERY).collect()
         # grow the chunk set and re-register so the client re-discovers it
         _write_part(tdir, 2)
         ctx.register_parquet("t", tdir)
         advanced = ctx.sql(QUERY).collect()
-        stats = delta_stats(reset=True)
+        stats = tracing.counters("delta", reset=True)
         assert stats.get("advance_hits") == 1, stats
         # the acceptance bar: advanced result == cold full run, byte for byte
         truth = _cold_truth(cluster, tdir)
@@ -109,10 +109,10 @@ def test_advance_on_append_bit_identical(tdir):
         assert not advanced.equals(cold)  # the append actually changed rows
         # the advanced entry is a first-class cache line: a third submission
         # is a plain hit served inline, with ZERO executor tasks
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         third = ctx.sql(QUERY).collect()
         assert third.equals(truth)
-        assert tenancy_stats(reset=True).get("cache_hit") == 1
+        assert tracing.counters("tenancy", reset=True).get("cache_hit") == 1
         st = cluster.scheduler_impl.state
         hits = _cached_jobs(st)
         assert hits and all(st.get_job_tasks(j) == [] for j in hits)
@@ -133,17 +133,17 @@ def test_advanced_entry_survives_scheduler_restart(tdir):
             settings={"ballista.cache.advance": "true"},
         )
         ctx.register_parquet("t", tdir)
-        delta_stats(reset=True)
+        tracing.counters("delta", reset=True)
         ctx.sql(QUERY).collect()
         _write_part(tdir, 2)
         ctx.register_parquet("t", tdir)
         advanced = ctx.sql(QUERY).collect()
-        assert delta_stats(reset=True).get("advance_hits") == 1
+        assert tracing.counters("delta", reset=True).get("advance_hits") == 1
         cluster.restart_scheduler()
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         again = ctx.sql(QUERY).collect()
         assert again.equals(advanced)
-        assert tenancy_stats(reset=True).get("cache_hit") == 1
+        assert tracing.counters("tenancy", reset=True).get("cache_hit") == 1
         ctx.close()
     finally:
         cluster.shutdown()
@@ -168,12 +168,12 @@ def test_advance_chaos_torn_publish_falls_back(tdir):
             settings={"ballista.cache.advance": "true"},
         )
         ctx.register_parquet("t", tdir)
-        delta_stats(reset=True)
+        tracing.counters("delta", reset=True)
         ctx.sql(QUERY).collect()
         _write_part(tdir, 2)
         ctx.register_parquet("t", tdir)
         result = ctx.sql(QUERY).collect()
-        stats = delta_stats(reset=True)
+        stats = tracing.counters("delta", reset=True)
         assert stats.get("advance_hits", 0) == 0, stats
         assert stats.get("advance_declined", 0) >= 1, stats
         assert result.equals(_cold_truth(cluster, tdir))
@@ -195,12 +195,12 @@ def test_float_sum_declines_to_full_recompute(tdir):
             settings={"ballista.cache.advance": "true"},
         )
         ctx.register_parquet("t", tdir)
-        delta_stats(reset=True)
+        tracing.counters("delta", reset=True)
         ctx.sql(q).collect()
         _write_part(tdir, 2)
         ctx.register_parquet("t", tdir)
         result = ctx.sql(q).collect()
-        stats = delta_stats(reset=True)
+        stats = tracing.counters("delta", reset=True)
         assert stats.get("advance_hits", 0) == 0, stats
         assert stats.get("advance_declined", 0) >= 1, stats
         assert result.equals(_cold_truth(cluster, tdir, q))
@@ -220,7 +220,7 @@ def test_shrunk_or_rewritten_set_never_advances(tdir):
             settings={"ballista.cache.advance": "true"},
         )
         ctx.register_parquet("t", tdir)
-        delta_stats(reset=True)
+        tracing.counters("delta", reset=True)
         ctx.sql(QUERY).collect()
         # rewrite part-0 with different rows AND add part-2: the base fact
         # set no longer holds, so the probe must find nothing
@@ -239,7 +239,7 @@ def test_shrunk_or_rewritten_set_never_advances(tdir):
         _write_part(tdir, 2)
         ctx.register_parquet("t", tdir)
         result = ctx.sql(QUERY).collect()
-        assert delta_stats(reset=True).get("advance_hits", 0) == 0
+        assert tracing.counters("delta", reset=True).get("advance_hits", 0) == 0
         assert result.equals(_cold_truth(cluster, tdir))
         ctx.close()
     finally:

@@ -15,7 +15,7 @@ import pytest
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.engine import ExecutionContext
 from ballista_tpu.ops import kernels
-from ballista_tpu.ops.runtime import delta_stats
+from ballista_tpu.utils import tracing
 
 
 def _reset_stage_caches():
@@ -35,10 +35,10 @@ def _reset_stage_caches():
 @pytest.fixture(autouse=True)
 def _fresh_caches():
     _reset_stage_caches()
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
     yield
     _reset_stage_caches()
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
 
 
 def _ctx(cache_dir):
@@ -86,7 +86,7 @@ def test_append_reprepares_only_new_chunks(tmp_path, monkeypatch):
     cache = tmp_path / "layouts"
 
     _run(data, cache)
-    cold = delta_stats(reset=True)
+    cold = tracing.counters("delta", reset=True)
     assert cold.get("chunks_prepared", 0) >= 2, cold
     assert cold.get("chunks_reused", 0) == 0, cold
 
@@ -108,7 +108,7 @@ def test_append_reprepares_only_new_chunks(tmp_path, monkeypatch):
         grown = _run(data, cache)
     finally:
         monkeypatch.setattr(FusedAggregateStage, "_read_scan_file", real)
-    warm = delta_stats(reset=True)
+    warm = tracing.counters("delta", reset=True)
     assert warm.get("chunks_reused", 0) >= cold["chunks_prepared"], warm
     assert warm.get("chunks_prepared", 0) >= 1, warm
     assert warm.get("bytes_reprepared_saved", 0) > 0, warm
@@ -128,7 +128,7 @@ def test_warm_set_reuses_every_chunk(tmp_path, monkeypatch):
     pq.write_table(_part(3), str(data / "part-0.parquet"))
     cache = tmp_path / "layouts"
     first = _run(data, cache)
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
     _reset_stage_caches()
 
     from ballista_tpu.ops.stage import FusedAggregateStage
@@ -142,7 +142,7 @@ def test_warm_set_reuses_every_chunk(tmp_path, monkeypatch):
         warm = _run(data, cache)
     finally:
         monkeypatch.setattr(FusedAggregateStage, "_read_scan_file", real)
-    stats = delta_stats(reset=True)
+    stats = tracing.counters("delta", reset=True)
     assert stats.get("chunks_reused", 0) >= 1, stats
     assert stats.get("chunks_prepared", 0) == 0, stats
     assert warm.equals(first)
@@ -180,7 +180,7 @@ def test_midappend_write_fails_closed(tmp_path):
         _run(data, cache)
     finally:
         FusedAggregateStage._read_scan_file = real
-    stats = delta_stats(reset=True)
+    stats = tracing.counters("delta", reset=True)
     assert stats.get("save_declined_midappend", 0) >= 1, stats
 
     # another process raced the same window: it fingerprinted at the OLD
@@ -212,7 +212,7 @@ def test_tampered_chunk_identity_misses(tmp_path):
     pq.write_table(_part(9), str(data / "part-0.parquet"))
     cache = tmp_path / "layouts"
     first = _run(data, cache)
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
 
     metas = list(cache.rglob("meta.json"))
     assert metas
@@ -223,7 +223,7 @@ def test_tampered_chunk_identity_misses(tmp_path):
             json.dump(m, open(mp, "w"))
     _reset_stage_caches()
     again = _run(data, cache)
-    stats = delta_stats(reset=True)
+    stats = tracing.counters("delta", reset=True)
     assert stats.get("chunks_reused", 0) == 0, stats
     assert stats.get("chunks_prepared", 0) >= 1, stats
     assert again.equals(first)

@@ -19,11 +19,7 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.proto import ballista_pb2 as pb
-from ballista_tpu.ops.runtime import (
-    fleet_stats,
-    recovery_stats,
-    shuffle_tier_stats,
-)
+from ballista_tpu.utils import tracing
 
 GROUP_SQL = (
     "select region, sum(amount) as s from sales group by region order by region"
@@ -95,7 +91,7 @@ def test_shared_publish_layout_and_counters(shared_dir, tmp_path):
         work_dir=str(tmp_path / "work"),
         job_id="jx",
     )
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     stats = w.execute_shuffle_write(0, ctx)
     assert stats.num_rows == 6
     base = os.path.join(shared_dir, "jx", "2", "0")
@@ -104,7 +100,7 @@ def test_shared_publish_layout_and_counters(shared_dir, tmp_path):
     # nothing under the work dir, no tmp residue in storage
     assert not os.path.exists(os.path.join(str(tmp_path / "work"), "jx"))
     assert not [p for p in pieces if ".tmp-" in p]
-    st = shuffle_tier_stats(reset=True)
+    st = tracing.counters("shuffle_tier", reset=True)
     assert st.get("storage_publish") == 1, st
 
 
@@ -128,13 +124,13 @@ def test_shuffle_store_write_chaos_tears_publish_atomically(shared_dir, tmp_path
         work_dir=str(tmp_path / "work"),
         job_id="jx",
     )
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     with pytest.raises(ChaosInjected):
         w.execute_shuffle_write(0, ctx)
     base = os.path.join(shared_dir, "jx", "2", "0")
     published = os.listdir(base) if os.path.isdir(base) else []
     assert published == [], published
-    st = shuffle_tier_stats(reset=True)
+    st = tracing.counters("shuffle_tier", reset=True)
     assert st.get("storage_publish_torn") == 1, st
 
 
@@ -171,11 +167,11 @@ def test_reader_resolves_storage_first_without_any_peer(shared_dir, tmp_path):
         work_dir=str(tmp_path / "work2"), job_id="jy",
         shuffle_fetcher=None,
     )
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     rows = sum(b.num_rows for b in reader.execute(0, rctx))
     rows += sum(b.num_rows for b in reader.execute(1, rctx))
     assert rows == 6
-    st = shuffle_tier_stats(reset=True)
+    st = tracing.counters("shuffle_tier", reset=True)
     assert st.get("storage_fetch") == 2, st
     assert "storage_fallback_peer" not in st, st
 
@@ -194,11 +190,11 @@ def test_reader_missing_storage_piece_degrades_to_lineage(shared_dir, tmp_path):
         config=BallistaConfig(_shared_settings(shared_dir)),
         work_dir=str(tmp_path / "work"), job_id="jy",
     )
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     with pytest.raises(ShuffleFetchError) as ei:
         list(reader.execute(0, rctx))
     assert ei.value.stage_id == 2 and ei.value.map_partition == 0
-    st = shuffle_tier_stats(reset=True)
+    st = tracing.counters("shuffle_tier", reset=True)
     assert st.get("storage_fallback_peer") == 1, st
 
 
@@ -283,10 +279,10 @@ def test_reset_lost_tasks_keeps_storage_homed_outputs():
     s.save_job_metadata("j", running)
     s.save_task_status(_completed_task("j", 1, 0, "dead", storage_uri="/s/j/1/0"))
     s.save_task_status(_completed_task("j", 1, 1, "dead"))
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     reset = s.reset_lost_tasks()  # nobody holds a lease: "dead" is dead
     assert reset == 1, reset
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("storage_home_retained") == 1, stats
     assert stats.get("task_retry", 0) == 1, stats
     kept = s.get_task_status("j", 1, 0)
@@ -388,7 +384,7 @@ def _run_job_kill_owner_prefetch(sales_table, settings):
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
     cluster.scheduler_impl.lost_task_check_interval = 0.3
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr, settings=settings)
         ctx.register_record_batches("sales", sales_table, n_partitions=4)
@@ -402,7 +398,7 @@ def _run_job_kill_owner_prefetch(sales_table, settings):
         victim = next(ex for ex in cluster.executors if ex.id in owners)
         victim.stop()
         out = ctx._collect_results(job_id, plan.schema(), timeout=120.0)
-        stats = recovery_stats(reset=True)
+        stats = tracing.counters("recovery", reset=True)
         ctx.close()
         return out, stats
     finally:
@@ -419,11 +415,11 @@ def test_executor_death_after_completion_is_a_nonevent_on_shared_tier(
     shared tier with ZERO recovery events of any kind — the dead
     executor's pieces kept their storage home and the client read them
     from the mount — and results are bit-identical across the tiers."""
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     shared_out, shared_stats = _run_job_kill_owner_prefetch(
         sales_table, _shared_settings(shared_dir)
     )
-    tier = shuffle_tier_stats(reset=True)
+    tier = tracing.counters("shuffle_tier", reset=True)
     local_out, local_stats = _run_job_kill_owner_prefetch(
         sales_table, _local_settings()
     )
@@ -466,8 +462,8 @@ def test_executor_death_mid_job_shared_tier_zero_lineage_recompute(
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
     cluster.scheduler_impl.lost_task_check_interval = 0.3
-    recovery_stats(reset=True)
-    shuffle_tier_stats(reset=True)
+    tracing.counters("recovery", reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     try:
         ctx = BallistaContext(
             *cluster.scheduler_addr, settings=_shared_settings(shared_dir)
@@ -498,8 +494,8 @@ def test_executor_death_mid_job_shared_tier_zero_lineage_recompute(
         victim.stop()
         out = ctx._collect_results(job_id, plan.schema(), timeout=120.0)
         assert out.column("s").to_pylist() == [120.0, 40.0, 145.0]
-        stats = recovery_stats(reset=True)
-        tier = shuffle_tier_stats(reset=True)
+        stats = tracing.counters("recovery", reset=True)
+        tier = tracing.counters("shuffle_tier", reset=True)
         # ZERO lineage recomputation: the map outputs never needed it
         assert stats.get("fetch_failed", 0) == 0, stats
         assert stats.get("map_recomputed", 0) == 0, stats
@@ -541,8 +537,8 @@ def test_scale_in_during_running_job_bit_identical_zero_retries(
     # fleet.scale chaos is ARMED (autoscaler evaluations can be torn);
     # the explicit scale_in_one drives the same drain machinery
     # deterministically while the job runs.
-    fleet_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("fleet", reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(
         n_executors=2,
         config=BallistaConfig({
@@ -585,10 +581,10 @@ def test_scale_in_during_running_job_bit_identical_zero_retries(
     finally:
         cluster.shutdown()
     assert out.equals(ref), (out.to_pydict(), ref.to_pydict())
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("task_retry", 0) == 0, stats
     assert stats.get("orphan_reassigned", 0) == 0, stats
-    fl = fleet_stats(reset=True)
+    fl = tracing.counters("fleet", reset=True)
     assert fl.get("scale_down", 0) >= 1, fl
     assert fl.get("drain_completed", 0) >= 1, fl
     assert cluster.fleet_size() == 1
@@ -611,8 +607,8 @@ def test_autoscaler_grows_under_backlog_and_drains_idle(shared_dir):
         "v": pa.array(np.round(rng.uniform(-100, 100, n), 2)),
     })
     sql = "select g, sum(v) as s, count(*) as c from t group by g order by g"
-    fleet_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("fleet", reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(
         n_executors=1,
         config=BallistaConfig({
@@ -663,12 +659,12 @@ def test_autoscaler_grows_under_backlog_and_drains_idle(shared_dir):
         ctx.close()
     finally:
         cluster.shutdown()
-    fl = fleet_stats(reset=True)
+    fl = tracing.counters("fleet", reset=True)
     assert fl.get("scale_up", 0) >= 1, fl
     assert fl.get("scale_down", 0) >= 1, fl
     assert fl.get("drain_completed", 0) >= fl.get("scale_down", 0), fl
     assert peak > 1, f"fleet never grew (peak {peak})"
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("task_retry", 0) == 0, stats
 
 
@@ -686,7 +682,7 @@ def test_fleet_scale_chaos_skips_decisions():
             "fleet.scale", "scale1"
         )
     )
-    fleet_stats(reset=True)
+    tracing.counters("fleet", reset=True)
     cluster = StandaloneCluster(
         n_executors=2,
         config=BallistaConfig({
@@ -704,7 +700,7 @@ def test_fleet_scale_chaos_skips_decisions():
         # torn by chaos -> no action
         assert cluster.autoscale_once() == 0
         assert cluster.fleet_size() == 2
-        fl = fleet_stats(reset=True)
+        fl = tracing.counters("fleet", reset=True)
         assert fl.get("scale_chaos_skipped") == 1, fl
         assert fl.get("scale_down", 0) == 0, fl
     finally:

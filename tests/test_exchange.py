@@ -18,12 +18,8 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.ops import costmodel, exchange
-from ballista_tpu.ops.runtime import (
-    exchange_stats,
-    recovery_stats,
-    shuffle_tier_stats,
-)
 from ballista_tpu.proto import ballista_pb2 as pb
+from ballista_tpu.utils import tracing
 
 GROUP_SQL = (
     "select region, sum(amount) as s from sales group by region order by region"
@@ -33,10 +29,10 @@ GROUP_SQL = (
 @pytest.fixture(autouse=True)
 def _clean_registry():
     exchange.reset()
-    exchange_stats(reset=True)
+    tracing.counters("exchange", reset=True)
     yield
     exchange.reset()
-    exchange_stats(reset=True)
+    tracing.counters("exchange", reset=True)
 
 
 @pytest.fixture
@@ -78,7 +74,7 @@ def test_publish_resolve_roundtrip_and_counters():
     assert exchange.resident_bytes() == b.nbytes
     assert exchange.stage_resident("e1", "job", 2, 0)
     assert not exchange.stage_resident("e1", "job", 2, 1)
-    s = exchange_stats(reset=True)
+    s = tracing.counters("exchange", reset=True)
     assert s.get("published") == 1 and s.get("publish_bytes") == b.nbytes
 
 
@@ -89,7 +85,7 @@ def test_publish_rejects_over_budget_piece():
         budget=b.nbytes - 1,
     )
     assert exchange.resolve("e1", "j", 1, 0, 0) is None
-    assert exchange_stats(reset=True).get("skipped_budget") == 1
+    assert tracing.counters("exchange", reset=True).get("skipped_budget") == 1
 
 
 def test_budget_eviction_is_cost_gated_by_size(cm):
@@ -104,7 +100,7 @@ def test_budget_eviction_is_cost_gated_by_size(cm):
     assert not exchange.publish("e1", "j", 1, 1, 0, [small], small.schema, 0,
                                 "/p/small", budget)
     assert exchange.resolve("e1", "j", 1, 0, 0) is not None
-    assert exchange_stats(reset=True).get("skipped_budget") == 1
+    assert tracing.counters("exchange", reset=True).get("skipped_budget") == 1
     # bigger incomer displaces the smaller resident
     exchange.reset()
     assert exchange.publish("e1", "j", 1, 1, 0, [small], small.schema, 0,
@@ -113,7 +109,7 @@ def test_budget_eviction_is_cost_gated_by_size(cm):
                             "/p/big", budget)
     assert exchange.resolve("e1", "j", 1, 1, 0) is None
     assert exchange.resolve("e1", "j", 1, 0, 0) is not None
-    assert exchange_stats(reset=True).get("evicted_budget") == 1
+    assert tracing.counters("exchange", reset=True).get("evicted_budget") == 1
 
 
 def test_budget_eviction_prices_at_observed_rates(cm):
@@ -134,7 +130,7 @@ def test_budget_eviction_prices_at_observed_rates(cm):
     assert not exchange.publish("e1", "j", 1, 0, 0, [big], big.schema, 0,
                                 "/p/big", budget)
     assert exchange.resolve("e1", "j", 1, 1, 0) is not None
-    assert exchange_stats(reset=True).get("skipped_budget") == 1
+    assert tracing.counters("exchange", reset=True).get("skipped_budget") == 1
 
 
 def test_republish_newest_attempt_wins():
@@ -177,7 +173,7 @@ def test_tenant_budget_enforced_before_global(cm):
     assert exchange.resolve("e1", "j", 1, 2, 0) is not None
     assert exchange.tenant_resident_bytes("alice") == a2.nbytes
     assert exchange.tenant_resident_bytes("bob") == b1.nbytes
-    s = exchange_stats(reset=True)
+    s = tracing.counters("exchange", reset=True)
     assert s.get("evicted_tenant_budget") == 1, s
     assert not s.get("evicted_budget"), s
 
@@ -196,7 +192,7 @@ def test_tenant_budget_cost_gate_keeps_warmer_own_entry(cm):
                                 tenant="alice", tenant_budget=t_budget)
     assert exchange.resolve("e1", "j", 1, 0, 0) is not None
     assert exchange.tenant_resident_bytes("alice") == big.nbytes
-    s = exchange_stats(reset=True)
+    s = tracing.counters("exchange", reset=True)
     assert s.get("skipped_budget") == 1, s
     # a single piece bigger than the tenant cap is rejected outright
     assert not exchange.publish("e1", "j", 1, 2, 0, [big], big.schema, 0,
@@ -318,14 +314,14 @@ def test_gc_shared_store_job_sweeps_by_terminal_kind(tmp_path):
         (base / "0.arrow").write_bytes(b"x")
         tasks.append(_completed_task("jobc", stage, 0, str(base)))
     st = _state()
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     # completed: intermediates sweep, the final stage stays for the client
     assert st._gc_shared_store_job("jobc", 3, tasks) == 2
     assert sorted(os.listdir(root / "jobc")) == ["3"]
     # failed: everything releases, the emptied job dir prunes with it
     assert st._gc_shared_store_job("jobc", None, tasks) == 1
     assert not (root / "jobc").exists()
-    assert shuffle_tier_stats(reset=True).get("gc_stage_swept") == 3
+    assert tracing.counters("shuffle_tier", reset=True).get("gc_stage_swept") == 3
     # work-dir-homed tasks (empty storage_uri) are never the scheduler's
     assert st._gc_shared_store_job(
         "jobl", None, [_completed_task("jobl", 1, 0)]
@@ -363,7 +359,7 @@ def test_result_cache_delete_sweeps_cached_final_stage(tmp_path):
         pl.storage_uri = str(base)
         assert st.result_cache_put(fp, done)
 
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     put("fp-a", "joba")
     st.result_cache_invalidate("fp-a")
     assert not (root / "joba").exists()
@@ -372,7 +368,7 @@ def test_result_cache_delete_sweeps_cached_final_stage(tmp_path):
     put("fp-c", "jobc")
     assert not (root / "jobb").exists()
     assert (root / "jobc").exists()
-    assert shuffle_tier_stats(reset=True).get("gc_result_swept") == 2
+    assert tracing.counters("shuffle_tier", reset=True).get("gc_result_swept") == 2
 
 
 # -- end to end ---------------------------------------------------------------
@@ -392,8 +388,8 @@ def _run_cluster(table, settings, n_executors=1):
     from ballista_tpu.executor.runtime import StandaloneCluster
 
     exchange.reset()
-    exchange_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("exchange", reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=n_executors)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr, settings={
@@ -406,7 +402,7 @@ def _run_cluster(table, settings, n_executors=1):
         ctx.close()
     finally:
         cluster.shutdown()
-    return out, exchange_stats(reset=True), recovery_stats(reset=True)
+    return out, tracing.counters("exchange", reset=True), tracing.counters("recovery", reset=True)
 
 
 def test_same_executor_consumer_skips_reupload_bit_identical():
@@ -456,7 +452,7 @@ def test_executor_death_with_resident_only_consumer_recovers():
     from ballista_tpu.executor.runtime import StandaloneCluster
 
     exchange.reset()
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=2)
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
@@ -492,7 +488,7 @@ def test_executor_death_with_resident_only_consumer_recovers():
         np.testing.assert_allclose(
             got.column("s").to_pylist(), expected.column("s").to_pylist()
         )
-        stats = recovery_stats(reset=True)
+        stats = tracing.counters("recovery", reset=True)
         assert stats.get("result_partition_restarted", 0) > 0, stats
     finally:
         state_mod.EXECUTOR_LEASE_SECS = old_lease
@@ -508,7 +504,7 @@ def test_terminal_gc_sweeps_intermediates_on_shared_tier(tmp_path):
 
     shared = tmp_path / "store"
     shared.mkdir()
-    shuffle_tier_stats(reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     cluster = StandaloneCluster(n_executors=1)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr, settings={
@@ -527,5 +523,5 @@ def test_terminal_gc_sweeps_intermediates_on_shared_tier(tmp_path):
     assert len(jobs) == 1, jobs
     stages = os.listdir(shared / jobs[0])
     assert len(stages) == 1, stages  # only the final stage survives
-    tier = shuffle_tier_stats(reset=True)
+    tier = tracing.counters("shuffle_tier", reset=True)
     assert tier.get("gc_stage_swept", 0) >= 1, tier

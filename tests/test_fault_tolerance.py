@@ -19,6 +19,7 @@ import pytest
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend, SqliteBackend
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 
 
 def _meta(i, port=1):
@@ -259,8 +260,6 @@ def _two_stage_state(max_retries="3"):
 # -- a stage's plan crosses the wire once (ISSUE 27) ---------------------------
 
 def _codec_counts():
-    from ballista_tpu.utils import tracing
-
     c = tracing.counters()
     return c.get("serde.plan_decode", 0), c.get("serde.plan_encode", 0)
 
@@ -726,10 +725,9 @@ def test_end_to_end_recovery_after_executor_death_with_lost_outputs(sales_table)
     """Executor killed AFTER its map stage completed: outputs lost while
     downstream reduces run. The job must still complete on the survivor via
     lineage recomputation (fetch_failed -> map recompute, lost-task resets),
-    with nonzero recovery counters in the new bench fields."""
+    with nonzero recovery counters."""
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
     from ballista_tpu.serde.logical import plan_to_proto
     import ballista_tpu.scheduler.state as state_mod
 
@@ -737,7 +735,7 @@ def test_end_to_end_recovery_after_executor_death_with_lost_outputs(sales_table)
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
     cluster.scheduler_impl.lost_task_check_interval = 0.3
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr)
         ctx.register_record_batches("sales", sales_table, n_partitions=4)
@@ -782,7 +780,7 @@ def test_end_to_end_recovery_after_executor_death_with_lost_outputs(sales_table)
         out = pa.concat_tables(tables).cast(plan.schema())
         assert out.column("s").to_pylist() == [120.0, 40.0, 145.0]
 
-        stats = recovery_stats()
+        stats = tracing.counters("recovery")
         recovered = (
             stats.get("fetch_failed", 0)
             + stats.get("map_recomputed", 0)
@@ -808,14 +806,13 @@ def test_completed_job_with_lost_result_partitions_restarts(sales_table):
     lineage/retry machinery — the collect returns correct results."""
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
     import ballista_tpu.scheduler.state as state_mod
 
     cluster = StandaloneCluster(n_executors=2)
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
     cluster.scheduler_impl.lost_task_check_interval = 0.3
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     try:
         ctx = BallistaContext(*cluster.scheduler_addr)
         ctx.register_record_batches("sales", sales_table, n_partitions=4)
@@ -839,7 +836,7 @@ def test_completed_job_with_lost_result_partitions_restarts(sales_table):
         out = ctx._collect_results(job_id, plan.schema(), timeout=120.0)
         assert out.column("s").to_pylist() == [120.0, 40.0, 145.0]
 
-        stats = recovery_stats()
+        stats = tracing.counters("recovery")
         assert stats.get("result_partition_restarted", 0) > 0, stats
         assert stats.get("completed_job_restarted", 0) > 0, stats
         assert stats.get("result_fetch_restarted", 0) > 0, stats

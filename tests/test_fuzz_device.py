@@ -14,6 +14,7 @@ import pytest
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.engine import ExecutionContext
 from ballista_tpu.ops import kernels
+from ballista_tpu.utils import tracing
 
 
 def _fresh():
@@ -361,11 +362,18 @@ def _distributed_fuzz_queries(qrng, k=2):
     return out
 
 
-def _run_distributed(table, queries, client_settings, cluster_config=None):
+def _run_distributed(table, queries, client_settings, cluster_config=None,
+                     lost_task_check_interval=None):
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
 
     cluster = StandaloneCluster(n_executors=2, config=cluster_config)
+    if lost_task_check_interval is not None:
+        # a run that kills an executor: until the lost-task check sees the
+        # expired lease, every failed duplicate of a dead primary's task is
+        # speculated again at once, a poll each. At the default 5 s that is
+        # 390-430 polls of the survivor, whose death seed holds for 400.
+        cluster.scheduler_impl.lost_task_check_interval = lost_task_check_interval
     try:
         ctx = BallistaContext(*cluster.scheduler_addr, settings=client_settings)
         ctx.register_record_batches("t", table, n_partitions=4)
@@ -385,7 +393,6 @@ def test_fuzz_distributed_two_stage_chaos(seed):
     all recover to BIT-IDENTICAL results. Own rng streams (14000+ data,
     15000+ queries), so every baseline stream above stays byte-identical."""
     from ballista_tpu.config import BallistaConfig
-    from ballista_tpu.ops.runtime import recovery_stats
 
     rng = np.random.default_rng(14000 + seed)
     qrng = np.random.default_rng(15000 + seed)
@@ -419,9 +426,9 @@ def test_fuzz_distributed_two_stage_chaos(seed):
         "ballista.chaos.sites": "kv.put,scheduler.plan_write",
         "ballista.shuffle.max_task_retries": "5",
     })
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     chaotic = _run_distributed(table, queries, chaos_client, chaos_cluster)
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     for sql, c, t in zip(queries, clean, chaotic):
         assert t.equals(c), (sql, t.to_pydict(), c.to_pydict())
     assert stats.get("chaos_injected", 0) > 0, stats
@@ -438,7 +445,6 @@ def test_fuzz_concurrent_submission_cache(seed):
     byte-identical."""
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import tenancy_stats
 
     rng = np.random.default_rng(16000 + seed)
     qrng = np.random.default_rng(17000 + seed)
@@ -467,7 +473,7 @@ def test_fuzz_concurrent_submission_cache(seed):
     )
     cluster = StandaloneCluster(n_executors=2)
     try:
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         results = {}
         errors = []
 
@@ -505,7 +511,7 @@ def test_fuzz_concurrent_submission_cache(seed):
                 assert got.equals(cold[qi]), (
                     i, queries[qi], got.to_pydict(), cold[qi].to_pydict()
                 )
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         total = sum(len(s) for s in schedules)
         assert stats.get("cache_hit", 0) > 0, (stats, schedules)
         assert stats.get("cache_hit", 0) + stats.get("cache_miss", 0) >= total
@@ -574,7 +580,6 @@ def test_fuzz_speculation_straggler(seed):
     queries), so every baseline stream above stays byte-identical."""
     from ballista_tpu.config import BallistaConfig
     from ballista_tpu.ops import costmodel
-    from ballista_tpu.ops.runtime import recovery_stats, speculation_stats
 
     rng = np.random.default_rng(20000 + seed)
     qrng = np.random.default_rng(21000 + seed)
@@ -612,11 +617,11 @@ def test_fuzz_speculation_straggler(seed):
         "ballista.chaos.sites": "task.slow",
         "ballista.chaos.slow_ms": "2000",
     }
-    recovery_stats(reset=True)
-    speculation_stats(reset=True)
+    tracing.counters("recovery", reset=True)
+    tracing.counters("speculation", reset=True)
     chaotic = _run_distributed(table, queries, chaos_client, spec_cluster)
-    rec = recovery_stats(reset=True)
-    spec = speculation_stats(reset=True)
+    rec = tracing.counters("recovery", reset=True)
+    spec = tracing.counters("speculation", reset=True)
     costmodel.reset()
     for sql, c, t in zip(queries, clean, chaotic):
         assert t.equals(c), (sql, t.to_pydict(), c.to_pydict())
@@ -715,7 +720,6 @@ def test_fuzz_shared_tier_chaos(seed, tmp_path):
 
     import ballista_tpu.scheduler.state as state_mod
     from ballista_tpu.config import BallistaConfig
-    from ballista_tpu.ops.runtime import recovery_stats, shuffle_tier_stats
     from ballista_tpu.utils.chaos import ChaosInjector
 
     rng = np.random.default_rng(24000 + seed)
@@ -778,14 +782,15 @@ def test_fuzz_shared_tier_chaos(seed, tmp_path):
     })
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
-    recovery_stats(reset=True)
-    shuffle_tier_stats(reset=True)
+    tracing.counters("recovery", reset=True)
+    tracing.counters("shuffle_tier", reset=True)
     try:
-        chaotic = _run_distributed(table, queries, chaos_client, chaos_cluster)
+        chaotic = _run_distributed(table, queries, chaos_client, chaos_cluster,
+                                   lost_task_check_interval=0.3)
     finally:
         state_mod.EXECUTOR_LEASE_SECS = old_lease
-    stats = recovery_stats(reset=True)
-    tier = shuffle_tier_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
+    tier = tracing.counters("shuffle_tier", reset=True)
     for sql, c, t in zip(queries, clean, chaotic):
         assert t.equals(c), (sql, t.to_pydict(), c.to_pydict())
     assert stats.get("chaos_injected", 0) > 0, stats
@@ -808,7 +813,6 @@ def test_fuzz_exchange_chaos(seed):
     import ballista_tpu.scheduler.state as state_mod
     from ballista_tpu.config import BallistaConfig
     from ballista_tpu.ops import exchange
-    from ballista_tpu.ops.runtime import exchange_stats, recovery_stats
     from ballista_tpu.utils.chaos import ChaosInjector
 
     rng = np.random.default_rng(26000 + seed)
@@ -865,14 +869,15 @@ def test_fuzz_exchange_chaos(seed):
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
     exchange.reset()
-    exchange_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("exchange", reset=True)
+    tracing.counters("recovery", reset=True)
     try:
-        chaotic = _run_distributed(table, queries, chaos_client, chaos_cluster)
+        chaotic = _run_distributed(table, queries, chaos_client, chaos_cluster,
+                                   lost_task_check_interval=0.3)
     finally:
         state_mod.EXECUTOR_LEASE_SECS = old_lease
-    stats = recovery_stats(reset=True)
-    ex = exchange_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
+    ex = tracing.counters("exchange", reset=True)
     for sql, c, t in zip(queries, clean, chaotic):
         assert t.equals(c), (sql, t.to_pydict(), c.to_pydict())
     assert stats.get("chaos_injected", 0) > 0, stats
@@ -928,7 +933,6 @@ def test_fuzz_delta_append(tmp_path, seed):
 
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import delta_stats
 
     rng = np.random.default_rng(28000 + seed)
     qrng = np.random.default_rng(29000 + seed)
@@ -977,9 +981,9 @@ def test_fuzz_delta_append(tmp_path, seed):
         finally:
             cluster.shutdown()
 
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
     grown, truth = run_grow()
-    stats = delta_stats(reset=True)
+    stats = tracing.counters("delta", reset=True)
     for sql, g, t in zip(queries, grown, truth):
         assert g.equals(t), (sql, g.to_pydict(), t.to_pydict())
     # the eligible shapes advanced; the float-sum shape declined loudly
@@ -990,13 +994,13 @@ def test_fuzz_delta_append(tmp_path, seed):
     # chaos pass's cold queries hit the first pass's (shared content-key)
     # cache entries; its append then forces a NEW advancement attempt
     # whose publish the chaos site tears.
-    delta_stats(reset=True)
+    tracing.counters("delta", reset=True)
     chaos_grown, chaos_truth = run_grow(BallistaConfig({
         "ballista.chaos.rate": "1.0",
         "ballista.chaos.seed": str(70 + seed),
         "ballista.chaos.sites": "cache.advance",
     }))
-    stats = delta_stats(reset=True)
+    stats = tracing.counters("delta", reset=True)
     for sql, g, t in zip(queries, chaos_grown, chaos_truth):
         assert g.equals(t), (sql, g.to_pydict(), t.to_pydict())
     assert stats.get("advance_hits", 0) == 0, stats
@@ -1018,7 +1022,6 @@ def test_fuzz_replica_failover(seed):
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.config import BallistaConfig
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     rng = np.random.default_rng(30000 + seed)
     qrng = np.random.default_rng(31000 + seed)
@@ -1040,7 +1043,7 @@ def test_fuzz_replica_failover(seed):
     )
 
     _fresh()
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(
         n_executors=2,
         n_schedulers=2,
@@ -1070,7 +1073,7 @@ def test_fuzz_replica_failover(seed):
     for sql, g, o in zip(queries, got, oracle):
         assert g.equals(o), (seed, kill_after, sql,
                              g.to_pydict(), o.to_pydict())
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     # the survivor finished every post-kill query without a single task
     # re-execution: failover is a control-plane event, not a data redo
     assert stats.get("task_retry", 0) == 0, stats

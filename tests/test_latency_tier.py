@@ -31,15 +31,11 @@ from ballista_tpu.client import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.executor.runtime import StandaloneCluster
 from ballista_tpu.ops import aotcache
-from ballista_tpu.ops.runtime import (
-    recovery_stats,
-    serving_stats,
-    tenancy_stats,
-)
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend, SqliteBackend
 from ballista_tpu.scheduler.server import SchedulerServer, _PushSubscriber
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 
 logging.getLogger("ballista.executor").setLevel(logging.CRITICAL)
 
@@ -141,7 +137,7 @@ def test_stale_attempt_push_rejected(tpath):
         assert server._pump_pushes() == 1
     td = sub.queue.get_nowait()
     pid = td.task_id
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     # the task is requeued (e.g. orphan reconciliation) -> attempt 1
     with st.kv.lock():
         cur = st.get_task_status(pid.job_id, pid.stage_id, pid.partition_id)
@@ -154,7 +150,7 @@ def test_stale_attempt_push_rejected(tpath):
     late.completed.path = "/stale"
     with st.kv.lock():
         assert not st.accept_task_status(late)
-    assert recovery_stats(reset=True).get("stale_status_dropped") == 1
+    assert tracing.counters("recovery", reset=True).get("stale_status_dropped") == 1
     # pump re-verification: the stale outstanding entry no longer matches
     # the KV (attempt moved on), so its credit frees and the retry pushes
     with st.kv.lock():
@@ -175,13 +171,13 @@ def test_push_chaos_kills_stream_and_leaves_assignment(tpath):
     sub = _PushSubscriber("e1", slots=2)
     with server._push_mu:
         server._subscribers["e1"] = sub
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     with server.state.kv.lock():
         assert server._pump_pushes() == 0
     assert sub.closed.is_set()
     # nothing delivered: the queue holds only the close() sentinel
     assert sub.queue.get_nowait() is None and sub.queue.qsize() == 0
-    assert recovery_stats(reset=True).get("chaos_push_torn") == 1
+    assert tracing.counters("recovery", reset=True).get("chaos_push_torn") == 1
     # the assignment stands (Running, in the durable ledger), exactly like
     # a PollWork response lost in transit
     running = [
@@ -260,17 +256,17 @@ def _args():
 def test_aot_roundtrip_disk_hit_and_prewarm(tmp_path):
     aotcache.reset(clear_disk_dir=True)
     step = _wrapped(tmp_path)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     out1 = np.asarray(step(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_trace") == 1 and s.get("aot_saved") == 1
     np.testing.assert_array_equal(out1, np.asarray(step(*_args())))
-    assert serving_stats(reset=True).get("compile_hit_memory") == 1
+    assert tracing.counters("serving", reset=True).get("compile_hit_memory") == 1
     # cold process: fresh wrapper + empty memory map -> disk hit, same bits
     aotcache.reset()
     step2 = _wrapped(tmp_path)
     out2 = np.asarray(step2(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_hit_disk") == 1 and not s.get("compile_trace")
     np.testing.assert_array_equal(out1, out2)
     # prewarm: artifacts compile BEFORE any call; the call is a memory hit
@@ -279,11 +275,11 @@ def test_aot_roundtrip_disk_hit_and_prewarm(tmp_path):
         BallistaConfig({"ballista.tpu.aot_cache": str(tmp_path / "aot")})
     )
     assert n == 1
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_prewarmed") == 1
     step3 = _wrapped(tmp_path)
     out3 = np.asarray(step3(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_hit_memory") == 1 and not s.get("compile_trace")
     np.testing.assert_array_equal(out1, out3)
 
@@ -310,16 +306,16 @@ def test_warm_compiles_without_execute(tmp_path):
     step = aotcache.wrap_step(
         _Owner("warm-A"), "unit", core, static_argnums=(0,)
     )
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     assert step.warm(*_args()) is True
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_warmed") == 1 and s.get("aot_saved") == 1
     assert not s.get("compile_trace")
     warm_traces = traces["n"]
     assert warm_traces >= 1  # the warm itself traced (a compile happened)
     # the real call: memory-map hit + jit executable-cache hit — NO retrace
     out = np.asarray(step(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_hit_memory") == 1 and not s.get("compile_trace")
     assert traces["n"] == warm_traces  # compile-without-execute held: the
     # signature was never traced again after the warm
@@ -330,9 +326,9 @@ def test_warm_compiles_without_execute(tmp_path):
     step2 = aotcache.wrap_step(
         _Owner("warm-A"), "unit", core, static_argnums=(0,)
     )
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     assert step2.warm(*_args()) is True
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_hit_disk") == 1 and not s.get("compile_warmed")
     np.testing.assert_array_equal(out, np.asarray(step2(*_args())))
 
@@ -344,7 +340,7 @@ def test_aot_shape_and_stage_keyed(tmp_path):
 
     aotcache.reset(clear_disk_dir=True)
     step = _wrapped(tmp_path)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     step(*_args())
     wide = (
         3,
@@ -355,7 +351,7 @@ def test_aot_shape_and_stage_keyed(tmp_path):
     step(*wide)  # new shape bucket -> fresh trace
     other = _wrapped(tmp_path, key="stage-B")
     other(*_args())  # new stage identity -> fresh trace
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("compile_trace") == 3 and not s.get("compile_hit_memory")
 
 
@@ -372,9 +368,9 @@ def test_aot_corrupted_artifact_falls_back(tmp_path):
         f.write(header + b"\n" + b"garbage-not-a-program")
     aotcache.reset()
     step2 = _wrapped(tmp_path)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     out2 = np.asarray(step2(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("aot_load_error") == 1  # reason recorded
     assert s.get("compile_trace") == 1  # fell back to a fresh compile
     np.testing.assert_array_equal(out1, out2)
@@ -398,18 +394,18 @@ def test_aot_fingerprint_mismatch_falls_back(tmp_path):
         ).encode() + b"\n" + blob)
     aotcache.reset()
     step2 = _wrapped(tmp_path)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     out2 = np.asarray(step2(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("aot_load_error") == 1 and s.get("compile_trace") == 1
     np.testing.assert_array_equal(out1, out2)
     # prewarm skips it the same way
     aotcache.reset()
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     assert aotcache.prewarm(
         BallistaConfig({"ballista.tpu.aot_cache": str(tmp_path / "aot")})
     ) == 0
-    assert serving_stats(reset=True).get("aot_load_error") == 1
+    assert tracing.counters("serving", reset=True).get("aot_load_error") == 1
 
 
 def test_aot_load_chaos_torn(tmp_path):
@@ -424,9 +420,9 @@ def test_aot_load_chaos_torn(tmp_path):
         chaos={"ballista.chaos.rate": "1.0",
                "ballista.chaos.sites": "aot.load"},
     )
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     out2 = np.asarray(step2(*_args()))
-    s = serving_stats(reset=True)
+    s = tracing.counters("serving", reset=True)
     assert s.get("aot_load_error") == 1 and s.get("compile_trace") == 1
     np.testing.assert_array_equal(out1, out2)
 
@@ -436,9 +432,9 @@ def test_aot_bypasses_without_key_or_dir(tmp_path):
     the wrapper is a plain jit passthrough — no counters, no files."""
     aotcache.reset(clear_disk_dir=True)
     step = _wrapped(tmp_path, key=None)
-    serving_stats(reset=True)
+    tracing.counters("serving", reset=True)
     step(*_args())
-    assert serving_stats(reset=True) == {}
+    assert tracing.counters("serving", reset=True) == {}
     assert aotcache.manifest_entries(str(tmp_path / "aot")) == []
 
 
@@ -455,12 +451,12 @@ def test_push_dispatch_e2e_zero_poll(tpath):
             settings={"ballista.cache.results": "false"},
         )
         ctx.register_parquet("t", tpath)
-        serving_stats(reset=True)
+        tracing.counters("serving", reset=True)
         q = "select k, sum(v) as s from t group by k order by k"
         first = ctx.sql(q).collect()
         again = ctx.sql(q).collect()
         assert again.equals(first)
-        s = serving_stats(reset=True)
+        s = tracing.counters("serving", reset=True)
         assert s.get("dispatch_push", 0) > 0
         assert s.get("dispatch_poll", 0) == 0, s
         assert s.get("task_pushed") == s.get("dispatch_push")
@@ -487,9 +483,9 @@ def test_stream_drop_poll_fallback_then_resubscribe(tpath):
         cluster.scheduler_impl.push_enabled = False
         ex.poll_loop._cancel_push()
         assert _wait_for(lambda: not ex.poll_loop._stream_ok.is_set())
-        serving_stats(reset=True)
+        tracing.counters("serving", reset=True)
         out = ctx.sql(q).collect()
-        s = serving_stats(reset=True)
+        s = tracing.counters("serving", reset=True)
         assert out.equals(base)
         assert s.get("dispatch_poll", 0) > 0, s
         assert s.get("dispatch_push", 0) == 0
@@ -497,9 +493,9 @@ def test_stream_drop_poll_fallback_then_resubscribe(tpath):
         # reconnects by itself and dispatch returns to push
         cluster.scheduler_impl.push_enabled = True
         assert _wait_for(lambda: ex.poll_loop._stream_ok.is_set())
-        serving_stats(reset=True)
+        tracing.counters("serving", reset=True)
         out2 = ctx.sql(q).collect()
-        s = serving_stats(reset=True)
+        s = tracing.counters("serving", reset=True)
         assert out2.equals(base)
         assert s.get("dispatch_push", 0) > 0
         assert s.get("dispatch_poll", 0) == 0, s
@@ -563,9 +559,9 @@ def test_aot_warm_push_query_zero_trace_zero_poll(tmp_path, tpath):
         ctx = BallistaContext(*cluster.scheduler_addr, settings=settings)
         ctx.register_parquet("t", tpath)
         cold = ctx.sql(q).collect()  # traces + persists the programs
-        assert serving_stats(reset=True).get("compile_trace", 0) > 0
+        assert tracing.counters("serving", reset=True).get("compile_trace", 0) > 0
         warm = ctx.sql(q).collect()
-        s = serving_stats(reset=True)
+        s = tracing.counters("serving", reset=True)
         assert warm.equals(cold)
         assert s.get("compile_trace", 0) == 0, s
         assert s.get("compile_hit_memory", 0) > 0
@@ -581,12 +577,12 @@ def test_aot_warm_push_query_zero_trace_zero_poll(tmp_path, tpath):
         config=BallistaConfig({**settings, "ballista.tpu.prewarm": "true"}),
     )
     try:
-        prewarmed = serving_stats(reset=True)
+        prewarmed = tracing.counters("serving", reset=True)
         assert prewarmed.get("compile_prewarmed", 0) > 0
         ctx = BallistaContext(*cluster.scheduler_addr, settings=settings)
         ctx.register_parquet("t", tpath)
         first = ctx.sql(q).collect()
-        s = serving_stats(reset=True)
+        s = tracing.counters("serving", reset=True)
         assert first.equals(cold)
         assert s.get("compile_trace", 0) == 0, s
         assert s.get("dispatch_poll", 0) == 0 and s.get("dispatch_push", 0) > 0
@@ -672,10 +668,10 @@ def test_streaming_lost_partition_recovers(tpath):
             ex for ex in cluster.executors if ex.id in owners
         )
         victim.stop()
-        recovery_stats(reset=True)
+        tracing.counters("recovery", reset=True)
         out = ctx._collect_results(job_id, plan.schema(), timeout=120)
         assert out.equals(baseline)
-        rec = recovery_stats(reset=True)
+        rec = tracing.counters("recovery", reset=True)
         assert rec.get("result_fetch_restarted", 0) >= 1
         assert rec.get("result_partition_restarted", 0) >= 1
         ctx.close()
@@ -720,13 +716,13 @@ def test_push_chaos_bit_identical(tpath):
     fault-free run. The seed is scanned so the run provably injects."""
     fault_free = _chaos_push_run(tpath, 0.0, 0)
     for seed in range(20):
-        recovery_stats(reset=True)
-        serving_stats(reset=True)
+        tracing.counters("recovery", reset=True)
+        tracing.counters("serving", reset=True)
         out = _chaos_push_run(tpath, 0.4, seed)
         assert out.equals(fault_free), f"seed {seed} diverged"
-        rec = recovery_stats(reset=True)
+        rec = tracing.counters("recovery", reset=True)
         if rec.get("chaos_push_torn"):
-            assert serving_stats(reset=True).get("push_stream_drop", 0) >= 1
+            assert tracing.counters("serving", reset=True).get("push_stream_drop", 0) >= 1
             return
     pytest.fail("no seed in range injected a scheduler.push fault")
 
@@ -756,7 +752,7 @@ def test_result_cache_eviction_lru_by_last_hit():
         config=BallistaConfig({"ballista.cache.results.max_entries": "3"}),
     )
     _reg(st)
-    tenancy_stats(reset=True)
+    tracing.counters("tenancy", reset=True)
     for i in range(3):
         assert st.result_cache_put(f"fp{i}", _completed(f"/p{i}"))
         time.sleep(0.01)
@@ -769,7 +765,7 @@ def test_result_cache_eviction_lru_by_last_hit():
         if st.kv.get(st._key("resultcache", f"fp{i}")) is not None
     ]
     assert present == [0, 2, 3], present
-    assert tenancy_stats(reset=True).get("cache_evicted") == 1
+    assert tracing.counters("tenancy", reset=True).get("cache_evicted") == 1
 
 
 def test_result_cache_ttl_expiry():
@@ -781,9 +777,9 @@ def test_result_cache_ttl_expiry():
     assert st.result_cache_put("fpx", _completed("/x"))
     assert st.result_cache_lookup("fpx") is not None  # fresh: still a hit
     time.sleep(0.1)
-    tenancy_stats(reset=True)
+    tracing.counters("tenancy", reset=True)
     assert st.result_cache_lookup("fpx") is None
-    stats = tenancy_stats(reset=True)
+    stats = tracing.counters("tenancy", reset=True)
     assert stats.get("cache_expired") == 1
     assert st.kv.get(st._key("resultcache", "fpx")) is None
 

@@ -29,6 +29,7 @@ from dev.analysis.lockgraph import (  # noqa: E402
 )
 from dev.analysis.rules_lockorder import RULE, build_graph, static_edges  # noqa: E402
 from ballista_tpu.utils import locks  # noqa: E402
+from ballista_tpu.utils import tracing
 
 
 def _site(src, dst, line=1, func="f", via=""):
@@ -642,13 +643,11 @@ def test_witness_chaos_e2e_zero_violations_zero_missed(tmp_path):
 
     import time
 
-    from ballista_tpu.ops.runtime import recovery_stats
-
     locks.reset_witness()
     locks.enable_witness()
     old_lease = state_mod.EXECUTOR_LEASE_SECS
     state_mod.EXECUTOR_LEASE_SECS = 1.0
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=2, config=BallistaConfig({
         "ballista.debug.lock_witness": "1",
         "ballista.chaos.rate": "0.005",
@@ -668,7 +667,7 @@ def test_witness_chaos_e2e_zero_violations_zero_missed(tmp_path):
         # polls at 250ms), then restart the scheduler on the same store
         # (ISSUE 6 path) and re-run on the degraded cluster
         deadline = time.time() + 10
-        while time.time() < deadline and not recovery_stats().get(
+        while time.time() < deadline and not tracing.counters("recovery").get(
             "chaos_executor_death"
         ):
             time.sleep(0.1)
@@ -681,7 +680,7 @@ def test_witness_chaos_e2e_zero_violations_zero_missed(tmp_path):
         cluster.shutdown()
         locks.disable_witness()
 
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("chaos_executor_death", 0) >= 1, stats
     assert stats.get("scheduler_restart", 0) >= 1, stats
     violations = locks.witness_violations()

@@ -28,11 +28,11 @@ import pytest
 from ballista_tpu.client import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.executor.runtime import StandaloneCluster
-from ballista_tpu.ops.runtime import recovery_stats, tenancy_stats
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.fingerprint import plan_fingerprint
 from ballista_tpu.scheduler.kv import MemoryBackend, SqliteBackend
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 
 logging.getLogger("ballista.executor").setLevel(logging.CRITICAL)
 
@@ -215,7 +215,7 @@ def test_quota_blocks_saturating_tenant():
     assert s.accept_task_status(done)
     a4 = s.assign_next_schedulable_task("e1")
     assert a4 is not None and a4[0].partition_id.job_id == "aaaa"
-    assert tenancy_stats(reset=True).get("admit_quota_deferred", 0) >= 1
+    assert tracing.counters("tenancy", reset=True).get("admit_quota_deferred", 0) >= 1
 
 
 def test_fair_share_prefers_light_tenant():
@@ -302,7 +302,7 @@ def test_result_cache_roundtrip_and_liveness():
     kv = MemoryBackend()
     s = SchedulerState(kv, "t")
     s.save_executor_metadata(_meta("e1"))
-    tenancy_stats(reset=True)
+    tracing.counters("tenancy", reset=True)
     assert s.result_cache_put("f" * 64, _completed_job())
     hit = s.result_cache_lookup("f" * 64)
     assert hit is not None and hit.cached
@@ -312,7 +312,7 @@ def test_result_cache_roundtrip_and_liveness():
     assert s.result_cache_put("a" * 64, _completed_job(executor="gone"))
     assert s.result_cache_lookup("a" * 64) is None
     assert kv.get(s._key("resultcache", "a" * 64)) is None
-    stats = tenancy_stats(reset=True)
+    stats = tracing.counters("tenancy", reset=True)
     assert stats.get("cache_hit") == 1
     assert stats.get("cache_invalidated") == 1
     assert stats.get("cache_put") == 2
@@ -330,10 +330,10 @@ def test_result_cache_put_chaos_torn():
         }),
     )
     s.save_executor_metadata(_meta("e1"))
-    tenancy_stats(reset=True)
+    tracing.counters("tenancy", reset=True)
     assert not s.result_cache_put("b" * 64, _completed_job())
     assert s.result_cache_lookup("b" * 64) is None
-    stats = tenancy_stats(reset=True)
+    stats = tracing.counters("tenancy", reset=True)
     assert stats.get("cache_put_torn") == 1
     assert not stats.get("cache_put")
 
@@ -374,7 +374,7 @@ def test_cache_hit_zero_tasks_and_mtime_invalidation(tpath):
             settings={"ballista.tenant.name": "dash"},
         )
         ctx.register_parquet("t", tpath)
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         q = "select k, sum(v) as s from t group by k order by k"
         cold = ctx.sql(q).collect()
         warm = ctx.sql(q).collect()
@@ -387,14 +387,14 @@ def test_cache_hit_zero_tasks_and_mtime_invalidation(tpath):
         assert len(cached_jobs) == 1
         # the acceptance counter: a cache-hit job runs ZERO executor tasks
         assert st.get_job_tasks(cached_jobs[0]) == []
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("cache_hit") == 1 and stats.get("cache_put") == 1
         # touching an input file's mtime invalidates: fresh execution,
         # fresh entry, same bits
         os.utime(tpath, (time.time() + 5, time.time() + 5))
         fresh = ctx.sql(q).collect()
         assert fresh.equals(cold)
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("cache_hit", 0) == 0 and stats.get("cache_put") == 1
         ctx.close()
     finally:
@@ -414,10 +414,10 @@ def test_cache_and_tenancy_survive_scheduler_restart(tpath):
         q = "select k, count(*) as n from t group by k order by k"
         cold = ctx.sql(q).collect()
         cluster.restart_scheduler()
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         warm = ctx.sql(q).collect()
         assert warm.equals(cold)
-        assert tenancy_stats(reset=True).get("cache_hit") == 1
+        assert tracing.counters("tenancy", reset=True).get("cache_hit") == 1
         st = cluster.scheduler_impl.state
         cached = [
             j for j, js in _jobs_of(st).items()
@@ -461,7 +461,7 @@ def test_lost_cached_partition_invalidates_and_resubmits(tpath, how):
         assert len(owners) < len(cluster.executors), (
             "need a surviving executor to re-execute on"
         )
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         if how == "collect":
             again = ctx.sql(q).collect()
         else:
@@ -469,7 +469,7 @@ def test_lost_cached_partition_invalidates_and_resubmits(tpath, how):
             again = pa.Table.from_batches(
                 list(ctx.collect_stream(plan)), schema=cold.schema)
         assert again.equals(cold)
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("cache_hit") == 1  # served stale, then...
         assert stats.get("cache_invalidated", 0) >= 1  # ...invalidated
         assert stats.get("cache_lost_resubmitted") == 1  # ...and resubmitted
@@ -562,10 +562,10 @@ def test_admit_chaos_bit_identical(tpath):
         try:
             ctx = BallistaContext(*cluster.scheduler_addr)
             ctx.register_parquet("t", tpath)
-            recovery_stats(reset=True)
+            tracing.counters("recovery", reset=True)
             outs[chaos] = ctx.sql(q).collect()
             if chaos:
-                assert recovery_stats(reset=True).get("chaos_injected", 0) > 0
+                assert tracing.counters("recovery", reset=True).get("chaos_injected", 0) > 0
             ctx.close()
         finally:
             cluster.shutdown()
@@ -585,12 +585,12 @@ def test_cache_put_chaos_bit_identical(tpath):
     try:
         ctx = BallistaContext(*cluster.scheduler_addr)
         ctx.register_parquet("t", tpath)
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         q = "select k, sum(v) as s from t group by k order by k"
         a = ctx.sql(q).collect()
         b = ctx.sql(q).collect()
         assert a.equals(b)
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("cache_put_torn", 0) >= 2
         assert stats.get("cache_hit", 0) == 0
         ctx.close()
@@ -682,8 +682,8 @@ def test_scheduler_crash_mid_admission_bit_identical(tmp_path):
             },
         )
         ctx.register_parquet("t", tpath)
-        recovery_stats(reset=True)
-        tenancy_stats(reset=True)
+        tracing.counters("recovery", reset=True)
+        tracing.counters("tenancy", reset=True)
         first = ctx.sql(q).collect()
         second = ctx.sql(q).collect()
         ctx.close()
@@ -692,11 +692,11 @@ def test_scheduler_crash_mid_admission_bit_identical(tmp_path):
         sup.join(timeout=5)
         cluster.shutdown()
     assert first.equals(clean) and second.equals(clean)
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("chaos_scheduler_crash", 0) >= 1, stats
     assert stats.get("scheduler_restart", 0) >= 1, stats
     # the repeat rode the durable cache entry written after the restart
-    assert tenancy_stats(reset=True).get("cache_hit", 0) >= 1
+    assert tracing.counters("tenancy", reset=True).get("cache_hit", 0) >= 1
 
 
 def test_plan_cache_shares_physical_plans(tpath):
@@ -710,12 +710,12 @@ def test_plan_cache_shares_physical_plans(tpath):
             settings={"ballista.cache.results": "false"},
         )
         ctx.register_parquet("t", tpath)
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         q = "select k, max(v) as m from t group by k order by k"
         a = ctx.sql(q).collect()
         b = ctx.sql(q).collect()
         assert a.equals(b)
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("plan_cache_hit") == 1
         assert stats.get("cache_hit", 0) == 0
         ctx.close()
@@ -731,7 +731,7 @@ def test_cross_tenant_cache_sharing(tpath):
     try:
         q = "select k, sum(v) as s from t group by k order by k"
         outs = []
-        tenancy_stats(reset=True)
+        tracing.counters("tenancy", reset=True)
         for tenant in ("alice", "bob", "carol"):
             ctx = BallistaContext(
                 *cluster.scheduler_addr,
@@ -741,7 +741,7 @@ def test_cross_tenant_cache_sharing(tpath):
             outs.append(ctx.sql(q).collect())
             ctx.close()
         assert outs[0].equals(outs[1]) and outs[1].equals(outs[2])
-        stats = tenancy_stats(reset=True)
+        stats = tracing.counters("tenancy", reset=True)
         assert stats.get("cache_hit") == 2  # bob and carol rode alice's run
         assert stats.get("cache_put") == 1
     finally:

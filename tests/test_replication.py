@@ -29,6 +29,7 @@ from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend
 from ballista_tpu.scheduler.server import SchedulerServer
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 
 # -- helpers -----------------------------------------------------------------
 
@@ -117,20 +118,18 @@ def test_renewal_keeps_ownership_against_peers():
 
 
 def test_peer_adopts_after_lease_expiry_with_monotonic_fence():
-    from ballista_tpu.ops.runtime import recovery_stats
-
     kv = MemoryBackend()
     a = _replica_state(kv, "a", "127.0.0.1:7001")
     b = _replica_state(kv, "b", "127.0.0.1:7002")
     _commit_running(a)
     time.sleep(0.1)  # owner stops renewing: replica death
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     assert b.ensure_job_writable("j") is None  # adopt-on-demand
     assert b.owns_job("j")
     lease = b.job_lease("j")
     assert lease.replica_id == "b"
     assert lease.fence == 2  # strictly past every fence the dead owner held
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("lease_adopted", 0) == 1, stats
 
 
@@ -159,29 +158,25 @@ def test_deposed_owner_writes_rejected_whole_without_corruption():
 def test_expired_unclaimed_lease_self_heals():
     """Single-replica servers run no heartbeat thread: their leases expire
     mid-job routinely and the next fenced write re-mints in place."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     kv = MemoryBackend()
     a = _replica_state(kv, "a", "127.0.0.1:7001")
     _commit_running(a)
     time.sleep(0.1)
     assert a.job_lease("j") is None  # lapsed, nobody claimed it
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     running = pb.JobStatus()
     running.running.SetInParent()
     assert a.save_job_metadata("j", running) is True
     lease = a.job_lease("j")
     assert lease.replica_id == "a" and lease.fence == 2
     assert a.owns_job("j")
-    assert recovery_stats(reset=True).get("lease_reminted", 0) == 1
+    assert tracing.counters("recovery", reset=True).get("lease_reminted", 0) == 1
 
 
 def test_adoption_runs_restart_recovery_scoped_to_the_job():
     """Failover IS restart recovery run by a peer: the adopter reloads the
     dead owner's durable assignment ledger with a fresh grace window, and
     the executor's attempt-matching echo re-adopts the task — no retry."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     kv = MemoryBackend()
     a = _replica_state(kv, "a", "127.0.0.1:7001")
     _commit_running(a)
@@ -190,11 +185,11 @@ def test_adoption_runs_restart_recovery_scoped_to_the_job():
     a.save_task_status(_pending("j", 1, 0))
     assert a.assign_next_schedulable_task("e1") is not None
     time.sleep(0.1)  # owner dies
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     b = _replica_state(kv, "b", "127.0.0.1:7002")
     assert b.ensure_job_writable("j") is None  # adopts + scoped recover
     assert ("j", 1, 0) in b._assigned
-    stats = recovery_stats()
+    stats = tracing.counters("recovery")
     assert stats.get("restart_job_resumed", 0) == 1, stats
     assert stats.get("restart_assignment_restored", 0) == 1, stats
     # restart_generation untouched: no process died
@@ -202,7 +197,7 @@ def test_adoption_runs_restart_recovery_scoped_to_the_job():
     # the owner executor vouches: re-adopted, not requeued
     assert b.reconcile_running_tasks("e1", [_echo("j", 1, 0, 0)]) == 0
     assert b.get_task_status("j", 1, 0).WhichOneof("status") == "running"
-    assert recovery_stats(reset=True).get("task_retry", 0) == 0
+    assert tracing.counters("recovery", reset=True).get("task_retry", 0) == 0
 
 
 # -- server-level ownership behavior -----------------------------------------
@@ -212,8 +207,6 @@ def test_pollwork_redirects_foreign_statuses_to_the_owner():
     """Gate-and-partition: a poll carrying statuses for a live peer's job
     folds nothing for it, assigns nothing, and aborts UNAVAILABLE naming
     the owner — the executor's retry loop re-homes and re-delivers."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     kv = MemoryBackend()
     cfg = BallistaConfig({"ballista.scheduler.lease_ttl_s": "5"})
     srv_a = SchedulerServer(
@@ -231,7 +224,7 @@ def test_pollwork_redirects_foreign_statuses_to_the_owner():
     done = _pending("j", 1, 0)
     done.completed.executor_id = "e1"
     done.completed.path = "/x"
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     params = pb.PollWorkParams(
         metadata=_meta("e1"), can_accept_task=True, task_status=[done]
     )
@@ -241,7 +234,7 @@ def test_pollwork_redirects_foreign_statuses_to_the_owner():
     # untouched and no assignment happened on the redirecting replica
     assert sa.get_task_status("j", 1, 0).WhichOneof("status") is None
     assert ("j", 1, 0) not in srv_b.state._assigned
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("ownership_redirected", 0) == 1, stats
     # the owner itself folds the same (idempotent) re-delivery fine
     result = srv_a.PollWork(
@@ -276,8 +269,6 @@ def test_queued_grace_sweep_fails_dead_planners_jobs_only():
     heartbeat lapses AND the 2xTTL grace passes, a peer fails it with a CAS
     against the exact queued bytes (racing a resurrected planner's atomic
     commit, exactly one write lands)."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     kv = MemoryBackend()
     cfg = BallistaConfig({"ballista.scheduler.lease_ttl_s": "0.05"})
     srv_a = SchedulerServer(
@@ -302,13 +293,13 @@ def test_queued_grace_sweep_fails_dead_planners_jobs_only():
         assert srv_b._sweep_queued_grace_locked(seen) == 0  # grace starts
     assert "jq" in seen
     time.sleep(0.12)  # 2xTTL grace elapses
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     with kv.lock():
         assert srv_b._sweep_queued_grace_locked(seen) == 1
     st = srv_b.state.get_job_metadata("jq")
     assert st.WhichOneof("status") == "failed"
     assert "replica 'a'" in st.failed.error
-    assert recovery_stats(reset=True).get("queued_grace_failed", 0) == 1
+    assert tracing.counters("recovery", reset=True).get("queued_grace_failed", 0) == 1
     # terminal: a later sweep has nothing left to do
     with kv.lock():
         assert srv_b._sweep_queued_grace_locked(seen) == 0
@@ -369,11 +360,10 @@ def test_three_replica_owner_kill_failover_bit_identical(sales_table):
     single-scheduler fault-free oracle with zero task retries."""
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     clean = _oracle(sales_table)
     cfg = BallistaConfig({"ballista.scheduler.lease_ttl_s": "0.3"})
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     # no executors yet: the job is guaranteed mid-flight when the owner dies
     cluster = StandaloneCluster(n_executors=0, n_schedulers=3, config=cfg)
     try:
@@ -401,7 +391,7 @@ def test_three_replica_owner_kill_failover_bit_identical(sales_table):
         ctx.close()
     finally:
         cluster.shutdown()
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert box["out"].equals(clean), (
         box["out"].to_pydict(), clean.to_pydict()
     )
@@ -417,11 +407,10 @@ def test_paused_deposed_owner_late_writes_rejected_e2e(sales_table):
     job completes uncorrupted, bit-identical to the oracle."""
     from ballista_tpu.client import BallistaContext
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     clean = _oracle(sales_table)
     cfg = BallistaConfig({"ballista.scheduler.lease_ttl_s": "0.2"})
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=0, n_schedulers=2, config=cfg)
     try:
         ctx = BallistaContext(
@@ -462,7 +451,7 @@ def test_paused_deposed_owner_late_writes_rejected_e2e(sales_table):
         ctx.close()
     finally:
         cluster.shutdown()
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert box["out"].equals(clean), (
         box["out"].to_pydict(), clean.to_pydict()
     )

@@ -21,6 +21,7 @@ from ballista_tpu.config import BallistaConfig
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend, SqliteBackend
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.chaos import ChaosInjected, ChaosInjector
 
 # -- durable assignment ledger ----------------------------------------------
@@ -86,8 +87,6 @@ def test_restarted_scheduler_readopts_echoed_assignment(tmp_path):
     """The re-adoption path: a fresh SchedulerState on the same store
     reloads the ledger; the owner's attempt-matching echo confirms the
     task (restart_readopted), which is NOT re-executed."""
-    from ballista_tpu.ops.runtime import recovery_stats
-
     db = str(tmp_path / "state.db")
     s1 = SchedulerState(SqliteBackend(db), "t")
     _running_job(s1)
@@ -97,7 +96,7 @@ def test_restarted_scheduler_readopts_echoed_assignment(tmp_path):
     assert s1.assign_next_schedulable_task("e1") is not None
     del s1  # crash
 
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     s2 = SchedulerState(SqliteBackend(db), "t")
     stats = s2.recover()
     assert stats.get("scheduler_restart") == 1
@@ -109,7 +108,7 @@ def test_restarted_scheduler_readopts_echoed_assignment(tmp_path):
     assert s2.get_task_status("j", 1, 0).WhichOneof("status") == "running"
     assert ("j", 1, 0) not in s2._assigned
     assert s2.kv.get("/ballista/t/assignments/j/1/0") is None
-    assert recovery_stats().get("restart_readopted", 0) == 1
+    assert tracing.counters("recovery").get("restart_readopted", 0) == 1
 
 
 def test_restarted_scheduler_requeues_unvouched_assignment(tmp_path):
@@ -404,7 +403,6 @@ def test_scheduler_crash_and_restart_is_bit_identical(tmp_path, sales_table):
     results are bit-identical to the fault-free run. No task an executor
     still owned is re-executed (task_retry == orphan_reassigned == 0)."""
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     crash_seed = _find_crash_seed()
 
@@ -421,7 +419,7 @@ def test_scheduler_crash_and_restart_is_bit_identical(tmp_path, sales_table):
         "ballista.rpc.retries": "20",
         "ballista.rpc.backoff_ms": "50",
     })
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(
         n_executors=2,
         kv=SqliteBackend(str(tmp_path / "sched.db")),
@@ -450,7 +448,7 @@ def test_scheduler_crash_and_restart_is_bit_identical(tmp_path, sales_table):
         assert chaotic[name].equals(clean[name]), (
             name, chaotic[name].to_pydict(), clean[name].to_pydict(),
         )
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("chaos_scheduler_crash", 0) >= 1, stats
     assert stats.get("scheduler_restart", 0) >= 1, stats
     assert stats.get("restart_job_resumed", 0) >= 1, stats
@@ -465,7 +463,6 @@ def test_plan_write_chaos_retries_to_bit_identical(sales_table):
     bit-identical to fault-free and the plan_retry counter shows the tears
     actually happened."""
     from ballista_tpu.executor.runtime import StandaloneCluster
-    from ballista_tpu.ops.runtime import recovery_stats
 
     clean_cluster = StandaloneCluster(n_executors=2)
     try:
@@ -505,7 +502,7 @@ def test_plan_write_chaos_retries_to_bit_identical(sales_table):
         "ballista.chaos.seed": str(seed),
         "ballista.chaos.sites": "scheduler.plan_write",
     })
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     cluster = StandaloneCluster(n_executors=2, config=cluster_config)
     try:
         chaotic = _run_queries(cluster, sales_table, CLIENT_SETTINGS)
@@ -513,5 +510,5 @@ def test_plan_write_chaos_retries_to_bit_identical(sales_table):
         cluster.shutdown()
     for name in ("group_by", "join"):
         assert chaotic[name].equals(clean[name]), name
-    stats = recovery_stats(reset=True)
+    stats = tracing.counters("recovery", reset=True)
     assert stats.get("plan_retry", 0) >= 1, stats

@@ -27,11 +27,8 @@ from ballista_tpu.client import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.executor.runtime import BallistaExecutor, StandaloneCluster
 from ballista_tpu.ops import costmodel
-from ballista_tpu.ops.runtime import (
-    recovery_stats,
-    routing_stats,
-    shared_scan_stats,
-)
+from ballista_tpu.ops.runtime import routing_stats
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.chaos import ChaosInjector
 
 QUERIES = [
@@ -162,10 +159,10 @@ def test_batched_bit_identical_to_solo(table_path, monkeypatch):
 
     monkeypatch.setattr(sharedscan, "SYNC_COMPILE", True)
     solo = _run_sequential(table_path, QUERIES)
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     routing_stats(reset=True)
     batched = _run_concurrent(table_path, QUERIES)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     routing = routing_stats(reset=True)
     for q, got, want in zip(QUERIES, batched, solo):
         assert got == want, (q, got, want)
@@ -184,9 +181,9 @@ def test_cold_composition_falls_back_to_member_launches(table_path):
     jitted steps over the SHARED upload (uploads still saved, results
     bit-identical) while the one-launch program warms in the background."""
     solo = _run_sequential(table_path, QUERIES)
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     batched = _run_concurrent(table_path, QUERIES)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(QUERIES, batched, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) >= 1, stats
@@ -201,14 +198,14 @@ def test_cold_composition_falls_back_to_member_launches(table_path):
 
 def test_shared_scan_off_forms_no_batches(table_path):
     cfg = BallistaConfig({"ballista.shared_scan": "false"})
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     out = _run_concurrent(
         table_path, QUERIES,
         client_settings=_client_settings(**{"ballista.shared_scan": "false"}),
         cluster_config=cfg,
     )
     assert all(o is not None for o in out)
-    assert shared_scan_stats(reset=True) == {}
+    assert tracing.counters("shared_scan", reset=True) == {}
 
 
 def test_resident_members_degrade_to_solo(table_path):
@@ -218,9 +215,9 @@ def test_resident_members_degrade_to_solo(table_path):
     and results stay bit-identical."""
     resident = _client_settings(**{"ballista.tpu.device_cache": "true"})
     solo = _run_sequential(table_path, QUERIES, client_settings=resident)
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     out = _run_concurrent(table_path, QUERIES, client_settings=resident)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(QUERIES, out, solo):
         assert got == want, (q, got, want)
     # the scheduler may form batches (it cannot see executor residency);
@@ -247,9 +244,9 @@ def test_evidence_gate_declines_predicted_slow_batches(table_path):
     for k in (2.0, 4.0, 8.0):
         costmodel.seed("stage.batch", k, 1e6, engine="task")
     solo = _run_sequential(table_path, QUERIES)
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     gated = _run_concurrent(table_path, QUERIES)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(QUERIES, gated, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) == 0, stats
@@ -257,9 +254,9 @@ def test_evidence_gate_declines_predicted_slow_batches(table_path):
     # favorable evidence: batching resumes
     for k in (2.0, 4.0, 8.0):
         costmodel.seed("stage.batch", k, 1e-6, engine="task")
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     fast = _run_concurrent(table_path, QUERIES)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(QUERIES, fast, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) >= 1, stats
@@ -274,9 +271,9 @@ def test_mixed_compatibility_batches_only_compatible_members(table_path):
     solo result."""
     queries = QUERIES + [STRING_FILTER_QUERY]
     solo = _run_sequential(table_path, queries)
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     batched = _run_concurrent(table_path, queries)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(queries, batched, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) >= 1, stats
@@ -295,11 +292,11 @@ def test_chaos_torn_batch_formation_degrades_to_solo(table_path):
         "ballista.chaos.seed": "7",
         "ballista.chaos.sites": "scheduler.batch",
     })
-    shared_scan_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
+    tracing.counters("recovery", reset=True)
     out = _run_concurrent(table_path, QUERIES, cluster_config=chaos_cfg)
-    stats = shared_scan_stats(reset=True)
-    rec = recovery_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
+    rec = tracing.counters("recovery", reset=True)
     for q, got, want in zip(QUERIES, out, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) == 0, stats
@@ -337,13 +334,13 @@ def test_member_failure_spares_batch_siblings(table_path):
         "ballista.chaos.seed": str(seed),
         "ballista.chaos.sites": "task.execute",
     }
-    shared_scan_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
+    tracing.counters("recovery", reset=True)
     out = _run_concurrent(
         table_path, QUERIES, per_query_settings=per_query,
     )
-    stats = shared_scan_stats(reset=True)
-    rec = recovery_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
+    rec = tracing.counters("recovery", reset=True)
     for q, got, want in zip(QUERIES, out, solo):
         assert got == want, (q, got, want)
     assert rec.get("task_retry", 0) >= 1, rec
@@ -387,8 +384,8 @@ def test_executor_death_mid_batch_recovers_bit_identical(table_path):
         ex.start()
         cluster.executors.append(ex)
 
-    shared_scan_stats(reset=True)
-    recovery_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
+    tracing.counters("recovery", reset=True)
     try:
         out = _run_concurrent(
             table_path, QUERIES, per_query_settings=per_query,
@@ -396,8 +393,8 @@ def test_executor_death_mid_batch_recovers_bit_identical(table_path):
         )
     finally:
         state_mod.EXECUTOR_LEASE_SECS = old_lease
-    stats = shared_scan_stats(reset=True)
-    rec = recovery_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
+    rec = tracing.counters("recovery", reset=True)
     for q, got, want in zip(QUERIES, out, solo):
         assert got == want, (q, got, want)
     assert stats.get("batches_formed", 0) >= 1, stats
@@ -603,9 +600,9 @@ def test_layout_warm_member_batches_bit_identical(table_path, tmp_path):
         "warm pass persisted no layout entries — the regression test "
         "would not exercise the layout-warm path"
     )
-    shared_scan_stats(reset=True)
+    tracing.counters("shared_scan", reset=True)
     batched = _run_concurrent(table_path, QUERIES, client_settings=warm)
-    stats = shared_scan_stats(reset=True)
+    stats = tracing.counters("shared_scan", reset=True)
     for q, got, want in zip(QUERIES, batched, solo):
         assert got == want, (q, got, want)
     # the whole point: layout-warm members now group and share the scan

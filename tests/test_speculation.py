@@ -25,10 +25,10 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.ops import costmodel
-from ballista_tpu.ops.runtime import recovery_stats, speculation_stats
 from ballista_tpu.proto import ballista_pb2 as pb
 from ballista_tpu.scheduler.kv import MemoryBackend, SqliteBackend
 from ballista_tpu.scheduler.state import SchedulerState
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.chaos import ChaosInjector
 
 # -- helpers ----------------------------------------------------------------
@@ -116,7 +116,7 @@ SPEC_KEY = "/ballista/t/speculation/j/1/0"
 
 
 def test_straggler_launches_duplicate_through_the_ledger():
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     got = s.maybe_speculate("e2")
     assert got is not None
@@ -133,7 +133,7 @@ def test_straggler_launches_duplicate_through_the_ledger():
     cur = s.get_task_status("j", 1, 0)
     assert cur.WhichOneof("status") == "running"
     assert cur.attempt == 0 and cur.running.executor_id == "e1"
-    assert speculation_stats().get("launched") == 1
+    assert tracing.counters("speculation").get("launched") == 1
     # never twice on one task; never back onto the primary's owner
     assert s.maybe_speculate("e2") is None
     assert s.maybe_speculate("e1") is None
@@ -159,12 +159,12 @@ def test_default_floor_spares_fresh_tasks():
     """Fault-free runs with default thresholds launch nothing: a task
     younger than ballista.speculation.min_runtime_ms never speculates,
     whatever the model predicts."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state(
         config=_spec_config(**{"ballista.speculation.min_runtime_ms": "500000"})
     )
     assert s.maybe_speculate("e2") is None
-    assert speculation_stats().get("launched", 0) == 0
+    assert tracing.counters("speculation").get("launched", 0) == 0
 
 
 def test_speculation_disabled_by_config():
@@ -193,20 +193,20 @@ def test_executor_that_failed_an_attempt_is_not_trusted():
 
 
 def test_duplicate_wins_primary_report_dropped():
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     # the duplicate (attempt 1) completes first
     assert s.accept_task_status(_completed("j", 1, 0, 1, "e2", speculative=True))
     assert s.kv.get(SPEC_KEY) is None
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("won") == 1
     assert stats.get("wasted_seconds", 0) > 0
     # the straggling primary finally reports: dropped as stale, and the
     # winner's published location stands
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     assert not s.accept_task_status(_completed("j", 1, 0, 0, "e1"))
-    assert recovery_stats().get("stale_status_dropped") == 1
+    assert tracing.counters("recovery").get("stale_status_dropped") == 1
     cur = s.get_task_status("j", 1, 0)
     assert cur.WhichOneof("status") == "completed"
     assert cur.attempt == 1 and cur.completed.executor_id == "e2"
@@ -216,16 +216,16 @@ def test_primary_wins_duplicate_report_dropped():
     """The numeric attempt guard alone would let the higher-numbered
     duplicate clobber the primary's completion — the completion-stands
     guard must drop it."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     assert s.accept_task_status(_completed("j", 1, 0, 0, "e1"))
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("lost") == 1
     assert s.kv.get(SPEC_KEY) is None
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     assert not s.accept_task_status(_completed("j", 1, 0, 1, "e2", speculative=True))
-    assert recovery_stats().get("stale_status_dropped") == 1
+    assert tracing.counters("recovery").get("stale_status_dropped") == 1
     cur = s.get_task_status("j", 1, 0)
     assert cur.attempt == 0 and cur.completed.executor_id == "e1"
 
@@ -233,14 +233,14 @@ def test_primary_wins_duplicate_report_dropped():
 def test_failed_duplicate_spares_the_primary():
     """A dying duplicate retires the speculation without consuming the
     task's retry budget or touching the primary."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     failed = _pending("j", 1, 0, attempt=1)
     failed.speculative = True
     failed.failed.error = "dup died"
     assert not s.accept_task_status(failed)
-    assert speculation_stats().get("failed") == 1
+    assert tracing.counters("speculation").get("failed") == 1
     assert s.kv.get(SPEC_KEY) is None
     cur = s.get_task_status("j", 1, 0)
     assert cur.WhichOneof("status") == "running" and cur.attempt == 0
@@ -253,13 +253,13 @@ def test_duplicate_fetch_failure_still_recomputes_the_lost_map():
     primary still runs, no retry budget consumed) — but the lineage it
     carries must NOT be: the named lost map output is recomputed now, not
     after the next consumer trips on it a failure round-trip later."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     # a completed upstream map output the duplicate will report lost
     map_done = _completed("j", 0, 0, 0, "em")
     s.save_task_status(map_done)
     assert s.maybe_speculate("e2") is not None
-    recovery_stats(reset=True)
+    tracing.counters("recovery", reset=True)
     ff = _pending("j", 1, 0, attempt=1)
     ff.speculative = True
     ff.fetch_failed.executor_id = "e2"
@@ -269,10 +269,10 @@ def test_duplicate_fetch_failure_still_recomputes_the_lost_map():
     ff.fetch_failed.map_executor_id = "em"
     ff.fetch_failed.path = "/w/em"
     assert not s.accept_task_status(ff)
-    assert speculation_stats().get("failed") == 1
+    assert tracing.counters("speculation").get("failed") == 1
     assert s.kv.get(SPEC_KEY) is None
     # the lost map output was requeued for recompute with the lineage
-    assert recovery_stats().get("map_recomputed") == 1
+    assert tracing.counters("recovery").get("map_recomputed") == 1
     mt = s.get_task_status("j", 0, 0)
     assert mt.WhichOneof("status") is None and mt.attempt == 1
     assert mt.history[0].executor_id == "em"
@@ -301,13 +301,13 @@ def test_primary_failure_promotes_the_duplicate():
     """The primary dies while its duplicate is in flight: the duplicate IS
     the retry — promoted to the current attempt on its executor, entering
     the normal assignment ledger, consuming no retry budget."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     spec_t0 = s._speculative[("j", 1, 0)][2]
     t = s.get_task_status("j", 1, 0)
     assert s.requeue_task(t, "e1", "primary lost", limit=1)
-    assert speculation_stats().get("promoted") == 1
+    assert tracing.counters("speculation").get("promoted") == 1
     # the watch clock keeps the duplicate's LAUNCH time: its completion
     # must observe the true duration, not seconds-since-promotion
     assert s._running_since[("j", 1, 0)] == ("e2", 1, spec_t0)
@@ -332,7 +332,7 @@ def test_lineage_invalidation_retires_instead_of_promoting():
     locations dying (lineage invalidation / fetch_failed) must NOT promote
     the duplicate — it was bound to the same dead locations; plain requeue
     rebinds fresh ones at the next assignment."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     t = s.get_task_status("j", 1, 0)
@@ -340,7 +340,7 @@ def test_lineage_invalidation_retires_instead_of_promoting():
         t, "e1", "upstream shuffle locations lost mid-run", limit=3,
         promote=False,
     )
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("promoted", 0) == 0
     assert stats.get("failed") == 1  # the duplicate retired with the reset
     assert s.kv.get(SPEC_KEY) is None
@@ -424,13 +424,13 @@ def test_promotion_respects_the_retry_budget():
     """Review regression: a primary already AT its final allowed attempt
     must fail the job when it dies — the in-flight duplicate is retired,
     never promoted to attempt numbers past the configured limit."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     t = s.get_task_status("j", 1, 0)
     # limit 0: attempt 0 IS the final budgeted attempt
     assert not s.requeue_task(t, "e1", "primary lost", limit=0)
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("promoted", 0) == 0
     assert stats.get("failed") == 1
     assert s.kv.get(SPEC_KEY) is None  # duplicate record retired with the job
@@ -450,19 +450,19 @@ def test_restart_recovers_both_attempts_from_the_ledger(tmp_path):
     assert s1.maybe_speculate("e2") is not None
     del s1  # crash with both attempts in flight
 
-    recovery_stats(reset=True)
-    speculation_stats(reset=True)
+    tracing.counters("recovery", reset=True)
+    tracing.counters("speculation", reset=True)
     s2 = SchedulerState(SqliteBackend(db), "t", config=_spec_config())
     stats = s2.recover()
     assert stats.get("restart_assignment_restored") == 1
     assert stats.get("restart_speculation_restored") == 1
-    assert speculation_stats().get("restored") == 1
+    assert tracing.counters("speculation").get("restored") == 1
     assert ("j", 1, 0) in s2._assigned
     assert s2.speculation_active(("j", 1, 0), "e2", 1)
     # both owners vouch: nothing requeues, the duplicate is re-adopted
     assert s2.reconcile_running_tasks("e1", [_echo("j", 1, 0, 0)]) == 0
     assert s2.reconcile_running_tasks("e2", [_echo("j", 1, 0, 1)]) == 0
-    assert recovery_stats().get("restart_speculation_readopted") == 1
+    assert tracing.counters("recovery").get("restart_speculation_readopted") == 1
     # the race resolves normally after the restart: duplicate wins here
     assert s2.accept_task_status(_completed("j", 1, 0, 1, "e2", speculative=True))
     assert not s2.accept_task_status(_completed("j", 1, 0, 0, "e1"))
@@ -497,14 +497,14 @@ def test_lost_in_transit_duplicate_is_dropped_after_grace():
     only visible to the speculation ledger: unvouched past the grace
     window, the record is dropped — the primary still runs, nothing
     requeues."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     ex, at, t0, vouched, restored = s._speculative[("j", 1, 0)]
     s._speculative[("j", 1, 0)] = (ex, at, t0 - 60.0, vouched, restored)
     # e2 polls with an empty echo: it never received the duplicate
     s.reconcile_running_tasks("e2", [])
-    assert speculation_stats().get("orphaned") == 1
+    assert tracing.counters("speculation").get("orphaned") == 1
     assert s.kv.get(SPEC_KEY) is None
     cur = s.get_task_status("j", 1, 0)
     assert cur.WhichOneof("status") == "running" and cur.attempt == 0
@@ -514,7 +514,7 @@ def test_dead_duplicate_executor_retires_the_speculation():
     """The duplicate's executor lease lapses: the sweep in the straggler
     monitor drops the record (the primary still runs) and the task may
     speculate again onto a live executor."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     assert s.maybe_speculate("e2") is not None
     s.kv.delete("/ballista/t/executors/e2")  # lease gone
@@ -522,7 +522,7 @@ def test_dead_duplicate_executor_retires_the_speculation():
     owner, attempt, t0 = s._running_since[("j", 1, 0)]
     s._running_since[("j", 1, 0)] = (owner, attempt, t0 - 5.0)
     got = s.maybe_speculate("e3")
-    assert speculation_stats().get("executor_lost") == 1
+    assert tracing.counters("speculation").get("executor_lost") == 1
     assert got is not None and got[0].attempt == 1
     raw = s.kv.get(SPEC_KEY)
     a = pb.Assignment()
@@ -561,8 +561,6 @@ def test_overdue_tenant_jumps_the_fair_share_order():
     """Deadline-aware admission: pure fair share would hand the idle
     tenant's task out next, but the busy tenant's oldest pending job has
     blown its SLO deadline — it is visited first."""
-    from ballista_tpu.ops.runtime import tenancy_stats
-
     costmodel.reset()
     s = SchedulerState(
         MemoryBackend(), "t",
@@ -578,7 +576,7 @@ def test_overdue_tenant_jumps_the_fair_share_order():
     s.save_job_tenant("bj", "bob", 0)
     s.save_stage_plan("bj", stage_b.stage_id, stage_b)
     s.save_task_status(_pending("bj", stage_b.stage_id, 0))
-    tenancy_stats(reset=True)
+    tracing.counters("tenancy", reset=True)
     # alice takes the first slot (tie or boost), then the fair-share ratio
     # (1 in flight vs bob's 0) would prefer bob — the blown deadline keeps
     # alice ahead until her pending work drains
@@ -591,7 +589,7 @@ def test_overdue_tenant_jumps_the_fair_share_order():
     assert got == ["alice", "alice", "alice"], got
     # one sustained overdue condition is ONE boost episode, however many
     # admission scans it spans
-    assert tenancy_stats().get("admit_slo_boosted", 0) == 1
+    assert tracing.counters("tenancy").get("admit_slo_boosted", 0) == 1
     # with no SLO configured the same shape hands bob the second slot
     costmodel.reset()
     s2 = SchedulerState(MemoryBackend(), "t", config=_spec_config())
@@ -613,7 +611,7 @@ def test_overdue_tenant_jumps_the_fair_share_order():
 
 
 def test_slo_outcome_counters():
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     costmodel.reset()
     s = SchedulerState(
         MemoryBackend(), "t",
@@ -629,7 +627,7 @@ def test_slo_outcome_counters():
     # one job is ONE outcome: a re-fold (restart_completed_job after a
     # lost result partition) must not double-count
     s._note_job_slo("late")
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("slo_misses") == 1
     assert stats.get("slo_met") == 1
 
@@ -745,7 +743,7 @@ def test_speculation_rescues_seeded_straggler_end_to_end():
                 seed = cand
                 break
         assert seed is not None, "no qualifying chaos seed in range"
-        speculation_stats(reset=True)
+        tracing.counters("speculation", reset=True)
         ctx2 = BallistaContext(
             *cluster.scheduler_addr,
             settings={
@@ -764,7 +762,7 @@ def test_speculation_rescues_seeded_straggler_end_to_end():
         assert chaotic.equals(clean), (
             chaotic.to_pydict(), clean.to_pydict(),
         )
-        stats = speculation_stats(reset=True)
+        stats = tracing.counters("speculation", reset=True)
         assert stats.get("launched", 0) >= 1, stats
         assert stats.get("won", 0) >= 1, stats
         # the rescue is the point: the job must finish well inside the
@@ -858,7 +856,7 @@ def test_respeculation_supersedes_straggling_duplicate():
     is superseded by a fresh duplicate on a third executor: the ledger now
     tracks attempt 2, the abandoned attempt 1 lands in the superseded set,
     and the launch count enforces ballista.speculation.max_attempts."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     s.save_executor_metadata(_meta("e3"))
     s.save_executor_metadata(_meta("e4"))
@@ -876,7 +874,7 @@ def test_respeculation_supersedes_straggling_duplicate():
     assert a.executor_id == "e3" and a.attempt == 2
     assert s._spec_superseded[("j", 1, 0)] == {1}
     assert s._spec_launches[("j", 1, 0)] == 2
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("launched") == 2 and stats.get("relaunched") == 1
     # bounded: max_attempts=2 (default) — a third launch never happens,
     # however long the second duplicate straggles
@@ -916,7 +914,7 @@ def test_superseded_failure_spares_task_and_live_duplicate():
     """An abandoned duplicate's failure touches nothing: no retry budget
     consumed, the primary stays running, and the LIVE successor duplicate
     stays ledgered."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     s.save_executor_metadata(_meta("e3"))
     assert s.maybe_speculate("e2") is not None
@@ -932,7 +930,7 @@ def test_superseded_failure_spares_task_and_live_duplicate():
     a = pb.Assignment()
     a.ParseFromString(s.kv.get(SPEC_KEY))
     assert a.executor_id == "e3" and a.attempt == 2
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("superseded_failed") == 1
     assert ("j", 1, 0) not in s._spec_superseded  # retired on sight
 
@@ -941,7 +939,7 @@ def test_superseded_completion_still_wins():
     """First completion wins, whoever crosses the line: the ABANDONED
     duplicate finishing first resolves the task, and the whole episode
     (ledger + superseded set) closes."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     s.save_executor_metadata(_meta("e3"))
     assert s.maybe_speculate("e2") is not None
@@ -955,7 +953,7 @@ def test_superseded_completion_still_wins():
     assert s.kv.get(SPEC_KEY) is None
     assert ("j", 1, 0) not in s._spec_superseded
     assert ("j", 1, 0) not in s._spec_launches
-    stats = speculation_stats()
+    stats = tracing.counters("speculation")
     assert stats.get("superseded_won") == 1
     # review regression: the abandoned duplicate's rescue is a speculative
     # WIN in the effectiveness counters, never a "primary won" loss
@@ -982,7 +980,7 @@ def test_primary_failure_promotes_the_respeculated_duplicate():
     """Primary dies while the RE-speculated duplicate runs: the promotion
     path adopts it (attempt 2, on its executor) exactly like a first-round
     duplicate — no retry budget consumed."""
-    speculation_stats(reset=True)
+    tracing.counters("speculation", reset=True)
     s = _straggling_state()
     s.save_executor_metadata(_meta("e3"))
     assert s.maybe_speculate("e2") is not None
@@ -993,7 +991,7 @@ def test_primary_failure_promotes_the_respeculated_duplicate():
     cur = s.get_task_status("j", 1, 0)
     assert cur.WhichOneof("status") == "running"
     assert cur.attempt == 2 and cur.running.executor_id == "e3"
-    assert speculation_stats().get("promoted") == 1
+    assert tracing.counters("speculation").get("promoted") == 1
     # promoted into the ASSIGNMENT ledger; speculation record retired
     assert s.kv.get(SPEC_KEY) is None
     assert s.kv.get("/ballista/t/assignments/j/1/0") is not None
@@ -1104,7 +1102,7 @@ def test_respeculation_rescues_double_straggler_end_to_end():
                 seed = cand
                 break
         assert seed is not None, "no qualifying chaos seed in range"
-        speculation_stats(reset=True)
+        tracing.counters("speculation", reset=True)
         ctx2 = BallistaContext(
             *cluster.scheduler_addr,
             settings={
@@ -1123,7 +1121,7 @@ def test_respeculation_rescues_double_straggler_end_to_end():
         assert chaotic.equals(clean), (
             chaotic.to_pydict(), clean.to_pydict(),
         )
-        stats = speculation_stats(reset=True)
+        stats = tracing.counters("speculation", reset=True)
         assert stats.get("launched", 0) >= 2, stats
         assert stats.get("relaunched", 0) >= 1, stats
         assert stats.get("won", 0) >= 1, stats

@@ -303,8 +303,8 @@ def test_mesh_fewer_partitions_than_devices():
 def test_mesh_readback_recorded():
     """Multi-chip readback accounting (ISSUE 3): the mesh aggregate's d2h
     result transfer must flow through record_readback on BOTH programs —
-    unrolled (G <= 1024) and sorted (G > 1024) — so bench.py's per-config
-    readback fields stop undercounting pod runs."""
+    unrolled (G <= 1024) and sorted (G > 1024) — so readback_stats
+    does not undercount pod runs."""
     from ballista_tpu.ops.runtime import readback_stats
 
     # unrolled mesh program

@@ -3,6 +3,7 @@ self-run over ballista_tpu/ gates the tree, each rule is exercised against
 known-bad and known-good fixture snippets, and the suppression syntax
 (mandatory reasons) plus per-file cache behavior are pinned."""
 
+import ast
 import json
 import os
 import pathlib
@@ -56,6 +57,41 @@ def test_all_rules_registered():
     assert "lock-order" in names  # ISSUE 14
     assert "durability" in names  # ISSUE 18
     assert "lint-usage" in names
+
+
+# -- who may know the device layer --------------------------------------------
+
+def _imports(path: pathlib.Path) -> set:
+    """Every module a file imports, at any depth, relative ones resolved."""
+    pkg = list(path.relative_to(REPO).parts[:-1])
+    out = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            out.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = pkg[:len(pkg) - node.level + 1] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            out.add(mod)
+            out.update(f"{mod}.{a.name}" for a in node.names)
+    return out
+
+
+def test_client_utils_and_the_rpc_front_do_not_import_the_device_layer():
+    pkg = REPO / "ballista_tpu"
+    files = sorted([*(pkg / "client").glob("*.py"), *(pkg / "utils").glob("*.py"),
+                    pkg / "scheduler" / "server.py", pkg / "scheduler" / "rpc.py"])
+    assert len(files) > 8
+    reach = {str(f.relative_to(REPO)): sorted(m for m in _imports(f)
+                                              if (m + ".").startswith("ballista_tpu.ops."))
+             for f in files}
+    assert not any(reach.values()), {f: m for f, m in reach.items() if m}
+
+
+def test_the_runtime_keeps_only_the_four_device_families():
+    tree = ast.parse((REPO / "ballista_tpu" / "ops" / "runtime.py").read_text())
+    stats = sorted(n.name for n in tree.body
+                   if isinstance(n, ast.FunctionDef) and n.name.endswith("_stats"))
+    assert stats == ["ingest_stats", "join_path_stats", "readback_stats", "routing_stats"]
 
 
 # -- lock-order (ISSUE 14) ---------------------------------------------------

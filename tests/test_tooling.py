@@ -165,64 +165,3 @@ def test_tpch_cli_benchmark(tmp_path):
     assert out.returncode == 0, out.stderr[-500:]
     result = json.loads(out.stdout)
     assert "q6" in result and result["q6"]["rows"] == 1
-
-
-def test_bench_refuses_without_tpu(monkeypatch):
-    """No TPU and nobody asked for the CPU: bench.py fails before its first
-    number. There is no probe child, no replay of a saved capture and no
-    auto-persist to replay from — a run without a chip is not a record."""
-    import importlib
-
-    from ballista_tpu.ops import device
-
-    bench = importlib.import_module("bench")
-    for gone in ("_probe_device", "_probe_device_once", "_emit_stale_capture",
-                 "_latest_session_capture", "_persist_capture", "RESULTS_DIR"):
-        assert not hasattr(bench, gone), gone
-    monkeypatch.setattr(device, "_cpu_requested", lambda jax: False)
-    device.reset()
-    try:
-        with pytest.raises(device.DeviceError, match="platform 'cpu'"):
-            bench._establish_device()
-    finally:
-        device.reset()
-
-
-def test_bench_names_the_device():
-    """Every result names the device it ran on, as JAX reports it; under
-    JAX_PLATFORMS=cpu (this suite) that is platform "cpu", said out loud."""
-    import importlib
-
-    import jax
-
-    bench = importlib.import_module("bench")
-    d = jax.devices()[0]
-    assert bench._establish_device() == {
-        "platform": d.platform, "kind": d.device_kind,
-        "count": len(jax.devices()),
-    }
-    assert d.platform == "cpu"
-
-
-def test_bench_failure_exits_nonzero(monkeypatch, capsys):
-    """A config that raises is named in bench._FAILED, and a scenario that
-    reports failure makes the run exit non-zero instead of printing a JSON
-    line with a hole in it."""
-    import importlib
-
-    bench = importlib.import_module("bench")
-    monkeypatch.setattr(bench, "_FAILED", [])
-
-    def boom(sf):
-        raise RuntimeError("no data")
-
-    monkeypatch.setattr(bench, "ensure_data", boom)
-    assert bench.bench_config(0.001, "q6") is None
-    assert bench._FAILED == ["q6@sf0.001"]
-
-    monkeypatch.setenv("BENCH_ROUTING_ONLY", "1")
-    monkeypatch.setattr(bench, "_routing_scenario", lambda: None)
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert ei.value.code == 1
-    assert json.loads(capsys.readouterr().out) == {"routing": None}

@@ -202,6 +202,48 @@ def test_reset_keeps_what_it_clears_until_the_next_reset():
     assert tracing.drained()["counters"] == {}
 
 
+def test_a_family_is_read_and_reset_alone():
+    tracing.incr("recovery.task_retry")
+    tracing.incr("recovery.rpc_retry", 3)
+    tracing.incr("recovery_x.other")  # a longer name is another family
+    tracing.incr("device.host_fallback", 2)
+    tracing.incr("serde.plan_decode")
+    assert tracing.counters("recovery") == {"task_retry": 1, "rpc_retry": 3}
+    assert tracing.counters("tenancy") == {}
+    assert tracing.counters("recovery", reset=True) == {"task_retry": 1, "rpc_retry": 3}
+    assert tracing.counters("recovery") == {}
+    assert tracing.counters() == {
+        "recovery_x.other": 1, "device.host_fallback": 2, "serde.plan_decode": 1}
+
+
+def test_incr_from_many_threads_loses_no_count_and_sums_floats():
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(10_000):
+                tracing.incr("speculation.launched")
+                tracing.incr("speculation.wasted_seconds", 0.25)
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert tracing.counters("speculation") == {"launched": 80_000, "wasted_seconds": 20_000.0}
+
+
+def test_reset_drains_a_family_like_any_other_counter():
+    tracing.incr("serving.dispatch_push", 17)
+    tracing.incr("device.map_rows", 5)
+    tracing.reset()
+    assert tracing.counters("serving") == {} and tracing.counters() == {}
+    assert tracing.drained()["counters"] == {"serving.dispatch_push": 17, "device.map_rows": 5}
+
+
 def test_timeline_orders_by_start_and_indents_by_parent():
     with tracing.span("client.collect", job="j9"):
         with tracing.span("client.submit"):
