@@ -524,6 +524,9 @@ class FactAggregateStage:
         keys, attrs = self._sec_map
         f2 = npcols[self.secondary["f2_scan_idx"]]
         tracing.incr("device.map_rows", len(f2))
+        # answered by a search of the sorted keys, as below: the side of
+        # `device.map_rows` that ops/mappedscan.py's position tables leave
+        tracing.incr("device.map_sorted_rows", len(f2))
         with self._dim_build(attachments=1, rows=len(keys), fact_rows=len(f2)):
             f2 = f2.astype(np.int64)
             if len(keys) == 0:

@@ -18,9 +18,13 @@ Rewrite (device path only; the host path keeps the original plan):
 Each INNER equi-join against a unique-keyed dim subtree becomes an
 *attachment*: at stage-prepare time the dim subtree executes on the host
 (it may carry its own filters/joins — q7's orders x customer x nation leg),
-and its columns are gathered per fact row through the key (sorted dim keys
-+ searchsorted, the same regular shape the device join kernel uses). The
-fact batch comes out extended with the mapped dim columns plus an
+and its columns are gathered per fact row through the key. The dim's rows
+are kept in key order, so a key's position among the sorted keys IS its
+row; a fact key finds that position by one read of a position table over
+the packed key range where the range fits MAX_POSITION_ENTRIES, else by a
+binary search of the sorted keys with the batch's keys probed in sorted
+order (_find_rows). The fact batch comes out extended with the mapped dim
+columns plus an
 ``__member`` int8 column (0 where the inner join would drop the row — a
 membership filter the stage fuses onto the device). Attachments chain:
 a later attachment's fact-side key may itself be a mapped column
@@ -35,6 +39,7 @@ group keys / aggregate inputs / filters may reference them freely
 
 from __future__ import annotations
 
+import math
 import threading
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -59,16 +64,30 @@ from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 # dim subtrees larger than this are not dimension maps; host joins them.
-# The ceiling admits SF=100's orders (150M rows). A map holds 16 bytes a row
-# (sorted int64 keys + int64 order) beside the dimension's own columns.
-# Measured at SF=10 on the v5e's host (PR 28, q9's first execution): its
-# five maps, 23.2M rows with orders' 15M (240 MB) and partsupp's 8M under a
-# packed two-column key (128 MB), are collected and sorted in 3.3 s; the
-# gather of 60M fact rows through them takes 80 s, 0.27 us a row and map.
-# What scales is the gather, not the map. The DEVICE cost is membership
-# bits + narrow mapped columns over the filtered fact, which the HBM budget
-# guards independently
+# The ceiling admits SF=100's orders (150M rows). A map holds the
+# dimension's own columns in key order and either a position table (below)
+# or its sorted int64 keys, 8 bytes a row.
+# Measured at SF=10 on the v5e's host (PR 32, q9's first execution, parent
+# and change in one call): its five maps, 23.2M rows with orders' 15M and
+# partsupp's 8M under a packed two-column key, are collected, sorted and
+# put in key order in 4.1 s (3.4 s before the reorder and the position
+# tables); the gather of 60M fact rows through them takes 24.8 s, 0.083 us
+# a row and map (84.0 s and 0.280 us by one unsorted binary search a row,
+# PR 32's parent). q8's seven maps: 11.8 s, 0.028 us (32.5 s, 0.077); q7's
+# five over 19M filtered fact rows: 4.5 s (11.7 s). What scales is still
+# the gather, not the map. The DEVICE cost is membership bits + narrow
+# mapped columns over the filtered fact, which the HBM budget guards
+# independently
 MAX_MAP_ROWS = 200_000_000
+# A map whose packed key range (the product of its key columns' ranges) has
+# at most this many values answers a fact key by position: an int32 table
+# over the whole range, 4 bytes a value, holds each key's dimension row or
+# -1. 2**28 entries are 1 GiB of host memory a map at the most. SF=10's
+# orders (15M keys in a range of 60M) takes 240 MB, what its sorted keys
+# and their order took before; SF=100's (600M, 2.4 GB) and partsupp's
+# two-column key (2 * 10**11 at SF=10) do not fit and are searched, a
+# batch's keys in sorted order (0.2 us a row in a CPU replica, PR 32).
+MAX_POSITION_ENTRIES = 1 << 28
 _PASSTHROUGH = (FilterExec, ProjectionExec, CoalesceBatchesExec, MergeExec)
 
 
@@ -228,26 +247,37 @@ class MappedScanExec(ExecutionPlan):
                     for k in a.dim_keys
                 ]
                 packed, mins, ranges, strides = _pack_dim_keys(key_vals)
-                if a.kind == "inner":
+                if a.kind == "inner" and np.all(packed[1:] > packed[:-1]):
+                    # collected in key order (a base table read in file
+                    # order): unique, and nothing to sort or move
+                    sorted_keys = packed
+                elif a.kind == "inner":
                     order = np.argsort(packed, kind="stable")
                     sorted_keys = packed[order]
-                    if len(sorted_keys) and np.any(
-                        sorted_keys[1:] == sorted_keys[:-1]
-                    ):
+                    if np.any(sorted_keys[1:] == sorted_keys[:-1]):
                         raise UnsupportedOnDevice(
                             f"dim keys {a.dim_keys} not unique (join multiplies)"
                         )
+                    # key order: the position of a key IS its dimension row
+                    table = table.take(pa.array(order)).combine_chunks()
                 else:
                     # membership only: distinct keys suffice, nothing to
                     # gather — no uniqueness requirement, no retained table
                     sorted_keys = np.unique(packed)
-                    order = None
                     table = None
+                # one of the two answers a key (_find_rows): a position
+                # table where the packed range fits one, else the sorted keys
+                pos = None
+                key_space = math.prod(ranges)
+                if key_space <= MAX_POSITION_ENTRIES:
+                    pos = np.full(key_space, -1, dtype=np.int32)
+                    pos[sorted_keys] = np.arange(len(sorted_keys), dtype=np.int32)
                 maps.append(
                     {
                         "table": table,
-                        "sorted": sorted_keys,
-                        "order": order,
+                        "rows": len(sorted_keys),
+                        "sorted": sorted_keys if pos is None else None,
+                        "pos": pos,
                         "mins": mins,
                         "ranges": ranges,
                         "strides": strides,
@@ -263,14 +293,21 @@ class MappedScanExec(ExecutionPlan):
         n_att = len(self.attachments)
         with tracing.span("runtime.dim_build", engine="mapped", attachments=n_att) as sp:
             maps = self._ensure_maps(ctx)
-            dim_rows = sum(len(m["sorted"]) for m in maps)
-            sp.set(rows=dim_rows)
+            dim_rows = sum(m["rows"] for m in maps)
+            n_dense = sum(m["pos"] is not None for m in maps)
+            sp.set(rows=dim_rows, dense=n_dense)
         for batch in self.fact.execute(partition, ctx):
             if batch.num_rows:
                 with tracing.span("runtime.dim_build", engine="mapped", attachments=n_att,
-                                  rows=dim_rows, fact_rows=batch.num_rows):
+                                  rows=dim_rows, fact_rows=batch.num_rows, dense=n_dense):
                     out = self._extend(batch, maps)
-                tracing.incr("device.map_rows", batch.num_rows * n_att)
+                # map_rows, and which way each attachment was answered: the
+                # two sum to it; a way no map of this scan goes is not named
+                for name, atts in (("device.map_rows", n_att),
+                                   ("device.map_dense_rows", n_dense),
+                                   ("device.map_sorted_rows", n_att - n_dense)):
+                    if atts:
+                        tracing.incr(name, batch.num_rows * atts)
                 yield out
 
     def _extend(self, batch: pa.RecordBatch, maps: List[dict]) -> pa.RecordBatch:
@@ -302,13 +339,8 @@ class MappedScanExec(ExecutionPlan):
                 in_range = (rel >= 0) & (rel < rng)
                 valid &= in_range
                 packed = packed + np.where(in_range, rel, 0) * stride
-            if len(m["sorted"]) == 0:
-                hit = np.zeros(n, dtype=bool)
-                idx_c = np.zeros(n, dtype=np.int64)
-            else:
-                idx = np.searchsorted(m["sorted"], packed)
-                idx_c = np.minimum(idx, len(m["sorted"]) - 1)
-                hit = valid & (m["sorted"][idx_c] == packed)
+            found, row = _find_rows(m, packed)
+            hit = valid & found
             if a.kind == "anti":
                 # NOT EXISTS: keep rows with no match (null keys never
                 # match, so they are kept — SQL NOT EXISTS semantics)
@@ -317,16 +349,39 @@ class MappedScanExec(ExecutionPlan):
             member &= hit
             if a.kind == "semi":
                 continue
-            # non-member rows gather row 0 (garbage, masked by __member;
-            # group codes need non-null values so no null fill here)
-            take = m["order"][np.where(hit, idx_c, 0)]
-            gathered = m["table"].take(pa.array(take))
+            # non-member rows gather row 0, the smallest key's (garbage,
+            # masked by __member; group codes need non-null values so no
+            # null fill here)
+            gathered = m["table"].take(pa.array(np.where(hit, row, 0)))
             for f, col in zip(gathered.schema, gathered.columns):
                 arr = col.combine_chunks()
                 arrays.append(arr)
                 by_name[f.name] = arr
         arrays.append(pa.array(member.astype(np.int8)))
         return pa.record_batch(arrays, schema=self._schema)
+
+
+def _find_rows(m: dict, packed: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(found, row) of a batch's packed keys in one map: `row` is the key's
+    position among the map's sorted keys, which is its row of the key-ordered
+    table, and means nothing where `found` is false."""
+    if m["pos"] is not None:
+        row = m["pos"][packed]
+        return row >= 0, row
+    # never empty: an empty dimension's range is 1 and takes a table.
+    # Probe in key order: the binary searches then walk the map forwards
+    # and stay in cache; scatter the answers back to the batch's order
+    keys = m["sorted"]
+    n = len(packed)
+    perm = np.argsort(packed)
+    needles = packed[perm]
+    at = np.searchsorted(keys, needles)
+    np.minimum(at, len(keys) - 1, out=at)
+    found = np.empty(n, dtype=bool)
+    found[perm] = keys[at] == needles
+    row = np.empty(n, dtype=np.int64)
+    row[perm] = at
+    return found, row
 
 
 def _pack_dim_keys(key_vals: List[np.ndarray]):

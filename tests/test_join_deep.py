@@ -157,6 +157,22 @@ def test_map_rows_counts_the_fact_rows_extended_and_a_warm_query_none(served, da
     assert "device.rank_map_build" not in warm["counters"]
 
 
+@pytest.mark.parametrize("name", TEXTS)
+def test_every_mapped_row_is_answered_one_of_the_two_ways(name, served):
+    """PR 32: by position table or by a search of sorted keys; q5's supplier
+    map (ops/factagg.py) still searches, and a warm query counts neither."""
+    cold, warm = served[name]["cold"]["counters"], served[name]["warm"]["counters"]
+    assert (cold.get("device.map_dense_rows", 0) + cold.get("device.map_sorted_rows", 0)
+            == cold["device.map_rows"])
+    assert ("device.map_dense_rows" in cold) == (name != "q5")
+    assert "device.map_dense_rows" not in warm and "device.map_sorted_rows" not in warm
+    gathers = [s for s in served[name]["cold"]["spans"]
+               if s.name == "runtime.dim_build" and s.attrs.get("engine") == "mapped"
+               and "fact_rows" in s.attrs]
+    assert cold.get("device.map_dense_rows", 0) == sum(
+        s.attrs["fact_rows"] * s.attrs["dense"] for s in gathers)
+
+
 def _window(builds, launches):
     """One query's log, drained: a root, `launches` programs and a
     `runtime.dim_build` per entry of `builds` (seconds)."""

@@ -11,9 +11,10 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.engine import ExecutionContext
-from ballista_tpu.ops import kernels
-from ballista_tpu.ops.mappedscan import MappedScanExec
+from ballista_tpu.ops import kernels, mappedscan
+from ballista_tpu.ops.mappedscan import Attachment, MappedScanExec
 from ballista_tpu.ops.stage import FusedAggregateStage
+from ballista_tpu.physical.plan import ExecutionPlan, Partitioning, TaskContext
 
 
 @pytest.fixture(autouse=True)
@@ -430,3 +431,201 @@ def test_composite_semi_keys_with_nulls(tmp_path):
     t, c = _run_both(paths, sql)
     assert c.column("s").to_pylist() == [1.0]
     assert t.column("s").to_pylist() == [1.0]
+
+
+# ---------------------------------------------------------------------------
+# how a map finds a key's dimension row (PR 32): by position table where the
+# packed key range fits MAX_POSITION_ENTRIES, else a search of the sorted
+# keys with the batch's needles in key order. Both against a plain reference
+# that shares no code with _extend: a dict from key tuple to dimension row.
+# ---------------------------------------------------------------------------
+
+
+class _Rows(ExecutionPlan):
+    """A table as a one-partition plan, in small batches."""
+
+    def __init__(self, table, batch_rows=64):
+        self.table, self.batch_rows = table, batch_rows
+
+    def schema(self):
+        return self.table.schema
+
+    def output_partitioning(self):
+        return Partitioning.unknown(1)
+
+    def children(self):
+        return []
+
+    def with_children(self, children):
+        return self
+
+    def fmt(self):
+        return "_Rows"
+
+    def execute(self, partition, ctx):
+        yield from self.table.to_batches(max_chunksize=self.batch_rows)
+
+
+def _reference_extend(fact, atts):
+    """(member, {mapped column: values}) by dict lookups, row by row. A row
+    an inner attachment drops carries the values of the smallest key's row
+    (what the gather leaves under a cleared `__member`); a later attachment
+    keyed on a mapped column looks that value up, as the scan does."""
+    lookups = []
+    for dim, fact_keys, dim_keys, kind in atts:
+        rows = dim.to_pylist()
+        keyed = {tuple(r[k] for k in dim_keys): r for r in rows
+                 if None not in [r[k] for k in dim_keys]}
+        lookups.append((fact_keys, kind, keyed, keyed[min(keyed)] if keyed else None))
+    member, mapped = [], {}
+    for r in fact.to_pylist():
+        keep = True
+        for fact_keys, kind, keyed, smallest in lookups:
+            key = tuple(r[k] for k in fact_keys)
+            found = keyed.get(key)  # a null component is in no dict key
+            if kind == "anti":
+                keep = keep and found is None
+            else:
+                keep = keep and found is not None
+            if kind == "inner":
+                r.update(found if found is not None else smallest)
+        member.append(int(keep))
+        for name, v in r.items():
+            if name not in fact.schema.names:
+                mapped.setdefault(name, []).append(v)
+    return member, mapped
+
+
+def _ints(values):
+    return pa.array(values, type=pa.int64())
+
+
+def _single_key_case(dim_keys, fact_keys):
+    dim = pa.table({"dk": _ints(dim_keys),
+                    "dv": pa.array([f"v{k}" for k in dim_keys]),
+                    "dn": _ints([k * 10 for k in dim_keys])})
+    fact = pa.table({"fk": _ints(fact_keys),
+                     "x": pa.array(np.arange(len(fact_keys), dtype=np.float64))})
+    return fact, [(dim, ["fk"], ["dk"], "inner")]
+
+
+def _two_column_case(span):
+    """A dimension unique on (a, b) with each component `span` wide: fact
+    pairs that hit, pairs whose components each exist but never together,
+    and pairs out of range on one side."""
+    rng = np.random.default_rng(11)
+    a = rng.choice(span, 40, replace=False)
+    b = rng.choice(span, 40, replace=False)
+    dim = pa.table({"da": _ints(a), "db": _ints(b),
+                    "cost": pa.array(rng.uniform(1, 9, 40))})
+    pick = rng.integers(0, 40, 300)
+    fa, fb = a[pick], b[pick]
+    fb = np.where(np.arange(300) % 5 == 0, b[(pick + 1) % 40], fb)  # no such pair
+    fa = np.where(np.arange(300) % 7 == 0, span + 3, fa)  # above the range
+    fact = pa.table({"fa": _ints(fa), "fb": _ints(fb)})
+    return fact, [(dim, ["fa", "fb"], ["da", "db"], "inner")]
+
+
+def _chained_case():
+    """A second dimension keyed on a column the first one maps (q7, q9's
+    nation through s_nationkey): a row the first drops looks up the
+    smallest key's value, a row the second drops stays dropped."""
+    dim = pa.table({"dk": _ints([5, 3, 9, 7]), "rk": _ints([2, 0, 1, 4])})
+    region = pa.table({"r": _ints([0, 1, 2]), "rname": pa.array(["zero", "one", "two"])})
+    fact = pa.table({"fk": _ints([3, 5, 7, 9, 4, 3, 11, 5])})
+    return fact, [(dim, ["fk"], ["dk"], "inner"), (region, ["rk"], ["r"], "inner")]
+
+
+def _membership_case(kind, sub_keys):
+    sub = pa.table({"sk": _ints(sub_keys)})
+    fact = pa.table({"fk": _ints([1, 1, 2, 3, None, 5, 8, 0]),
+                     "x": pa.array(np.arange(8, dtype=np.float64))})
+    return fact, [(sub, ["fk"], ["sk"], kind)]
+
+
+_SCRAMBLED = [40, 7, 23, 15, 2, 31, 11, 19]
+# name -> (case, MAX_POSITION_ENTRIES for the case or None, maps answered by position)
+EXTEND_CASES = {
+    "dense_single_key": (_single_key_case(list(range(10, 90)), list(range(100)) * 3), None, 1),
+    # the same keys with the bound below their range of 80
+    "single_key_beyond_the_bound": (
+        _single_key_case(list(range(10, 90)), list(range(100)) * 3), 79, 0),
+    "two_column_key_dense": (_two_column_case(60), None, 1),
+    # 10**6 x 10**6 packed values: no position table at the real bound
+    "two_column_key_wide": (_two_column_case(10 ** 6), None, 0),
+    "chained_through_a_mapped_column": (_chained_case(), None, 2),
+    "chained_beyond_the_bound": (_chained_case(), 1, 0),
+    "null_fact_keys": (_single_key_case([4, 2, 6], [2, None, 6, None, 4, 2]), None, 1),
+    "null_fact_keys_beyond_the_bound": (
+        _single_key_case([4, 2, 6], [2, None, 6, None, 4, 2]), 2, 0),
+    # below, above and between the dimension's keys
+    "fact_keys_outside_and_between": (
+        _single_key_case([10, 20, 30], [5, 10, 15, 20, 25, 30, 35, -1, 10 ** 12]), None, 1),
+    "fact_keys_outside_and_between_beyond_the_bound": (
+        _single_key_case([10, 20, 30], [5, 10, 15, 20, 25, 30, 35, -1, 10 ** 12]), 20, 0),
+    "semi": (_membership_case("semi", [1, 1, 3, 8]), None, 1),
+    "semi_beyond_the_bound": (_membership_case("semi", [1, 1, 3, 8]), 7, 0),
+    "anti": (_membership_case("anti", [1, 1, 3, 8]), None, 1),
+    "anti_beyond_the_bound": (_membership_case("anti", [1, 1, 3, 8]), 7, 0),
+    "semi_with_an_empty_dimension": (_membership_case("semi", []), None, 1),
+    "dimension_not_in_key_order": (_single_key_case(_SCRAMBLED, sorted(_SCRAMBLED) * 2 + [3]), None, 1),
+    "dimension_not_in_key_order_beyond_the_bound": (
+        _single_key_case(_SCRAMBLED, sorted(_SCRAMBLED) * 2 + [3]), 38, 0),
+}
+
+
+def _mapped_scan(fact, atts):
+    return MappedScanExec(_Rows(fact), [
+        Attachment(_Rows(dim), fact_keys, dim_keys, kind=kind)
+        for dim, fact_keys, dim_keys, kind in atts])
+
+
+@pytest.mark.parametrize("name", EXTEND_CASES)
+def test_extend_against_a_dict_reference(name, monkeypatch):
+    (fact, atts), bound, want_dense = EXTEND_CASES[name]
+    if bound is not None:
+        monkeypatch.setattr(mappedscan, "MAX_POSITION_ENTRIES", bound)
+    scan = _mapped_scan(fact, atts)
+    maps = scan._ensure_maps(TaskContext())
+    assert sum(m["pos"] is not None for m in maps) == want_dense
+    got = pa.Table.from_batches(
+        [scan._extend(b, maps) for b in fact.to_batches(max_chunksize=64)])
+    assert got.schema == scan.schema()
+    for field in fact.schema:  # the fact's own columns pass through
+        assert got.column(field.name).equals(fact.column(field.name))
+    member, mapped = _reference_extend(fact, atts)
+    assert got.column("__member").to_pylist() == member
+    assert set(mapped) == set(got.schema.names) - set(fact.schema.names) - {"__member"}
+    # every row, dropped rows too: those carry the smallest key's values
+    for column, want in mapped.items():
+        assert got.column(column).to_pylist() == want, column
+    # the case is one: some rows stay and some go (none stays on an empty dimension)
+    assert sum(member) < len(member)
+    assert (sum(member) > 0) == all(dim.num_rows for dim, *_ in atts)
+
+
+def test_the_two_ways_sum_to_map_rows_and_the_span_says_dense():
+    """One scan with a map of each way: `device.map_dense_rows` and
+    `.map_sorted_rows` count fact rows x attachments answered by position
+    table and by search, and every per-batch span carries `dense`."""
+    from ballista_tpu.utils import tracing
+
+    fact, (narrow,) = _two_column_case(60)
+    _, (wide,) = _two_column_case(10 ** 6)
+    # the wide dimension's columns renamed so that both attach to one fact
+    wide_dim = wide[0].rename_columns(["wa", "wb", "wcost"])
+    atts = [narrow, (wide_dim, ["fa", "fb"], ["wa", "wb"], "inner"),
+            (pa.table({"sk": _ints([1, 2])}), ["fa"], ["sk"], "semi")]
+    scan = _mapped_scan(fact, atts)
+    tracing.reset()
+    out = pa.Table.from_batches(list(scan.execute(0, TaskContext())))
+    counters = tracing.counters()
+    spans = [s for s in tracing.spans() if s.name == "runtime.dim_build"]
+    tracing.reset()
+    assert out.num_rows == fact.num_rows == 300
+    assert counters["device.map_rows"] == 3 * 300
+    assert counters["device.map_dense_rows"] == 2 * 300
+    assert counters["device.map_sorted_rows"] == 1 * 300
+    assert len(spans) == 1 + 5 and all(s.attrs["dense"] == 2 for s in spans)
+    gathers = [s for s in spans if "fact_rows" in s.attrs]
+    assert sum(s.attrs["fact_rows"] * s.attrs["dense"] for s in gathers) == 600
