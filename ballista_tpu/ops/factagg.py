@@ -64,6 +64,7 @@ from ballista_tpu.ops.stage import (
     FusedAggregateStage,
     _SCAN_TYPES,
     decode_packed_rows,
+    groups_out,
     jnp_unpack_i32,
     packed_positions,
     state_column,
@@ -690,8 +691,8 @@ class FactAggregateStage:
             )
         )
         record_readback(packed.shape[-1], packed.nbytes)
-        with tracing.span("runtime.to_arrow", engine="factagg_sec"):
-            return self._secondary_to_table(packed, info, sec, GA)
+        with tracing.span("runtime.to_arrow", engine="factagg_sec") as sp:
+            return groups_out(sp, self._secondary_to_table(packed, info, sec, GA))
 
     def _secondary_to_table(self, packed, info, sec, GA) -> pa.Table:
         rows = self._decode(packed)
@@ -1033,8 +1034,8 @@ class FactAggregateStage:
             # map selected ranks back to dim rows: every valid rank is a
             # member, so the bisection finds it
             dim_idx = maps["dim_rows"][np.searchsorted(maps["ranks"], idx)]
-            with tracing.span("runtime.to_arrow", engine="factagg_topk"):
-                return self._assemble(sel, idx, dim_idx, dim["table"], ent)
+            with tracing.span("runtime.to_arrow", engine="factagg_topk") as sp:
+                return groups_out(sp, self._assemble(sel, idx, dim_idx, dim["table"], ent))
         positions = maps["ranks"]
         n_pos = len(positions)
         if n_pos == 0:
@@ -1046,13 +1047,13 @@ class FactAggregateStage:
                             maps["pos_pad"])
         )[:, :n_pos]
         record_readback(sel.shape[-1], sel.nbytes)
-        with tracing.span("runtime.to_arrow", engine="factagg_select"):
+        with tracing.span("runtime.to_arrow", engine="factagg_select") as sp:
             rows = self._decode(sel)
             keep = rows[0] > 0
-            return self._assemble_decoded(
+            return groups_out(sp, self._assemble_decoded(
                 [r[keep] for r in rows], positions[keep], maps["dim_rows"][keep],
                 dim["table"], ent,
-            )
+            ))
 
     def _decode(self, stacked: np.ndarray) -> List[np.ndarray]:
         return [

@@ -91,6 +91,24 @@ class _JoinProgramOwner:
 _AOT_OWNER = _JoinProgramOwner()
 
 
+def _join_span(build_rows: int, probe_rows: int, **attrs) -> tracing.Span:
+    """`runtime.join`: a device join's work on one partition, one span
+    around the encoding of its keys and one around a probe batch (sort,
+    search, gather, their readbacks and the flattening into selections are
+    its children). It ends with the `path` the batch took and its `out_rows`."""
+    return tracing.span("runtime.join", build_rows=build_rows,
+                        probe_rows=probe_rows, **attrs)
+
+
+def _record_path(path: str, reason: Optional[str] = None) -> None:
+    """The path a join took: counted for join_path_stats, and named on the
+    `runtime.join` span it is decided in."""
+    record_join_path(path, reason)
+    sp = tracing.current()
+    if sp is not None and sp.name == "runtime.join":
+        sp.set(path=path)
+
+
 def match_runs(sorted_codes, probe_codes):
     """Per-probe match run over a sorted build-code plane (traced):
     paired searchsorted left/right -> (starts, counts), both int32. Null
@@ -157,7 +175,7 @@ def _decline(kind: str, reason: str) -> None:
     so tracing must count a fallback, not a mid-ladder step-aside."""
     from ballista_tpu.ops.kernels import host_fallback
 
-    record_join_path(kind, reason)
+    _record_path(kind, reason)
     record_routing("host", "join")
     return host_fallback(reason)
 
@@ -309,7 +327,7 @@ def _split_offload(
         np.repeat(offsets[hot_sel], hot_counts) + _within_runs(hot_counts)
     ] = bi_hot
     probe_idx = np.repeat(np.arange(np_, dtype=np.int64), counts_h)
-    record_join_path("split", "partial offload at the tier boundary")
+    _record_path("split", "partial offload at the tier boundary")
     # observed = the modeled work (gather + host join); the merge scatter
     # and oracle assertion are not part of the prediction, so timing them
     # would bill measurement scope as model error in the mispredict rate
@@ -337,7 +355,7 @@ def _extended_gather(
     record_routing("device", "join.extended", dev_pred, dt)
     costmodel.check_mispredict("join.gather", probe_slots * tier, dev_pred, dt)
     build_idx, probe_idx = _flatten_matched(mat, counts_h, np_)
-    record_join_path("device", "extended tier past the static ladder")
+    _record_path("device", "extended tier past the static ladder")
     return build_idx, probe_idx, counts_h.astype(np.int64)
 
 
@@ -361,6 +379,16 @@ def device_join_indices(
     the static ladder is the whole story, so direct callers keep the
     pre-adaptive contract exactly.
     """
+    with _join_span(len(build_codes), len(probe_codes)) as sp:
+        res = _join_batch(build_codes, probe_codes, config)
+        sp.set(out_rows=0 if res is None else len(res[0]))
+        return res
+
+
+def _join_batch(
+    build_codes: np.ndarray, probe_codes: np.ndarray, config
+) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """device_join_indices' work, inside its `runtime.join` span."""
     from ballista_tpu.ops import costmodel
     from ballista_tpu.ops.kernels import join_multiplicity_tier
 
@@ -376,7 +404,7 @@ def device_join_indices(
         mat, dt = _run_gather(order, starts, counts, tier, np_)
         with tracing.span("runtime.to_arrow", engine="join"):
             build_idx, probe_idx = _flatten_matched(mat, counts_h, np_)
-        record_join_path("device")
+        _record_path("device")
         record_routing("device", "join", predicted, dt)
         # gross mispredict either way re-tiers the bucket: a first-call
         # trace/compile outlier otherwise inflates the rate for _FORGET_AT
@@ -414,13 +442,15 @@ def device_membership_counts(
     host oracle's ``join_indices`` counts bit-for-bit), or None when the
     device declines (empty side, code range past int32) — every decline
     carries a recorded reason."""
-    plane = _counts_plane(build_codes, probe_codes)
-    if plane is None:
-        return None  # reason recorded by _counts_plane's decline
-    _order, _starts, _counts, counts_h, _np = plane
-    record_join_path("device")
-    record_routing("device", "join.counts")
-    return counts_h.astype(np.int64)
+    with _join_span(len(build_codes), len(probe_codes), out_rows=0) as sp:
+        plane = _counts_plane(build_codes, probe_codes)
+        if plane is None:
+            return None  # reason recorded by _counts_plane's decline
+        _order, _starts, _counts, counts_h, _np = plane
+        _record_path("device")
+        record_routing("device", "join.counts")
+        sp.set(out_rows=int(np.count_nonzero(counts_h)))  # the probes with a match
+        return counts_h.astype(np.int64)
 
 
 def try_device_inner_join(
@@ -444,10 +474,11 @@ def try_device_inner_join(
     probe row reproduces it exactly, keeping bit-identity."""
     from ballista_tpu.physical.joinutil import combined_key_codes
 
-    bcodes, pcodes = combined_key_codes(
-        [build.column(k) for k in build_keys],
-        [probe.column(k) for k in probe_keys],
-    )
+    with _join_span(build.num_rows, probe.num_rows, path="encode"):
+        bcodes, pcodes = combined_key_codes(
+            [build.column(k) for k in build_keys],
+            [probe.column(k) for k in probe_keys],
+        )
     if (
         config is not None
         and config.tpu_cost_model()

@@ -59,6 +59,19 @@ def plane_keys(idx: int) -> Tuple[int, int]:
     the f64 total order."""
     return -2 * idx - 2, -2 * idx - 3
 
+
+def groups_out(sp: tracing.Span, table: Optional[pa.Table]) -> Optional[pa.Table]:
+    """The table a device aggregate hands the host, counted: its rows as
+    `groups` on the `runtime.to_arrow` span that assembled it and as one
+    increment of `device.groups_out` a stage result (the groups of a grouped
+    aggregate, the survivors of a top-k or a member select). None, a top-k
+    that handed nothing over, counts nothing."""
+    if table is not None:
+        sp.set(groups=table.num_rows)
+        tracing.incr("device.groups_out", table.num_rows)
+    return table
+
+
 # ceiling for the per-batch unrolled path (G linear passes); beyond it the
 # stage switches to the sorted chunked-segment layout (ops/layout.py), which
 # is O(N) regardless of group count
@@ -2040,8 +2053,8 @@ class FusedAggregateStage:
         record_readback(
             sum(f.shape[-1] for f in fetched), sum(f.nbytes for f in fetched)
         )
-        with tracing.span("runtime.to_arrow", engine="unrolled"):
-            return self._batches_to_table(fetched, pending)
+        with tracing.span("runtime.to_arrow", engine="unrolled") as sp:
+            return groups_out(sp, self._batches_to_table(fetched, pending))
 
     def _batches_to_table(self, fetched, pending) -> pa.Table:
         partial_tables: List[pa.Table] = []
@@ -2116,13 +2129,13 @@ class FusedAggregateStage:
             self._sorted_step(ent["layout"].L1, ent["cols"], aux, ent["clen"])
         )
         record_readback(stacked.shape[-1], stacked.nbytes)
-        with tracing.span("runtime.to_arrow", engine="sorted"):
+        with tracing.span("runtime.to_arrow", engine="sorted") as sp:
             rows = self._decode_stacked(stacked)
             counts = layout.fold_sum(rows[0])
             outputs = self._fold_state_rows(layout, rows)
-            return self._assemble_partial(
+            return groups_out(sp, self._assemble_partial(
                 outputs, counts, ent["key_values"], ent["n_groups"]
-            )
+            ))
 
     # -- fused Sort+Limit epilogue (planner _topk_pushdown) -------------
     def _topk_eligible(self, ent: dict) -> bool:
@@ -2311,8 +2324,8 @@ class FusedAggregateStage:
                                      ent["n_groups"], owner)
             )
         record_readback(packed.shape[-1], packed.nbytes)
-        with tracing.span("runtime.to_arrow", engine="topk"):
-            return self._topk_to_table(ent, packed)
+        with tracing.span("runtime.to_arrow", engine="topk") as sp:
+            return groups_out(sp, self._topk_to_table(ent, packed))
 
     def _topk_to_table(self, ent: dict, packed: np.ndarray) -> Optional[pa.Table]:
         spec = self.topk

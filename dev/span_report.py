@@ -9,9 +9,11 @@ query of median length; per span name the seconds a query spends in it
 (median over the text's queries: whole and self), and the median of the
 first third of the window's queries against the last third (what grows as
 the process serves more); above them the window's counters, the `serde.*`
-and how many rank maps were served (`device.rank_map_hit`) and built. Texts
-are matched to jobs by the order of the `client.collect` spans: the window
-sends its texts round-robin.
+and how many rank maps were served (`device.rank_map_hit`) and built, the rows
+the device aggregates handed the host (`device.groups_out`, and per text the
+`groups` of its `runtime.to_arrow` spans) and the device joins (`runtime.join`:
+spans, seconds, `out_rows`). Texts are matched to jobs by the order of the
+`client.collect` spans: the window sends its texts round-robin.
 """
 
 from __future__ import annotations
@@ -23,6 +25,17 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks", "chip")]
+
+
+def _joins(spans: list) -> str:
+    """`runtime.join` spans in one line: how many, their seconds, what they emitted."""
+    probes = [s for s in spans if s.attrs.get("path") != "encode"]
+    paths = sorted({str(s.attrs.get("path")) for s in probes})
+    return (f"runtime.join: {len(spans)} spans, {sum(s.seconds for s in spans) * 1e3:.1f} ms, "
+            f"{len(probes)} probe batches ({'/'.join(paths) or 'no path'}) of "
+            f"{sum(s.attrs.get('build_rows', 0) for s in probes)} build and "
+            f"{sum(s.attrs.get('probe_rows', 0) for s in probes)} probe rows, "
+            f"out_rows {sum(s.attrs.get('out_rows', 0) for s in probes)}")
 
 
 def report(cell: str) -> str:
@@ -39,7 +52,9 @@ def report(cell: str) -> str:
     out = [f"{cell}: {len(log)} spans, {len(roots)} queries, counters {counters}",
            f"rank maps (fact aggregates, a partition a query): "
            f"{counters.get('device.rank_map_hit', 0)} served from the prepared partition, "
-           f"{counters.get('device.rank_map_build', 0)} built"]
+           f"{counters.get('device.rank_map_build', 0)} built",
+           f"device aggregates handed the host {counters.get('device.groups_out', 0)} rows "
+           f"(device.groups_out); {_joins([s for s in log if s.name == 'runtime.join'])}"]
     for i, text in enumerate(texts):
         mine = roots[i::len(texts)]
         if not mine:
@@ -47,6 +62,11 @@ def report(cell: str) -> str:
         median = sorted(mine, key=lambda s: s.seconds)[len(mine) // 2]
         out += [f"\n== {text}: {len(mine)} queries, median {median.seconds * 1e3:.1f} ms "
                 f"(job {median.job})", tracing.timeline(median.job, by_job[median.job])]
+        spans = by_job[median.job]
+        out.append("groups by engine: " + (", ".join(
+            f"{s.attrs.get('engine')} {s.attrs['groups']}" for s in spans
+            if s.name == "runtime.to_arrow" and "groups" in s.attrs) or "none")
+            + "; " + _joins([s for s in spans if s.name == "runtime.join"]))
         per_query = [tracing.by_name(by_job[r.job]) for r in mine]
         third = max(1, len(mine) // 3)
         out.append(f"{'span':26s} {'n':>5s} {'total ms':>9s} {'self ms':>9s} "
