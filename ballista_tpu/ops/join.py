@@ -1,14 +1,30 @@
-"""Device join kernel: sort + paired binary search with M:N multiplicity.
+"""Device join kernel: sort + per-probe run look-up with M:N multiplicity.
 
 The TPU-native lowering of the hash join (every TPC-H join, primary-key or
 not): build-side key codes are sorted on device ONCE (stable, so equal keys
-keep build-row order), each probe key binary-searches the sorted plane twice
-(jnp.searchsorted side='left'/'right' — branch-free, vectorizes on the VPU)
-and the difference is that probe's match run-length. Duplicate build keys no
-longer decline: run-lengths exclusive-scan into per-probe output offsets on
-the host flatten, and matches materialize through a bounded-width gather
-whose static width is the smallest admission tier
-(ops/kernels.py::JOIN_MULTIPLICITY_TIERS) covering the observed maximum
+keep build-row order) and each probe key finds its run in the sorted plane,
+a start and a run-length, by one of two look-ups chosen from the key range
+the join observes (ISSUE 34):
+
+- **position table** (table_runs, program `join_runs_table`) where the codes
+  are dense: a scatter-add of the build codes and a prefix sum give, per
+  code, the run's start and length, and a probe reads both by its code. It
+  answers when the range, bucketed, is at most _TABLE_MAX_ENTRIES and
+  neither it nor the build plane exceeds _TABLE_SLOTS_PER_PROBE_SLOT slots a
+  probe slot; a single integer key is `value - lo` (physical/joinutil.py),
+  so every TPC-H key is dense.
+- **paired binary search** (match_runs, program `join_runs`) elsewhere: a
+  packed composite key whose range is wide, a long range or a long build
+  under a short probe. jnp.searchsorted side='left'/'right' lowers to a loop of dependent
+  gathers over the whole probe plane, one a halving: a v5e's trace read
+  0.21 s a million probes for the pair (2 x 14 gathers of 7.5 ms over 8k
+  build rows), 97 % of the device's time in the cell that joins most.
+
+Both return the same starts and counts bit for bit wherever a probe has a
+match. Duplicate build keys do not decline: run-lengths exclusive-scan into
+per-probe output offsets on the host flatten, and matches materialize
+through a bounded-width gather whose static width is the smallest admission
+tier (ops/kernels.py::JOIN_MULTIPLICITY_TIERS) covering the observed maximum
 multiplicity, keeping every program shape static.
 
 Adaptive execution (ISSUE 10) replaces the wholesale decline past the
@@ -28,7 +44,7 @@ the host oracle:
 - **cold paths unchanged** — no config / cost model off / no structural
   skew reproduces the pre-adaptive step-aside exactly.
 
-Both compiled programs (the runs kernel and each gather width) ride the
+The compiled programs (the two runs kernels and each gather width) ride the
 persistent AOT disk tier (ops/aotcache.py) under a stable plan-independent
 key, so a cold process reloads them as compile_hit_disk instead of fresh
 traces (ISSUE 10 satellite; PR 8 residue).
@@ -77,6 +93,14 @@ _SPLIT_MAX_HOT_KEYS = 16
 # planned-build-side row excess past which the observed cardinalities are
 # treated as a plan-time misestimate and the build side switches
 _BUILD_SWAP_RATIO = 4
+# a probe finds its run in a position table (table_runs) where the table is
+# at most this long (two int32 planes of 128 MB, 0.67 GB of temporaries) and
+# neither the table nor the build plane is longer than this multiple of the
+# probe slots: building it (0.29 ns an entry, 7.8 ns a build row on a v5e)
+# then costs a fraction of the searches it replaces (200-270 ns a probe
+# slot). Wider ranges and longer builds keep the paired search (match_runs)
+_TABLE_MAX_ENTRIES = 1 << 25
+_TABLE_SLOTS_PER_PROBE_SLOT = 64
 
 
 class _JoinProgramOwner:
@@ -100,13 +124,18 @@ def _join_span(build_rows: int, probe_rows: int, **attrs) -> tracing.Span:
                         probe_rows=probe_rows, **attrs)
 
 
+def _set_on_join_span(**attrs) -> None:
+    """Attributes of the `runtime.join` span a probe batch is decided in."""
+    sp = tracing.current()
+    if sp is not None and sp.name == "runtime.join":
+        sp.set(**attrs)
+
+
 def _record_path(path: str, reason: Optional[str] = None) -> None:
     """The path a join took: counted for join_path_stats, and named on the
     `runtime.join` span it is decided in."""
     record_join_path(path, reason)
-    sp = tracing.current()
-    if sp is not None and sp.name == "runtime.join":
-        sp.set(path=path)
+    _set_on_join_span(path=path)
 
 
 def match_runs(sorted_codes, probe_codes):
@@ -125,6 +154,43 @@ def match_runs(sorted_codes, probe_codes):
     return starts.astype(jnp.int32), counts.astype(jnp.int32)
 
 
+def _prefix_sum(x):
+    """Inclusive prefix sum of a power-of-two-long int32 vector (traced), in
+    two levels: rows of 2,048, then the rows' totals. One jnp.cumsum over a
+    2M-entry table took the chip's compiler 8.5-13.4 s a program shape, this
+    form 0.7 s (PERF.md, PR 34)."""
+    import jax.numpy as jnp
+
+    rows = jnp.cumsum(x.reshape(-1, min(x.shape[0], 2048)), axis=1, dtype=jnp.int32)
+    before = jnp.cumsum(rows[:, -1], dtype=jnp.int32) - rows[:, -1]
+    return (rows + before[:, None]).reshape(-1)
+
+
+def table_runs(build_codes, probe_codes, entries: int):
+    """match_runs' answer read off a position table (traced), for codes in
+    [0, entries), a power of two: per code c, how many build rows carry it
+    (a scatter-add; null codes -1 and `_PAD_CODE` fall outside and are
+    dropped) and where its run begins in the stable-sorted build plane, the
+    null build rows plus the exclusive prefix sum of the counts (nulls sort
+    first, pads last): what searchsorted(side="left") returns there. The two
+    are one table of two-wide rows, so a probe is ONE gather (6.1 ms a
+    million probes on a v5e; two gathers of two tables 24.4). Bit-identical
+    to match_runs in `counts` everywhere and in `starts` wherever `counts`
+    is above 0; a probe without a match gets some other start, which nothing
+    reads (gather_matches masks past the run length)."""
+    import jax.numpy as jnp
+
+    valid = (build_codes >= 0) & (build_codes < entries)
+    counts_t = jnp.zeros(entries, jnp.int32).at[
+        jnp.where(valid, build_codes, entries)
+    ].add(1, mode="drop")
+    nulls = jnp.sum(build_codes < 0, dtype=jnp.int32)
+    starts_t = nulls + _prefix_sum(counts_t) - counts_t
+    row = jnp.stack([starts_t, counts_t], axis=1)[jnp.clip(probe_codes, 0, entries - 1)]
+    inside = (probe_codes >= 0) & (probe_codes < entries)
+    return row[:, 0], jnp.where(inside, row[:, 1], 0)
+
+
 def gather_matches(values, starts, counts, width: int):
     """Bounded-width gather (traced): [P, width] of values[starts + j],
     masked to -1 past each probe's run length. Shared with the mesh
@@ -138,18 +204,30 @@ def gather_matches(values, starts, counts, width: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _runs_kernel():
+def _runs_kernel(table: bool = False):
+    """The runs program, one of two by how a probe finds its run:
+    `join_runs` searches the sorted plane, `join_runs_table` reads a position
+    table of a static number of entries (its leading argument)."""
     from ballista_tpu.ops import aotcache
 
-    def runs(build_codes, probe_codes):
+    def _order(build_codes):
         import jax.numpy as jnp
 
         # stable: equal build keys keep original row order, matching the
         # host oracle's kind="stable" argsort (bit-equal output order)
-        order = jnp.argsort(build_codes, stable=True)
-        starts, counts = match_runs(build_codes[order], probe_codes)
-        return order, starts, counts
+        return jnp.argsort(build_codes, stable=True)
 
+    def runs(build_codes, probe_codes):
+        order = _order(build_codes)
+        return (order, *match_runs(build_codes[order], probe_codes))
+
+    def runs_table(entries, build_codes, probe_codes):
+        return (_order(build_codes), *table_runs(build_codes, probe_codes, entries))
+
+    if table:
+        return aotcache.wrap_step(
+            _AOT_OWNER, "join_runs_table", runs_table, static_argnums=(0,)
+        )
     return aotcache.wrap_step(_AOT_OWNER, "join_runs", runs, static_argnums=())
 
 
@@ -197,10 +275,22 @@ def _counts_plane(build_codes: np.ndarray, probe_codes: np.ndarray):
     b = jnp.asarray(
         pad_to(build_codes.astype(np.int32), bucket_rows(nb, 16), _PAD_CODE)
     )
-    # null probe keys (-1) binary-search below all valid codes and compare
-    # unequal — already a non-match; pads reuse the same sentinel
+    # null probe keys (-1) search below all valid codes and lie outside the
+    # position table — already a non-match; pads reuse the same sentinel
     p = jnp.asarray(pad_to(probe_codes.astype(np.int32), bucket_rows(np_, 16), -1))
-    order, starts, counts = _runs_kernel()(b, p)
+    # the look-up adapts to the key range observed: a dense range reads a
+    # position table, a wide one (a packed composite key) keeps the search
+    entries = bucket_rows(hi + 1)
+    if entries <= _TABLE_MAX_ENTRIES and (
+        max(entries, b.shape[0]) <= _TABLE_SLOTS_PER_PROBE_SLOT * p.shape[0]
+    ):
+        method = "table"
+        order, starts, counts = _runs_kernel(table=True)(entries, b, p)
+    else:
+        method = "search"
+        order, starts, counts = _runs_kernel()(b, p)
+    tracing.incr(f"device.join_{method}_probes", np_)
+    _set_on_join_span(method=method, entries=entries)
     counts_h = readback(counts)[:np_]
     return order, starts, counts, counts_h, np_
 

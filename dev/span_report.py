@@ -12,12 +12,14 @@ the process serves more); above them the window's counters, the `serde.*`
 and how many rank maps were served (`device.rank_map_hit`) and built, the rows
 the device aggregates handed the host (`device.groups_out`, and per text the
 `groups` of its `runtime.to_arrow` spans) and the device joins (`runtime.join`:
-spans, seconds, `out_rows`). Texts are matched to jobs by the order of the
-`client.collect` spans: the window sends its texts round-robin.
+spans, seconds, `path`, `method` and `entries`, `out_rows`). Texts are matched
+to jobs by the order of the `client.collect` spans: the window sends its texts
+round-robin.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import statistics
@@ -31,8 +33,11 @@ def _joins(spans: list) -> str:
     """`runtime.join` spans in one line: how many, their seconds, what they emitted."""
     probes = [s for s in spans if s.attrs.get("path") != "encode"]
     paths = sorted({str(s.attrs.get("path")) for s in probes})
+    # how a probe found its run: position table or search, and the entries of its key range
+    how = collections.Counter((str(s.attrs.get("method")), s.attrs.get("entries", 0)) for s in probes)
+    methods = ", ".join(f"{n} {m} of {e} entries" for (m, e), n in sorted(how.items()))
     return (f"runtime.join: {len(spans)} spans, {sum(s.seconds for s in spans) * 1e3:.1f} ms, "
-            f"{len(probes)} probe batches ({'/'.join(paths) or 'no path'}) of "
+            f"{len(probes)} probe batches ({'/'.join(paths) or 'no path'}; {methods or 'no method'}) of "
             f"{sum(s.attrs.get('build_rows', 0) for s in probes)} build and "
             f"{sum(s.attrs.get('probe_rows', 0) for s in probes)} probe rows, "
             f"out_rows {sum(s.attrs.get('out_rows', 0) for s in probes)}")

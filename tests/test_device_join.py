@@ -6,7 +6,8 @@ import pytest
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.engine import ExecutionContext
-from ballista_tpu.ops.join import device_join_indices
+from ballista_tpu.ops.join import device_join_indices, device_membership_counts
+from ballista_tpu.ops.runtime import bucket_rows
 
 
 def test_device_join_indices_basic():
@@ -200,3 +201,228 @@ def test_q13_q22_device_engaged_on_tpch(tmp_path_factory):
     # counts are ints and q22's sum is exact over these rows: bit-equality
     assert out["cpu"]["q13"] == out["tpu"]["q13"]
     assert out["cpu"]["q22"] == out["tpu"]["q22"]
+
+
+# ---------------------------------------------------------------------------
+# the runs program's two look-ups (ISSUE 34): a position table where the key
+# range is dense, the paired search elsewhere
+# ---------------------------------------------------------------------------
+
+
+def _dup(rng, n_keys, hi, k):
+    """`n_keys` distinct codes of [0, hi], each 1..k times, shuffled."""
+    keys = rng.choice(hi + 1, size=n_keys, replace=False)
+    build = np.repeat(keys, rng.integers(1, k + 1, n_keys)).astype(np.int64)
+    rng.shuffle(build)
+    return build
+
+
+def _case_small_runs(rng):
+    return _dup(rng, 300, 999, 3), rng.integers(0, 1000, 700).astype(np.int64)
+
+
+def _case_top_tier(rng):
+    from ballista_tpu.ops.kernels import JOIN_MULTIPLICITY_TIERS
+
+    build = _dup(rng, 12, 40, JOIN_MULTIPLICITY_TIERS[-1])
+    build = np.concatenate([build, np.full(JOIN_MULTIPLICITY_TIERS[-1], 41)])
+    return build, rng.integers(0, 45, 90).astype(np.int64)
+
+
+def _case_nulls_both_sides(rng):
+    build, probe = _case_small_runs(rng)
+    build[rng.integers(0, len(build), 40)] = -1
+    probe[rng.integers(0, len(probe), 60)] = -1
+    return build, probe
+
+
+def _case_probe_outside_build_range(rng):
+    # build codes in [200, 300], probes below, inside and above
+    return _dup(rng, 60, 100, 4) + 200, rng.integers(0, 5000, 800).astype(np.int64)
+
+
+def _case_power_of_two_planes(rng):
+    # no pad slot on either side, and the top code of the range present
+    build = np.concatenate([_dup(rng, 200, 1022, 1), [1023] * 56])
+    return build.astype(np.int64), rng.integers(0, 1024, 2048).astype(np.int64)
+
+
+def _case_build_of_one_row(rng):
+    return np.array([7], dtype=np.int64), rng.integers(0, 12, 50).astype(np.int64)
+
+
+def _case_all_build_null(rng):
+    return np.full(20, -1, dtype=np.int64), rng.integers(-1, 30, 40).astype(np.int64)
+
+
+_RUNS_CASES = [
+    _case_small_runs, _case_top_tier, _case_nulls_both_sides,
+    _case_probe_outside_build_range, _case_power_of_two_planes,
+    _case_build_of_one_row, _case_all_build_null,
+]
+
+
+def _case_id(case):
+    return case.__name__[len("_case_"):]
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+@pytest.mark.parametrize("case", _RUNS_CASES, ids=_case_id)
+def test_table_runs_is_match_runs_bit_for_bit(case, seed):
+    """`counts` everywhere and `starts` wherever a probe has a match, over
+    the padded planes the programs see (pad codes on both sides)."""
+    import jax.numpy as jnp
+
+    from ballista_tpu.ops.join import _PAD_CODE, match_runs, table_runs
+    from ballista_tpu.ops.runtime import pad_to
+
+    build, probe = case(np.random.default_rng(seed))
+    b = jnp.asarray(pad_to(build.astype(np.int32), bucket_rows(len(build), 16), _PAD_CODE))
+    p = jnp.asarray(pad_to(probe.astype(np.int32), bucket_rows(len(probe), 16), -1))
+    starts, counts = (np.asarray(a) for a in match_runs(b[jnp.argsort(b, stable=True)], p))
+    for entries in (bucket_rows(int(max(build.max(), probe.max())) + 1), 1 << 14):
+        starts_t, counts_t = (np.asarray(a) for a in table_runs(b, p, entries))
+        assert starts_t.dtype == counts_t.dtype == np.int32
+        np.testing.assert_array_equal(counts_t, counts)
+        np.testing.assert_array_equal(starts_t[counts > 0], starts[counts > 0])
+
+
+@pytest.fixture(params=["table", "search"])
+def method(request, monkeypatch):
+    """Both look-ups over the same inputs: no range is dense once the cap is 0."""
+    from ballista_tpu.ops import join as jmod
+
+    if request.param == "search":
+        monkeypatch.setattr(jmod, "_TABLE_MAX_ENTRIES", 0)
+    return request.param
+
+
+@pytest.mark.parametrize("case", _RUNS_CASES[:-1], ids=_case_id)
+def test_both_entries_equal_the_host_oracle_by_either_method(case, method):
+    from ballista_tpu.physical.joinutil import join_indices
+    from ballista_tpu.utils import tracing
+
+    build, probe = case(np.random.default_rng(5))
+    bi_o, pi_o = join_indices(build, probe, "inner")
+    tracing.reset()
+    build_idx, probe_idx, counts = device_join_indices(build, probe)
+    members = device_membership_counts(build, probe)
+    assert tracing.counters("device") == {f"join_{method}_probes": 2 * len(probe)}
+    np.testing.assert_array_equal(build_idx, bi_o)
+    np.testing.assert_array_equal(probe_idx, pi_o)
+    want = np.bincount(pi_o, minlength=len(probe))
+    np.testing.assert_array_equal(counts, want)
+    np.testing.assert_array_equal(members, want)
+
+
+def _probe_spans():
+    from ballista_tpu.utils import tracing
+
+    return [s for s in tracing.spans()
+            if s.name == "runtime.join" and s.attrs.get("path") != "encode"]
+
+
+@pytest.mark.parametrize("top,want", [(4095, "table"), (4096, "search")],
+                         ids=["range_at_the_cap", "range_past_the_cap"])
+def test_the_cap_on_the_table_s_entries_decides(top, want, monkeypatch):
+    from ballista_tpu.ops import join as jmod
+    from ballista_tpu.physical.joinutil import join_indices
+    from ballista_tpu.utils import tracing
+
+    monkeypatch.setattr(jmod, "_TABLE_MAX_ENTRIES", 4096)
+    rng = np.random.default_rng(top)
+    build = np.concatenate([_dup(rng, 500, top - 1, 2), [top, top]])
+    probe = np.concatenate([rng.integers(0, top + 1, 900), [top]]).astype(np.int64)
+    tracing.reset()
+    build_idx, probe_idx, _ = device_join_indices(build, probe)
+    (span,) = _probe_spans()
+    assert (span.attrs["method"], span.attrs["path"]) == (want, "device")
+    assert span.attrs["entries"] == (4096 if want == "table" else 8192)
+    assert tracing.counters("device") == {f"join_{want}_probes": len(probe)}
+    bi_o, pi_o = join_indices(build, probe, "inner")
+    np.testing.assert_array_equal(build_idx, bi_o)
+    np.testing.assert_array_equal(probe_idx, pi_o)
+
+
+@pytest.mark.parametrize("what", ["range", "build"])
+def test_a_long_table_or_build_stays_away_from_a_short_probe(what):
+    """The second bound, at the module's own constant: a prefix sum over 2M
+    entries, or a scatter of a long build, to spare 1,024 probe slots their
+    searches; the same build under enough probes reads the table."""
+    from ballista_tpu.ops import join as jmod
+    from ballista_tpu.utils import tracing
+
+    rng = np.random.default_rng(9)
+    per_slot = jmod._TABLE_SLOTS_PER_PROBE_SLOT
+    top, rows = ((1 << 21) - 1, 3000) if what == "range" else (1023, 1024 * per_slot + 1)
+    build = rng.integers(0, top + 1, rows).astype(np.int64)
+    build[:2] = top
+    slots = bucket_rows(rows, 16) if what == "build" else top + 1
+    short = rng.integers(0, top + 1, 1000).astype(np.int64)
+    enough = rng.integers(0, top + 1, slots // per_slot - 5).astype(np.int64)
+    tracing.reset()
+    device_membership_counts(build, short)
+    device_membership_counts(build, enough)
+    assert [(s.attrs["method"], s.attrs["entries"]) for s in _probe_spans()] == [
+        ("search", top + 1), ("table", top + 1)]
+    assert tracing.counters("device") == {"join_search_probes": len(short),
+                                          "join_table_probes": len(enough)}
+
+
+def test_a_wide_composite_key_keeps_the_search_and_the_counters_add_up():
+    """A dense single key reads the table, two packed columns of 40,000 values
+    each (a range of 1.6e9) search; the two counters sum to the probe rows of
+    every probe span, and a decline counts under neither."""
+    from ballista_tpu.ops.join import try_device_inner_join
+    from ballista_tpu.utils import tracing
+
+    rng = np.random.default_rng(4)
+    build, probe = (pa.table({c: pa.array(rng.integers(0, 40_000, n)) for c in "ab"})
+                    for n in (300, 2000))
+    tracing.reset()
+    assert try_device_inner_join(build, probe, ["a"], ["a"]) is not None
+    assert try_device_inner_join(build, probe, ["a", "b"], ["a", "b"]) is not None
+    assert device_membership_counts(np.arange(50), np.arange(70)) is not None
+    assert device_membership_counts(np.arange(50), np.empty(0, np.int64)) is None
+    spans = _probe_spans()
+    assert [s.attrs.get("method") for s in spans] == ["table", "search", "table", None]
+    assert spans[1].attrs["entries"] == 1 << 31 and spans[3].attrs["path"] == "host_fallback"
+    counters = tracing.counters()
+    assert counters["device.join_table_probes"] == 2000 + 70
+    assert counters["device.join_search_probes"] == 2000
+    assert sum(s.attrs["probe_rows"] for s in spans) == 2000 + 2000 + 70
+
+
+def test_the_two_runs_programs_are_named_and_compile_once_a_shape(tmp_path, monkeypatch):
+    from ballista_tpu.ops import aotcache
+    from ballista_tpu.ops import join as jmod
+    from ballista_tpu.utils import tracing
+
+    aotcache.reset(clear_disk_dir=True)
+    aotcache.configure(BallistaConfig({"ballista.tpu.aot_cache": str(tmp_path / "aot")}))
+    jmod._runs_kernel.cache_clear()
+    jmod._gather_kernel.cache_clear()
+    try:
+        rng = np.random.default_rng(8)
+        build = _dup(rng, 100, 900, 2)
+        tracing.reset()
+        device_join_indices(build, rng.integers(0, 901, 400).astype(np.int64))
+        monkeypatch.setattr(jmod, "_TABLE_MAX_ENTRIES", 0)
+        device_join_indices(build, rng.integers(0, 901, 400).astype(np.int64))
+        monkeypatch.undo()
+        launched = [s.attrs["program"] for s in tracing.spans() if s.name == "runtime.launch"]
+        assert launched[0::2] == ["join_runs_table", "join_runs"]
+        assert launched[1] == launched[3] and launched[1].startswith("join_gather_w")
+        first = tracing.counters("serving", reset=True)
+        assert first["compile_trace"] == 3 and first["aot_saved"] == 3
+        # the same buckets again, other rows: nothing compiles, by either method
+        device_join_indices(build[:-3], rng.integers(0, 1000, 300).astype(np.int64))
+        monkeypatch.setattr(jmod, "_TABLE_MAX_ENTRIES", 0)
+        device_join_indices(build[:-3], rng.integers(0, 1000, 300).astype(np.int64))
+        again = tracing.counters("serving", reset=True)
+        assert not again.get("compile_trace") and not again.get("compile_hit_disk"), again
+    finally:
+        aotcache.reset(clear_disk_dir=True)
+        aotcache.configure(BallistaConfig({}))
+        jmod._runs_kernel.cache_clear()
+        jmod._gather_kernel.cache_clear()

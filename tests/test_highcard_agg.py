@@ -137,6 +137,22 @@ def test_a_device_join_is_a_span_with_its_rows(name, served):
 
 
 @pytest.mark.parametrize("name", TEXTS)
+def test_every_probe_batch_says_how_it_found_its_runs(name, served):
+    """ISSUE 34: the texts' keys are single integers of a dense range, so a
+    probe reads the position table wherever the range is not long for it;
+    the two counters sum to the probe rows of the probe spans."""
+    warm = served[name]["warm"]
+    probes = [s for s in warm["spans"]
+              if s.name == "runtime.join" and s.attrs["path"] != "encode"]
+    assert {s.attrs["method"] for s in probes} <= {"table", "search"}
+    assert any(s.attrs["method"] == "table" for s in probes)
+    for method in ("table", "search"):
+        assert warm["counters"].get(f"device.join_{method}_probes", 0) == sum(
+            s.attrs["probe_rows"] for s in probes if s.attrs["method"] == method)
+    assert all(s.attrs["entries"] >= 1024 for s in probes)
+
+
+@pytest.mark.parametrize("name", TEXTS)
 def test_groups_out_is_the_sum_of_the_stage_results_rows(name, served):
     for log in (served[name]["cold"], served[name]["warm"]):
         handed = [s.attrs["groups"] for s in log["spans"]
