@@ -19,7 +19,7 @@ def tpu_filter(batch: pa.RecordBatch, predicate) -> Optional[pa.RecordBatch]:
     return kernels.filter_batch(batch, predicate)
 
 
-def tpu_hash_aggregate(exec_node, partition: int, ctx) -> Optional[pa.Table]:
+def tpu_hash_aggregate(exec_node, partition: int, ctx, keyset=None) -> Optional[pa.Table]:
     from ballista_tpu.ops import kernels
 
-    return kernels.hash_aggregate(exec_node, partition, ctx)
+    return kernels.hash_aggregate(exec_node, partition, ctx, keyset)

@@ -373,7 +373,11 @@ def resolve_stage(exec_node, ctx) -> Tuple[object, str, str, float]:
     return stage, key, stable, unit_size
 
 
-def hash_aggregate(exec_node, partition: int, ctx) -> Optional[pa.Table]:
+def hash_aggregate(exec_node, partition: int, ctx, keyset=None) -> Optional[pa.Table]:
+    """The device's partial states of one partition, or None (host path).
+    `keyset` (HashAggregateExec.execute) goes to the fused stage's sorted
+    engine, which may then hand back only the groups whose key it holds;
+    every other engine returns every group."""
     # bind the AOT disk tier + cost model from THIS dispatch's config
     # BEFORE any path that compiles or observes (the countjoin prescreen
     # included — resolve_stage rebinds idempotently for the ladder below)
@@ -426,7 +430,12 @@ def hash_aggregate(exec_node, partition: int, ctx) -> Optional[pa.Table]:
         # prepare, the dimension side, the rank maps)
         with costmodel.timed(op, units=max(1.0, unit_size), routing_op="stage"), \
                 tracing.span("runtime.stage", engine=type(stage).__name__):
-            out = stage.run(partition, ctx)
+            from ballista_tpu.ops.stage import FusedAggregateStage
+
+            if keyset is not None and isinstance(stage, FusedAggregateStage):
+                out = stage.run(partition, ctx, keyset=keyset)
+            else:
+                out = stage.run(partition, ctx)
         return out
     except UnsupportedOnDevice:
         # permanently declined: free its pinned device entries and their

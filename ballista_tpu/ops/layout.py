@@ -155,6 +155,38 @@ class SortedSegmentLayout:
             )
         return self
 
+    def subset(self, groups: np.ndarray):
+        """(chunk ids, layout) for the ascending group ids `groups`: the
+        chunks of those groups in layout order, and a layout whose fold_*
+        fold exactly those chunks, read back in that order, to the groups'
+        states: the same reduceat over the same segments, so each state is
+        bit for bit the whole layout's."""
+        assert self._host_folds, "min_one_chunk=False layouts fold in-program"
+        if self.one_chunk_per_group:
+            counts = np.ones(len(groups), dtype=np.int64)
+            chunks = np.asarray(groups, dtype=np.int64)
+        else:
+            bounds = np.append(self._fold_starts, self.V)
+            starts = bounds[groups]
+            counts = bounds[groups + 1] - starts
+            firsts = np.cumsum(counts) - counts
+            chunks = (np.repeat(starts - firsts, counts)
+                      + np.arange(int(counts.sum()), dtype=np.int64))
+        sub = SortedSegmentLayout.__new__(SortedSegmentLayout)
+        sub.n_groups = len(groups)
+        sub.L1 = self.L1
+        sub.V = len(chunks)
+        sub.owner = np.repeat(np.arange(len(groups), dtype=np.int64), counts)
+        sub.clen = self.clen[chunks]
+        sub.row_take = None
+        sub._host_folds = True
+        # the whole layout's fold, not identity where every kept group
+        # happens to own one chunk: fold_sum widens as it always has
+        sub.one_chunk_per_group = self.one_chunk_per_group
+        if not sub.one_chunk_per_group:
+            sub._fold_starts = np.cumsum(counts) - counts
+        return chunks, sub
+
     def materialize(self, col: np.ndarray) -> np.ndarray:
         """Lay a row-space column out as [V, L1] tiles (pad slots carry row
         0's value; every consumer masks with the clen-derived pad)."""

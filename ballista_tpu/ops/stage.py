@@ -72,6 +72,72 @@ def groups_out(sp: tracing.Span, table: Optional[pa.Table]) -> Optional[pa.Table
     return table
 
 
+def _int_keys(arr) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """(int64 values, valid mask) of an integer key column; None for any
+    other type."""
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    elif not isinstance(arr, pa.Array):
+        arr = pa.array(arr)
+    if not pa.types.is_integer(arr.type):
+        return None
+    valid = (np.ones(len(arr), dtype=bool) if not arr.null_count
+             else arr.is_valid().to_numpy(zero_copy_only=False))
+    values = arr.fill_null(0).to_numpy(zero_copy_only=False).astype(np.int64)
+    return values, valid
+
+
+class GroupKeyIndex:
+    """A prepared partition's groups by key: each group's key values packed
+    to one int64 (mixed radix over each column's range among the groups)
+    and sorted once, so that a key set of m rows finds its member groups in
+    m binary searches of the n groups, never n searches of the set."""
+
+    def __init__(self, lows, spans, packed, order) -> None:
+        self.lows, self.spans = lows, spans
+        self.packed = packed  # sorted [n]
+        self.order = order  # group id of each packed entry
+
+    @classmethod
+    def build(cls, key_values) -> Optional["GroupKeyIndex"]:
+        """None where a key column is not integral, holds a NULL, or the
+        ranges' product does not fit 62 bits."""
+        if not key_values:
+            return None
+        cols = [_int_keys(kv) for kv in key_values]
+        if any(c is None or not c[1].all() for c in cols) or not len(cols[0][0]):
+            return None
+        lows = [int(v.min()) for v, _ in cols]
+        spans = [int(v.max()) - lo + 1 for (v, _), lo in zip(cols, lows)]
+        total = 1
+        for span in spans:
+            total *= span
+        if total >= 1 << 62:
+            return None
+        packed = np.zeros(len(cols[0][0]), dtype=np.int64)
+        for (v, _), lo, span in zip(cols, lows, spans):
+            packed = packed * span + (v - lo)
+        order = np.argsort(packed, kind="stable")
+        return cls(lows, spans, packed[order], order)
+
+    def members(self, keyset) -> Optional[np.ndarray]:
+        """Ascending ids of the groups whose key is a row of `keyset` (one
+        array a key column); a NULL or out-of-range key matches nothing.
+        None where a column is not integral."""
+        cols = [_int_keys(k) for k in keyset]
+        if len(cols) != len(self.spans) or any(c is None for c in cols):
+            return None
+        ok = np.ones(len(cols[0][0]), dtype=bool)
+        packed = np.zeros(len(ok), dtype=np.int64)
+        for (v, valid), lo, span in zip(cols, self.lows, self.spans):
+            ok &= valid & (v >= lo) & (v < lo + span)
+            packed = packed * span + np.where(ok, v - lo, 0)
+        probe = packed[ok]
+        pos = np.minimum(np.searchsorted(self.packed, probe), len(self.packed) - 1)
+        hit = self.packed[pos] == probe
+        return np.unique(self.order[pos[hit]])
+
+
 # ceiling for the per-batch unrolled path (G linear passes); beyond it the
 # stage switches to the sorted chunked-segment layout (ops/layout.py), which
 # is O(N) regardless of group count
@@ -523,6 +589,7 @@ class FusedAggregateStage:
         self._topk_fold_step = None  # skewed-cover variant (in-program fold)
         self._step = self._build_step()
         self._sorted_step = None  # built on first high-cardinality partition
+        self._keyset_take = None  # built on a sorted partition's first key set
         self._device_cache: Dict[int, dict] = {}
         # narrow-residency choice of the first batch, keyed by col index
         # (or "derived:<name>" for derived tiles); kept stable across
@@ -1967,7 +2034,10 @@ class FusedAggregateStage:
             outputs, counts, ent["key_values"], ent["n_groups"]
         )
 
-    def run(self, partition: int, ctx) -> Optional[pa.Table]:
+    def run(self, partition: int, ctx, keyset=None) -> Optional[pa.Table]:
+        """The partition's partial states. `keyset` (HashAggregateExec.execute)
+        lets the sorted engine read back only the groups whose key it holds;
+        the other engines return every group."""
         import jax.numpy as jnp
 
         use_cache = ctx.config.device_cache() and self.cacheable
@@ -2034,7 +2104,7 @@ class FusedAggregateStage:
                 out = self._run_topk(prepared, aux)
                 if out is not None:
                     return out  # None: boundary tie -> full readback below
-            return self._run_sorted(prepared, aux)
+            return self._run_sorted(prepared, aux, keyset)
         if prepared["kind"] == "pallas_sorted":
             return self._run_pallas_sorted(prepared, aux)
 
@@ -2121,13 +2191,19 @@ class FusedAggregateStage:
                 outs.append(folds[fold](rows[row]))
         return outs
 
-    def _run_sorted(self, ent: dict, aux) -> pa.Table:
+    def _run_sorted(self, ent: dict, aux, keyset=None) -> pa.Table:
         from ballista_tpu.ops.runtime import copy_out, record_readback
 
         layout = ent["layout"]
-        stacked = copy_out(
-            self._sorted_step(ent["layout"].L1, ent["cols"], aux, ent["clen"])
-        )
+        stacked = self._sorted_step(layout.L1, ent["cols"], aux, ent["clen"])
+        if keyset is not None:
+            # the membership is host work beside the launched step
+            groups = self._keyset_groups(ent, keyset)
+            if groups is not None:
+                return self._run_keyset(ent, stacked, groups)
+            tracing.incr("device.keyset_groups_kept", ent["n_groups"])
+            tracing.incr("device.keyset_groups_dropped", 0)
+        stacked = copy_out(stacked)
         record_readback(stacked.shape[-1], stacked.nbytes)
         with tracing.span("runtime.to_arrow", engine="sorted") as sp:
             rows = self._decode_stacked(stacked)
@@ -2136,6 +2212,59 @@ class FusedAggregateStage:
             return groups_out(sp, self._assemble_partial(
                 outputs, counts, ent["key_values"], ent["n_groups"]
             ))
+
+    # -- key-set select (a SEMI join's keys, distributed/planner.py) ------
+    def _keyset_groups(self, ent: dict, keyset) -> Optional[np.ndarray]:
+        """Ascending ids of the entry's groups whose key is a row of
+        `keyset`, or None where the full readback runs: keys that do not
+        pack to one int64, or a set that keeps more than half the groups.
+        The packed, sorted group keys are built once and kept in the entry."""
+        index = ent.get("key_index")
+        if index is None:
+            index = ent["key_index"] = GroupKeyIndex.build(ent["key_values"]) or False
+        if index is False:
+            return None
+        groups = index.members(keyset)
+        if groups is None or 2 * len(groups) > ent["n_groups"]:
+            return None
+        return groups
+
+    def _run_keyset(self, ent: dict, stacked, groups: np.ndarray) -> pa.Table:
+        """Read back only the chunks of `groups`: a take on the device out of
+        the sorted step's output, then the same decode, fold and assembly
+        over those chunks alone. No reduction changes, so each kept group's
+        states are bit for bit the full readback's."""
+        import jax.numpy as jnp
+
+        from ballista_tpu.ops.runtime import bucket_rows, copy_out, record_readback
+
+        chunks, sub = ent["layout"].subset(groups)
+        n = len(chunks)
+        idx = np.zeros(bucket_rows(n), dtype=np.int32)
+        idx[:n] = chunks
+        if self._keyset_take is None:
+            from ballista_tpu.ops import aotcache
+
+            self._keyset_take = aotcache.wrap_step(
+                self, "keyset_take",
+                lambda stacked, idx: jnp.take(stacked, idx, axis=1),
+                static_argnums=(),
+            )
+        packed = copy_out(self._keyset_take(stacked, jnp.asarray(idx)))
+        record_readback(n, packed.nbytes)
+        with tracing.span("runtime.to_arrow", engine="sorted", keyset=len(groups)) as sp:
+            rows = self._decode_stacked(packed[:, :n])
+            counts = sub.fold_sum(rows[0])
+            outputs = self._fold_state_rows(sub, rows)
+            take = pa.array(groups)
+            key_values = [
+                (kv if isinstance(kv, (pa.Array, pa.ChunkedArray)) else pa.array(kv)).take(take)
+                for kv in ent["key_values"]
+            ]
+            out = groups_out(sp, self._assemble_partial(outputs, counts, key_values, len(groups)))
+        tracing.incr("device.keyset_groups_kept", len(groups))
+        tracing.incr("device.keyset_groups_dropped", ent["n_groups"] - len(groups))
+        return out
 
     # -- fused Sort+Limit epilogue (planner _topk_pushdown) -------------
     def _topk_eligible(self, ent: dict) -> bool:

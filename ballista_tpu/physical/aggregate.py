@@ -155,10 +155,14 @@ class HashAggregateExec(ExecutionPlan):
         )
 
     # ------------------------------------------------------------------
-    def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+    def execute(self, partition: int, ctx: TaskContext,
+                keyset: Optional[List[pa.Array]] = None) -> Iterator[pa.RecordBatch]:
+        """`keyset`, one array a group key in group order, is the key set of
+        the SEMI join this execution feeds: the device may leave out the
+        groups whose key is not in it (the join drops them anyway)."""
         if ctx.backend == "tpu" and self.mode in (AggregateMode.PARTIAL, AggregateMode.SINGLE):
             from ballista_tpu.ops.dispatch import tpu_hash_aggregate
-            out = tpu_hash_aggregate(self, partition, ctx)
+            out = tpu_hash_aggregate(self, partition, ctx, keyset)
             if out is not None:
                 if self.mode == AggregateMode.SINGLE:
                     # the fused stage produces partial states; merge them to

@@ -19,7 +19,12 @@ import pyarrow as pa
 
 from ballista_tpu.errors import PlanError
 from ballista_tpu.logical.plan import JoinType
-from ballista_tpu.physical.joinutil import combined_key_codes, join_indices, take_table
+from ballista_tpu.physical.joinutil import (
+    combined_key_codes,
+    int32_key_codes,
+    join_indices,
+    take_table,
+)
 from ballista_tpu.physical.plan import (
     ExecutionPlan,
     Partitioning,
@@ -88,6 +93,23 @@ class HashJoinExec(ExecutionPlan):
                 self._build_table = collect_all(side, ctx)
             return self._build_table
 
+    def _keyset(self, build: pa.Table) -> dict:
+        """What a SEMI join hands an aggregate input grouped exactly by its
+        keys (the planner's key-set link, distributed/planner.py): the build
+        side's key columns in group order, for this execution alone. The
+        join below still decides every row, so the aggregate may return any
+        superset of the member groups."""
+        from ballista_tpu.physical.aggregate import HashAggregateExec
+
+        left = self.left
+        if self.join_type != JoinType.SEMI or not isinstance(left, HashAggregateExec):
+            return {}
+        groups = left.schema().names[: len(left.group_exprs)]
+        right_of = dict(self.on)
+        if not groups or sorted(right_of) != sorted(groups) or len(self.on) != len(groups):
+            return {}
+        return {"keyset": [build.column(right_of[g]) for g in groups]}
+
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
         left_keys = [n for n, _ in self.on]
         right_keys = [n for _, n in self.on]
@@ -95,7 +117,7 @@ class HashJoinExec(ExecutionPlan):
         if self.join_type in (JoinType.SEMI, JoinType.ANTI):
             # build on RIGHT, probe LEFT partitions
             build = self._collect_build(self.right, ctx)
-            probe = collect_partition(self.left, partition, ctx)
+            probe = collect_partition(self.left, partition, ctx, **self._keyset(build))
             bcodes, pcodes = combined_key_codes(
                 [build.column(k) for k in right_keys],
                 [probe.column(k) for k in left_keys],
@@ -114,7 +136,7 @@ class HashJoinExec(ExecutionPlan):
 
                 aotcache.configure(ctx.config)
                 costmodel.configure(ctx.config)
-                counts = device_membership_counts(bcodes, pcodes)
+                counts = device_membership_counts(*int32_key_codes(bcodes, pcodes))
                 if counts is not None:
                     keep = counts > 0 if self.join_type == JoinType.SEMI \
                         else counts == 0

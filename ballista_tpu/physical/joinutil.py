@@ -59,6 +59,23 @@ def _refactorize(
     return dense[: len(lcodes)], dense[len(lcodes):], card
 
 
+def int32_key_codes(
+    left_codes: np.ndarray, right_codes: np.ndarray
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The same equalities in codes below 2**31 - 2, the device join's
+    range: as they are where they fit, else dense over the distinct
+    non-NULL codes of both sides (a packed composite key, q20's pair);
+    NULL (-1) stays -1."""
+    hi = max([int(c.max()) for c in (left_codes, right_codes) if len(c)], default=-1)
+    if hi < 2**31 - 2:
+        return left_codes, right_codes
+    combined = np.concatenate([left_codes, right_codes])
+    valid = combined >= 0
+    out = np.full(len(combined), -1, dtype=np.int64)
+    out[valid] = np.unique(combined[valid], return_inverse=True)[1]
+    return out[: len(left_codes)], out[len(left_codes):]
+
+
 def combined_key_codes(
     left_cols: List[pa.Array], right_cols: List[pa.Array]
 ) -> Tuple[np.ndarray, np.ndarray]:

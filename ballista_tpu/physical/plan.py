@@ -130,10 +130,12 @@ class ExecutionPlan:
 
 
 def collect_partition(
-    plan: ExecutionPlan, partition: int, ctx: TaskContext
+    plan: ExecutionPlan, partition: int, ctx: TaskContext, **execute_kw
 ) -> pa.Table:
-    """Drain one partition into a Table (reference utils.rs collect_stream)."""
-    batches = list(plan.execute(partition, ctx))
+    """Drain one partition into a Table (reference utils.rs collect_stream).
+    `execute_kw` goes to the plan's own `execute` (a SEMI join hands its
+    aggregate input the key set, HashAggregateExec.execute)."""
+    batches = list(plan.execute(partition, ctx, **execute_kw))
     if not batches:
         return pa.table(
             {f.name: pa.array([], type=f.type) for f in plan.schema()},

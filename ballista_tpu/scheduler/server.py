@@ -917,7 +917,8 @@ class SchedulerServer:
 
     def _plan_and_commit(self, job_id, plan, config, attempt, content_key, sp) -> None:
         physical = self._physical_plan(plan, config, content_key)
-        stages = DistributedPlanner(config).plan_query_stages(job_id, physical)
+        planner = DistributedPlanner(config)
+        stages = planner.plan_query_stages(job_id, physical)
         # all-or-nothing publish: stage plans, pending tasks, and the
         # queued->running flip land in ONE KV batch, so a crash mid-plan
         # leaves no torn job (the job stays queued with no planning keys
@@ -941,7 +942,7 @@ class SchedulerServer:
         with tracing.span("scheduler.plan.commit"):
             batch.commit()
         ready.since[None] = tracing.now_ns()  # tasks are runnable from here
-        sp.set(stages=len(stages), tasks=tasks)
+        sp.set(stages=len(stages), tasks=tasks, keyset_links=planner.keyset_links)
         log.info("job %s planned into %d stages", job_id, len(stages))
 
     def _record_queue(self, status: pb.TaskStatus) -> None:

@@ -11,8 +11,11 @@ first third of the window's queries against the last third (what grows as
 the process serves more); above them the window's counters, the `serde.*`
 and how many rank maps were served (`device.rank_map_hit`) and built, the rows
 the device aggregates handed the host (`device.groups_out`, and per text the
-`groups` of its `runtime.to_arrow` spans) and the device joins (`runtime.join`:
-spans, seconds, `path`, `method` and `entries`, `out_rows`). Texts are matched
+`groups` of its `runtime.to_arrow` spans), the device joins (`runtime.join`:
+spans, seconds, `path`, `method` and `entries`, `out_rows`) and the key-set
+links (`keyset_links` of the `scheduler.plan` spans, the groups the sorted
+engine kept and dropped, `device.keyset_groups_kept` / `_dropped`, and per
+text the `keyset` of its `runtime.to_arrow` spans). Texts are matched
 to jobs by the order of the `client.collect` spans: the window sends its texts
 round-robin.
 """
@@ -43,6 +46,15 @@ def _joins(spans: list) -> str:
             f"out_rows {sum(s.attrs.get('out_rows', 0) for s in probes)}")
 
 
+def _keysets(spans: list, counters: dict) -> str:
+    """The key-set links of these spans' plans and the groups they dropped."""
+    plans = [s for s in spans if s.name == "scheduler.plan"]
+    return (f"keyset_links {sum(s.attrs.get('keyset_links', 0) for s in plans)} on "
+            f"{len(plans)} plans; device.keyset_groups_kept "
+            f"{counters.get('device.keyset_groups_kept', 0)}, dropped "
+            f"{counters.get('device.keyset_groups_dropped', 0)}")
+
+
 def report(cell: str) -> str:
     import run
     from ballista_tpu.utils import tracing
@@ -59,7 +71,8 @@ def report(cell: str) -> str:
            f"{counters.get('device.rank_map_hit', 0)} served from the prepared partition, "
            f"{counters.get('device.rank_map_build', 0)} built",
            f"device aggregates handed the host {counters.get('device.groups_out', 0)} rows "
-           f"(device.groups_out); {_joins([s for s in log if s.name == 'runtime.join'])}"]
+           f"(device.groups_out); {_joins([s for s in log if s.name == 'runtime.join'])}; "
+           f"{_keysets(log, counters)}"]
     for i, text in enumerate(texts):
         mine = roots[i::len(texts)]
         if not mine:
@@ -71,7 +84,12 @@ def report(cell: str) -> str:
         out.append("groups by engine: " + (", ".join(
             f"{s.attrs.get('engine')} {s.attrs['groups']}" for s in spans
             if s.name == "runtime.to_arrow" and "groups" in s.attrs) or "none")
-            + "; " + _joins([s for s in spans if s.name == "runtime.join"]))
+            + "; " + _joins([s for s in spans if s.name == "runtime.join"])
+            + "; key-set selects: " + (", ".join(
+                str(s.attrs["keyset"]) for s in spans
+                if s.name == "runtime.to_arrow" and "keyset" in s.attrs) or "none")
+            + "; keyset_links " + str(sum(s.attrs.get("keyset_links", 0) for s in spans
+                                          if s.name == "scheduler.plan")))
         per_query = [tracing.by_name(by_job[r.job]) for r in mine]
         third = max(1, len(mine) // 3)
         out.append(f"{'span':26s} {'n':>5s} {'total ms':>9s} {'self ms':>9s} "

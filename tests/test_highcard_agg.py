@@ -163,15 +163,21 @@ def test_groups_out_is_the_sum_of_the_stage_results_rows(name, served):
 
 
 def test_q20_hands_over_no_fewer_groups_than_the_year_s_pairs(served, data):
-    """The inner aggregate's whole result reaches the host: a group a
-    (l_partkey, l_suppkey) pair of 1994 at least (the partial aggregates of
-    several tasks may each emit a pair), and q15's a group a supplier, twice."""
+    """q20's inner aggregate holds a group a (l_partkey, l_suppkey) pair of
+    1994 at least (the partial aggregates of several tasks may each hold a
+    pair), and since the key-set link it hands over only those its LEFT
+    join can match: what it kept and dropped add up to no fewer than the
+    year's pairs, and what reached the host is the kept. q15's whole result
+    still reaches the host, a group a supplier, twice."""
     li = load(data[0], {"lineitem": ["l_partkey", "l_suppkey", "l_shipdate"]})["lineitem"]
     days = li.l_shipdate
     year = li[(days >= _day("1994-01-01")) & (days < _day("1995-01-01"))]
     pairs = len(year.drop_duplicates(["l_partkey", "l_suppkey"]))
     assert pairs > 1000
-    assert served["q20"]["warm"]["counters"]["device.groups_out"] >= pairs
+    q20 = served["q20"]["warm"]["counters"]
+    kept, dropped = q20["device.keyset_groups_kept"], q20["device.keyset_groups_dropped"]
+    assert kept + dropped >= pairs and 0 < kept < pairs / 10
+    assert q20["device.groups_out"] <= kept
     quarter = li[(days >= _day("1996-01-01")) & (days < _day("1996-04-01"))]
     assert (served["q15"]["warm"]["counters"]["device.groups_out"]
             >= 2 * quarter.l_suppkey.nunique())
