@@ -89,15 +89,26 @@ def write_stream_to_disk(
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = _piece_tmp_path(path)
     try:
-        with pa.ipc.new_file(tmp, schema, options=_ipc_options(codec)) as w:
-            for b in batches:
-                w.write_batch(b)
+        with tracing.span("shuffle.ipc", bytes=0):  # the opening
+            w = pa.ipc.new_file(tmp, schema, options=_ipc_options(codec))
+        done = False
+        try:
+            for b in batches:  # the pull is the plan's, not the writer's
+                with tracing.span("shuffle.ipc") as sp:
+                    w.write_batch(b)
+                    nbytes = b.nbytes
+                    sp.set(bytes=nbytes)
                 stats.num_rows += b.num_rows
                 stats.num_batches += 1
-                stats.num_bytes += b.nbytes
-        if pre_publish is not None:
-            pre_publish()
-        os.replace(tmp, path)
+                stats.num_bytes += nbytes
+            done = True
+        finally:
+            with tracing.span("shuffle.ipc", bytes=0):  # the close and the publish
+                w.close()
+                if done:
+                    if pre_publish is not None:
+                        pre_publish()
+                    os.replace(tmp, path)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
@@ -184,19 +195,24 @@ class _ExchangeCapture:
             tracing.incr("exchange.skipped_budget")
             return False
         kept = False
-        for piece, batches in self.pieces.items():
-            kept |= exchange.publish(
-                self.executor_id, self.job_id, self.stage_id,
-                self.map_partition, piece, batches, schema,
-                self.attempt, finals[piece], self.budget,
-                tenant=self.tenant, tenant_budget=self.tenant_budget,
-            )
+        with tracing.span("shuffle.publish", pieces=len(self.pieces)) as sp:
+            for piece, batches in self.pieces.items():
+                kept |= exchange.publish(
+                    self.executor_id, self.job_id, self.stage_id,
+                    self.map_partition, piece, batches, schema,
+                    self.attempt, finals[piece], self.budget,
+                    tenant=self.tenant, tenant_budget=self.tenant_budget,
+                )
+            sp.set(kept=kept)
         return kept
 
 
 class ShuffleWriterExec(ExecutionPlan):
     """Stage-top operator: executes one input partition of its child and
     materializes it, hash/round-robin split across output partitions."""
+
+    # its work is the `shuffle.write` span: no `executor.operator` around it
+    traced_pulls = False
 
     def __init__(
         self,
@@ -263,8 +279,10 @@ class ShuffleWriterExec(ExecutionPlan):
         aggregate stats. Piece paths: {base}/{m}.arrow with {base} from
         shuffle_output_base — the executor work dir (local tier) or the
         shared storage dir (shared tier, same atomic publish)."""
-        # self time is the partitioning and the IPC write: the input plan's
-        # execution shows as the spans it opens beneath this one
+        # the input plan's pulls (`executor.operator`), the partitioning
+        # (`shuffle.partition`), the IPC writes, close and publish
+        # (`shuffle.ipc`) and the resident exchange (`shuffle.publish`) are
+        # its children: its self time is the loop between them
         with tracing.span("shuffle.write", job=self.job_id, stage=self.stage_id,
                           partition=partition) as sp:
             stats = self._shuffle_write(partition, ctx)
@@ -307,13 +325,14 @@ class ShuffleWriterExec(ExecutionPlan):
             return stats
         n_out = pscheme.partition_count()
         writers = []
-        os.makedirs(base, exist_ok=True)
-        opts = _ipc_options(codec)
         finals = [os.path.join(base, f"{m}.arrow") for m in range(n_out)]
         tmps = [_piece_tmp_path(p) for p in finals]
-        for tmp in tmps:
-            sink = pa.OSFile(tmp, "wb")
-            writers.append((sink, pa.ipc.new_file(sink, schema, options=opts)))
+        with tracing.span("shuffle.ipc", bytes=0):  # the opening of the pieces
+            os.makedirs(base, exist_ok=True)
+            opts = _ipc_options(codec)
+            for tmp in tmps:
+                sink = pa.OSFile(tmp, "wb")
+                writers.append((sink, pa.ipc.new_file(sink, schema, options=opts)))
         ok = False
         try:
             import numpy as np
@@ -321,21 +340,29 @@ class ShuffleWriterExec(ExecutionPlan):
             from ballista_tpu.physical.repartition import split_by_partition
 
             for batch in self.input.execute(partition, ctx):
-                if pscheme.scheme == "hash":
-                    keys = [
-                        _as_array(e.evaluate(batch), batch.num_rows)
-                        for e in pscheme.exprs
-                    ]
-                    ids = hash_rows(keys, n_out)
-                else:
-                    ids = np.arange(batch.num_rows, dtype=np.int64) % n_out
-                for m, piece in enumerate(split_by_partition(batch, ids, n_out)):
-                    if piece.num_rows:
+                with tracing.span("shuffle.partition", rows=batch.num_rows) as sp:
+                    if pscheme.scheme == "hash":
+                        keys = [
+                            _as_array(e.evaluate(batch), batch.num_rows)
+                            for e in pscheme.exprs
+                        ]
+                        ids = hash_rows(keys, n_out)
+                    else:
+                        ids = np.arange(batch.num_rows, dtype=np.int64) % n_out
+                    pieces = [(m, piece) for m, piece in
+                              enumerate(split_by_partition(batch, ids, n_out))
+                              if piece.num_rows]
+                    sp.set(pieces=len(pieces))
+                with tracing.span("shuffle.ipc") as sp:
+                    nbytes = 0
+                    for m, piece in pieces:
                         writers[m][1].write_batch(piece)
                         if capture is not None:
                             capture.add(m, piece)
                         total.num_rows += piece.num_rows
-                        total.num_bytes += piece.nbytes
+                        nbytes += piece.nbytes
+                    sp.set(bytes=nbytes)
+                total.num_bytes += nbytes
                 total.num_batches += 1
             if pre_publish is not None:
                 # shared-tier torn-write seam: raising here leaves ok=False,
@@ -343,19 +370,20 @@ class ShuffleWriterExec(ExecutionPlan):
                 pre_publish()
             ok = True
         finally:
-            for sink, w in writers:
-                w.close()
-                sink.close()
-            if ok:
-                # publish atomically only after EVERY piece closed clean —
-                # readers (and concurrent duplicate executions) never see a
-                # partial or interleaved piece
-                for tmp, final in zip(tmps, finals):
-                    os.replace(tmp, final)
-            else:
-                for tmp in tmps:
-                    if os.path.exists(tmp):
-                        os.unlink(tmp)
+            with tracing.span("shuffle.ipc", bytes=0):  # the close and the publish
+                for sink, w in writers:
+                    w.close()
+                    sink.close()
+                if ok:
+                    # publish atomically only after EVERY piece closed clean —
+                    # readers (and concurrent duplicate executions) never see a
+                    # partial or interleaved piece
+                    for tmp, final in zip(tmps, finals):
+                        os.replace(tmp, final)
+                else:
+                    for tmp in tmps:
+                        if os.path.exists(tmp):
+                            os.unlink(tmp)
         if ok:
             tracing.incr("shuffle_tier.storage_publish" if storage_uri
                          else "shuffle_tier.local_publish")

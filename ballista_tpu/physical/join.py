@@ -33,6 +33,7 @@ from ballista_tpu.physical.plan import (
     collect_all,
     collect_partition,
 )
+from ballista_tpu.utils import tracing
 from ballista_tpu.utils.locks import make_lock
 
 
@@ -118,13 +119,11 @@ class HashJoinExec(ExecutionPlan):
             # build on RIGHT, probe LEFT partitions
             build = self._collect_build(self.right, ctx)
             probe = collect_partition(self.left, partition, ctx, **self._keyset(build))
-            bcodes, pcodes = combined_key_codes(
-                [build.column(k) for k in right_keys],
-                [probe.column(k) for k in left_keys],
-            )
-            keep_idx = None
-            if (self.filter is None and ctx.backend == "tpu"
-                    and ctx.config.tpu_device_join()):
+            build_keys = [build.column(k) for k in right_keys]
+            probe_keys = [probe.column(k) for k in left_keys]
+            asked = (self.filter is None and ctx.backend == "tpu"
+                     and ctx.config.tpu_device_join())
+            if asked:
                 # EXISTS / NOT EXISTS as device membership counting (q22):
                 # the per-probe counts plane decides kept rows — counts > 0
                 # keeps SEMI rows, counts == 0 keeps ANTI rows — exactly
@@ -136,18 +135,24 @@ class HashJoinExec(ExecutionPlan):
 
                 aotcache.configure(ctx.config)
                 costmodel.configure(ctx.config)
+                bcodes, pcodes = combined_key_codes(build_keys, probe_keys)
                 counts = device_membership_counts(*int32_key_codes(bcodes, pcodes))
                 if counts is not None:
                     keep = counts > 0 if self.join_type == JoinType.SEMI \
                         else counts == 0
-                    keep_idx = np.nonzero(keep)[0]
-            if keep_idx is None:
+                    out = probe.take(pa.array(np.nonzero(keep)[0]))
+                    yield from batch_table(out, ctx.batch_size)
+                    return
+            with self._host_join(build, probe, declined=asked) as sp:
+                if not asked:
+                    bcodes, pcodes = combined_key_codes(build_keys, probe_keys)
                 if self.filter is None:
                     how = "semi_right" if self.join_type == JoinType.SEMI else "anti_right"
                     keep_idx, _ = join_indices(bcodes, pcodes, how)
                 else:
                     keep_idx = self._filtered_semi_indices(build, probe, bcodes, pcodes)
-            out = probe.take(pa.array(keep_idx))
+                out = probe.take(pa.array(keep_idx))
+                sp.set(out_rows=out.num_rows)
             yield from batch_table(out, ctx.batch_size)
             return
 
@@ -182,41 +187,51 @@ class HashJoinExec(ExecutionPlan):
                 yield from batch_table(out, ctx.batch_size)
                 return
             device_declined = True
-        bcodes, pcodes = combined_key_codes(
-            [build.column(k) for k in left_keys],
-            [probe.column(k) for k in right_keys],
-        )
-        how = {
-            JoinType.INNER: "inner",
-            JoinType.LEFT: "left",
-            JoinType.RIGHT: "right",
-            JoinType.FULL: "full",
-        }[self.join_type]
-        if (
-            how in ("left", "full")
-            and not self.partitioned
-            and self.right.output_partitioning().partition_count() > 1
-        ):
-            raise PlanError(
-                f"{how} join requires co-partitioned inputs or a "
-                "single-partition probe side"
+        with self._host_join(build, probe, declined=device_declined) as sp:
+            bcodes, pcodes = combined_key_codes(
+                [build.column(k) for k in left_keys],
+                [probe.column(k) for k in right_keys],
             )
-        if device_declined:
-            # the host join after a device decline is the device's
-            # alternative cost: measure it so tier selection learns what
-            # host-wholesale actually costs at this scale
-            from ballista_tpu.ops import costmodel
+            how = {
+                JoinType.INNER: "inner",
+                JoinType.LEFT: "left",
+                JoinType.RIGHT: "right",
+                JoinType.FULL: "full",
+            }[self.join_type]
+            if (
+                how in ("left", "full")
+                and not self.partitioned
+                and self.right.output_partitioning().partition_count() > 1
+            ):
+                raise PlanError(
+                    f"{how} join requires co-partitioned inputs or a "
+                    "single-partition probe side"
+                )
+            if device_declined:
+                # the host join after a device decline is the device's
+                # alternative cost: measure it so tier selection learns what
+                # host-wholesale actually costs at this scale
+                from ballista_tpu.ops import costmodel
 
-            with costmodel.timed("join.host", len(bcodes) + len(pcodes),
-                                 engine="host", predictive=False):
+                with costmodel.timed("join.host", len(bcodes) + len(pcodes),
+                                     engine="host", predictive=False):
+                    left_idx, right_idx = join_indices(bcodes, pcodes, how)
+            else:
                 left_idx, right_idx = join_indices(bcodes, pcodes, how)
-        else:
-            left_idx, right_idx = join_indices(bcodes, pcodes, how)
-        left_out = take_table(build, left_idx)
-        right_out = take_table(probe, right_idx)
-        cols = list(left_out.columns) + list(right_out.columns)
-        out = pa.table(cols, schema=self._schema)
+            left_out = take_table(build, left_idx)
+            right_out = take_table(probe, right_idx)
+            cols = list(left_out.columns) + list(right_out.columns)
+            out = pa.table(cols, schema=self._schema)
+            sp.set(out_rows=out.num_rows)
         yield from batch_table(out, ctx.batch_size)
+
+    def _host_join(self, build: pa.Table, probe: pa.Table, declined: bool) -> tracing.Span:
+        """`executor.host_join`: a join the host answers, from its key codes
+        to the rows it takes; `declined` where the device was asked first.
+        It ends with the `out_rows` it emitted."""
+        return tracing.span("executor.host_join", type=self.join_type.value,
+                            build_rows=build.num_rows, probe_rows=probe.num_rows,
+                            declined=declined)
 
     def _filtered_semi_indices(
         self,

@@ -10,12 +10,14 @@ boundary from BASELINE's north star.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterator, List, Optional
 
 import pyarrow as pa
 
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.errors import PlanError
+from ballista_tpu.utils import tracing
 
 
 class Partitioning:
@@ -95,8 +97,43 @@ class TaskContext:
         return backend
 
 
+_END = object()
+
+
+def _traced_pulls(execute):
+    """`execute` whose every pull runs inside an `executor.operator` span:
+    `next()` of the batch iterator in the span (the first pull calls
+    `execute` too, and the last, which ends it, gets one as well), the yield
+    outside it, so no span is open while the consumer works and the pulls
+    of the children nest on the thread's stack."""
+
+    @functools.wraps(execute)
+    def pulls(self, *args, **kw):
+        it = None
+        while True:
+            with tracing.span("executor.operator", op=type(self).__name__) as sp:
+                if it is None:
+                    it = iter(execute(self, *args, **kw))
+                batch = next(it, _END)
+                sp.set(rows=0 if batch is _END else batch.num_rows)
+            if batch is _END:
+                return
+            yield batch
+
+    return pulls
+
+
 class ExecutionPlan:
-    """Base physical operator."""
+    """Base physical operator. Each subclass's own `execute` is wrapped once,
+    when the class is made, by `_traced_pulls`; a class that is its own
+    span (`ShuffleWriterExec`, `shuffle.write`) sets `traced_pulls = False`."""
+
+    traced_pulls = True
+
+    def __init_subclass__(cls, **kw) -> None:
+        super().__init_subclass__(**kw)
+        if "execute" in cls.__dict__ and cls.traced_pulls:
+            cls.execute = _traced_pulls(cls.__dict__["execute"])
 
     def schema(self) -> pa.Schema:
         raise NotImplementedError

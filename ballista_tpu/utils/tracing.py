@@ -14,7 +14,19 @@ bounded ring, with no lock: the oldest span falls out and is counted in
 
 While JAX is imported in the process and a profiler session is on, a span
 is also a `jax.profiler.TraceAnnotation` and lies in the `.xplane.pb` on the
-device's time line; without a session that is one flag test.
+device's time line; without a session that is one flag test. The annotation
+carries the span's `id` (`span=<id>`): the annotated spans of a trace give
+the offset between the two clocks, and with it every span of the log, a
+`record()` too, has its place on the device's time line
+(`dev/span_report.py`).
+
+Where the host's time goes inside a task: every operator's pull is an
+`executor.operator` (`op`, `rows`; `physical/plan.py`, none open across a
+yield, so an operator's self time is its own work and the engines' spans are
+its children), a join the host answers is an `executor.host_join`
+(`physical/join.py`), and a shuffle write's steps are `shuffle.partition`,
+`shuffle.ipc` and `shuffle.publish` under its `shuffle.write`
+(`distributed/stages.py`), whose self time is then the loop between them.
 
 `reset()` keeps what it clears; `drained()` returns it. `by_name` and
 `covered_s` are what the readers of a log share.
@@ -70,8 +82,9 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from ballista_tpu.utils.locks import make_lock
 
-# The largest window of today's cells closes about 39k spans (185 queries of
-# `scan_agg` in 51 s, some 210 a query; my chip run, PR 26): four of them fit.
+# The largest window of the benchmark's cells closes about 83k spans
+# (`scan_agg` on a TPU v5e: 245 queries in 51 s, some 340 a query with one
+# span an operator's pull): the ring holds three.
 RING = 1 << 18
 
 
@@ -141,7 +154,7 @@ class Span:
         if annotate is not None and annotate.is_enabled():  # a profiler session is on
             ids = {k: v for k, v in (("job", self.job), ("stage", self.stage),
                                      ("partition", self.partition)) if v is not None}
-            self._annotation = annotate(self.name, **ids)
+            self._annotation = annotate(self.name, span=self.id, **ids)
             self._annotation.__enter__()
         stack.append(self)  # last: nothing above may leave it there unpopped
         self.start_ns = time.perf_counter_ns()
