@@ -17,7 +17,7 @@ group counts pad to power-of-two buckets to bound XLA recompilation.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 import pyarrow as pa
@@ -209,6 +209,125 @@ def skew_split_plan(codes: np.ndarray, n_groups: int) -> Optional[Tuple[int, int
 class TooManyGroups(UnsupportedOnDevice):
     """Internal signal: per-batch unrolled path declined on cardinality;
     run() retries with the sorted layout before giving up."""
+
+
+# a gathered run of at most this many padded rows stays one entry whatever
+# its padding: under 2^19 rows, a few MiB a column, where each cut costs a
+# launch and a program shape (q12's filtered files, about 39k rows each)
+_WHOLE_BUCKET_ROWS = 2**20
+
+
+def _tight_runs(sizes: List[int]) -> List[Tuple[int, int]]:
+    """Split consecutive batches of `sizes` rows into runs [lo, hi) whose
+    padding to bucket_rows is at most an eighth of the bucket, or whose
+    bucket is at most _WHOLE_BUCKET_ROWS, or that hold one batch (padded
+    alone, as an entry of one batch is), or that the cut would not pad
+    less. A run past that padding is cut at the longest prefix that fits
+    half its bucket: a file's 131,072-row chunks just past a power of two
+    become entries of 2^k chunks and a tail, not one entry padded to twice
+    the file."""
+    rows = sum(sizes)
+    bucket = bucket_rows(rows)
+    whole = [(0, len(sizes))]
+    if (len(sizes) == 1 or bucket <= _WHOLE_BUCKET_ROWS
+            or bucket - rows <= bucket // 8):
+        return whole
+    cut, head = 0, 0
+    while cut < len(sizes) and head + sizes[cut] <= bucket // 2:
+        head += sizes[cut]
+        cut += 1
+    cut = min(max(cut, 1), len(sizes) - 1)
+    runs = _tight_runs(sizes[:cut]) + [
+        (lo + cut, hi + cut) for lo, hi in _tight_runs(sizes[cut:])
+    ]
+    if sum(bucket_rows(sum(sizes[lo:hi])) for lo, hi in runs) >= bucket:
+        return whole
+    return runs
+
+
+def _joined(batches: List[tuple]) -> tuple:
+    """One entry of consecutive lowered batches, each (rows, lowered
+    columns, group codes, key values, group count): the columns joined, and
+    each batch's group codes renumbered into the entry's own groups (the
+    groups that occur, numbered batch by batch in the order of their
+    batch-local codes). A single batch stays as it is."""
+    if len(batches) == 1:
+        return batches[0]
+    groups: Dict[tuple, int] = {}
+    codes: List[np.ndarray] = []
+    key_pieces: List[List[pa.Array]] = []
+    for _, _, batch_codes, key_values, n_groups in batches:
+        present = np.flatnonzero(np.bincount(batch_codes, minlength=n_groups))
+        keys = (zip(*(kv.take(pa.array(present)).to_pylist() for kv in key_values))
+                if key_values else [()] * len(present))
+        to_entry = np.zeros(n_groups, dtype=np.int32)
+        new: List[int] = []
+        for i, key in zip(present, keys):
+            g = groups.get(key)
+            if g is None:
+                g = groups[key] = len(groups)
+                new.append(int(i))
+            to_entry[i] = g
+        if len(groups) > MAX_GROUPS:
+            raise TooManyGroups(
+                f"{len(groups)} groups in a gathered batch exceed the unrolled path")
+        if new:
+            key_pieces.append([kv.take(pa.array(new)) for kv in key_values])
+        codes.append(to_entry[batch_codes])
+    cols = {idx: np.concatenate([b[1][idx] for b in batches]) for idx in batches[0][1]}
+    key_values = [pa.concat_arrays([piece[c] for piece in key_pieces])
+                  for c in range(len(key_pieces[0]))]
+    return (sum(b[0] for b in batches), cols, np.concatenate(codes),
+            key_values, len(groups))
+
+
+class _GatheredBatches:
+    """Consecutive lowered batches of one scan partition, held until they
+    become entries of the unrolled engine. A partition may come in many
+    batches (a cached parquet read hands a file over in 131,072-row chunks,
+    which a mapped scan extends one by one; a memory table in its record
+    batches), and each entry costs run() a launch, a readback and a partial
+    table. Each batch is ranked and lowered alone, so the host works on
+    chunk-sized arrays; entries() joins them.
+
+    An entry takes no batch of another scan partition: each row bucket is
+    a program of its own to compile, and files alike then give entries
+    alike. A stage over eight 7.5M-row files keeps one bucket, 2^23;
+    gathered across files up to a batch size of 2^24 rows, its entries
+    would take three (2^24, 2^23, 2^21)."""
+
+    def __init__(self, part: int) -> None:
+        self.part = part
+        self.rows = 0
+        self.batches: List[tuple] = []
+        # integer SUM/AVG input -> the most rows its int32 sum allows
+        self.sum_rows: Dict[str, int] = {}
+
+    def _bound(self, sum_rows: Dict[str, int]) -> Dict[str, int]:
+        out = dict(self.sum_rows)
+        for c, r in sum_rows.items():
+            out[c] = min(r, out.get(c, r))
+        return out
+
+    def fits(self, part: int, n: int, sum_rows: Dict[str, int], limit: int) -> bool:
+        """Whether a batch of `n` rows of scan partition `part`, whose int32
+        sums allow `sum_rows`, may join without passing `limit` rows or the
+        int32 sum bound of the entry."""
+        rows = self.rows + n
+        return (part == self.part and rows <= limit
+                and all(rows <= r for r in self._bound(sum_rows).values()))
+
+    def add(self, n: int, npcols: Dict[int, np.ndarray], codes: np.ndarray,
+            key_values: List[pa.Array], n_groups: int, sum_rows: Dict[str, int]) -> None:
+        self.batches.append((n, npcols, codes, key_values, n_groups))
+        self.rows += n
+        self.sum_rows = self._bound(sum_rows)
+
+    def entries(self) -> Iterator[tuple]:
+        """(rows, lowered columns, group codes, key values, group count) of
+        each entry: the batches in runs of bounded padding (_tight_runs)."""
+        for lo, hi in _tight_runs([b[0] for b in self.batches]):
+            yield _joined(self.batches[lo:hi])
 
 
 # --- int32 <-> f32-pair packing -------------------------------------------
@@ -997,6 +1116,11 @@ class FusedAggregateStage:
         straight to codes — ~10x faster than the streaming dictionary read).
         With scan_stride=N, driven partition p covers scan partitions
         p, p+N, p+2N, ... (N=1: SINGLE mode over MergeExec reads them all)."""
+        for _, batch in self._scan_part_batches(partition, ctx):
+            yield batch
+
+    def _scan_part_batches(self, partition: int, ctx):
+        """_scan_batches' batches, each with the scan partition it is of."""
         if self.scan_stride is not None:
             total = self.scan.output_partitioning().partition_count()
             parts = range(partition, total, self.scan_stride)
@@ -1011,14 +1135,17 @@ class FusedAggregateStage:
             # multi-file (scan_stride) reads are independent: decode up to
             # `workers` files concurrently, yielding tables in file order so
             # the batch stream is identical to the serial read
-            for table in ordered_map(
+            tables = ordered_map(
                 read_one, parts,
                 ctx.config.tpu_ingest_workers(), ctx.config.tpu_ingest_depth(),
-            ):
-                yield from table.to_batches(max_chunksize=ctx.batch_size)
+            )
+            for i, table in enumerate(tables):
+                for batch in table.to_batches(max_chunksize=ctx.batch_size):
+                    yield parts[i], batch
             return
         for p in parts:
-            yield from self.scan.execute(p, ctx)
+            for batch in self.scan.execute(p, ctx):
+                yield p, batch
 
     def _read_scan_file(self, path: str, ctx) -> pa.Table:
         """Eager parquet read of one scan file (dictionary pages map straight
@@ -1043,6 +1170,15 @@ class FusedAggregateStage:
         integer aggregates). batch_cols: one Dict[int, np.ndarray], or a list
         of them when the sum spans several mesh shards (psum adds across
         shards, so the bound uses the GLOBAL row count)."""
+        for name, rows in self._int_sum_rows(batch_cols):
+            if n > rows:
+                raise UnsupportedOnDevice(
+                    f"int32 sum over column {name!r} may overflow"
+                )
+
+    def _int_sum_rows(self, batch_cols) -> Iterator[Tuple[str, int]]:
+        """(input column, the most rows whose int32 sum cannot overflow) of
+        each integer SUM or AVG input that holds a non-zero value."""
         col_dicts = batch_cols if isinstance(batch_cols, list) else [batch_cols]
         for a, ie, ix in zip(self.aggs, self.agg_inputs, self.int_exact):
             if not ix or a.fn not in ("sum", "avg"):
@@ -1054,10 +1190,8 @@ class FusedAggregateStage:
                     maxabs = max(
                         maxabs, abs(int(npcol.max())), abs(int(npcol.min()))
                     )
-            if maxabs * n > _INT32_MAX:
-                raise UnsupportedOnDevice(
-                    f"int32 sum over column {ie.name!r} may overflow"
-                )
+            if maxabs:
+                yield ie.name, _INT32_MAX // maxabs
 
     def _lower_columns(self, batch: pa.RecordBatch) -> Dict[int, np.ndarray]:
         cols: Dict[int, np.ndarray] = {}
@@ -1093,7 +1227,8 @@ class FusedAggregateStage:
     # holds-lock: self._prepare_lock
     def _prepare_partition(self, partition: int, ctx) -> List[dict]:
         """Host work for one partition: scan, encode, pad, transfer. Returns
-        per-batch device-input entries (jnp column arrays stay resident).
+        the device-input entries, each of consecutive batches of a scan
+        partition (jnp column arrays stay resident).
         Like the sorted path, the staged host artifacts persist through
         ops/layout_cache.py: the low-cardinality shapes (q1/q6) pay the
         same full-scan decode at SF=100 (~400 s measured), so a fresh
@@ -1144,36 +1279,22 @@ class FusedAggregateStage:
         budget = ctx.config.tpu_hbm_budget()
         total_bytes = 0
 
-        def _prefetch(batch: pa.RecordBatch):
+        def _prefetch(item: Tuple[int, pa.RecordBatch]):
             # group codes FIRST: a high-cardinality switch must not pay the
             # column upload. Pure per-batch work (no shared stage state), so
             # batches may rank concurrently; the TooManyGroups decision
             # stays in the ordered consumer below for serial-identical
             # semantics.
             t0 = _time.perf_counter()
+            part, batch = item
             codes, key_values, n_groups = self._group_codes(batch)
-            return batch, codes, key_values, n_groups, _time.perf_counter() - t0
+            return part, batch, codes, key_values, n_groups, _time.perf_counter() - t0
 
-        batch_src = (
-            b for b in self._scan_batches(partition, ctx) if b.num_rows
-        )
-        for batch, codes, key_values, n_groups, dt in pipelined_map(
-            batch_src, _prefetch,
-            ctx.config.tpu_ingest_workers(), ctx.config.tpu_ingest_depth(),
-            on_src_time=src_times.append,
-        ):
-            scan_s += dt
-            n = batch.num_rows
-            bucket = bucket_rows(n)
-            if n_groups == 0:
-                continue
-            if n_groups > MAX_GROUPS:
-                # beyond the unrolled path's ceiling: run() retries with the
-                # sorted chunked-segment layout
-                raise TooManyGroups(f"{n_groups} groups exceeds unrolled path")
+        def _stage_entry(n: int, npcols: Dict[int, np.ndarray], codes: np.ndarray,
+                         key_values: List[pa.Array], n_groups: int) -> None:
+            nonlocal total_bytes, encode_s, upload_s
             t_enc0 = _time.perf_counter()
-            npcols = self._lower_columns(batch)
-            self._check_int_ranges(npcols, n)
+            bucket = bucket_rows(n)
             staged: Dict[int, tuple] = {}
             for idx in list(npcols):
                 npcol = npcols.pop(idx)
@@ -1219,6 +1340,46 @@ class FusedAggregateStage:
                 }
             )
             upload_s += _time.perf_counter() - t_up0
+
+        # consecutive batches of a scan partition are gathered
+        # (_GatheredBatches) up to the batch size and while the int32 sum
+        # bound holds, and become entries of bounded padding
+        held: Optional[_GatheredBatches] = None
+        batches_in = 0
+        batch_src = (
+            (p, b) for p, b in self._scan_part_batches(partition, ctx) if b.num_rows
+        )
+        for part, batch, codes, key_values, n_groups, dt in pipelined_map(
+            batch_src, _prefetch,
+            ctx.config.tpu_ingest_workers(), ctx.config.tpu_ingest_depth(),
+            on_src_time=src_times.append,
+        ):
+            scan_s += dt
+            batches_in += 1
+            n = batch.num_rows
+            if n_groups == 0:
+                continue
+            if n_groups > MAX_GROUPS:
+                # beyond the unrolled path's ceiling: run() retries with the
+                # sorted chunked-segment layout
+                raise TooManyGroups(f"{n_groups} groups exceeds unrolled path")
+            t_enc0 = _time.perf_counter()
+            npcols = self._lower_columns(batch)
+            self._check_int_ranges(npcols, n)
+            encode_s += _time.perf_counter() - t_enc0
+            sum_rows = dict(self._int_sum_rows(npcols))
+            if held is not None and not held.fits(part, n, sum_rows, ctx.batch_size):
+                for entry in held.entries():
+                    _stage_entry(*entry)
+                held = None
+            if held is None:
+                held = _GatheredBatches(part)
+            held.add(n, npcols, codes, key_values, n_groups, sum_rows)
+        if held is not None:
+            for entry in held.entries():
+                _stage_entry(*entry)
+        tracing.incr("device.gathered_batches_in", batches_in)
+        tracing.incr("device.gathered_batches_out", len(entries))
         if persisting and records:
             self._save_batches_layout(partition, ctx, records)
         scan_s += sum(src_times)

@@ -9,8 +9,10 @@ query of median length; per span name the seconds a query spends in it
 (median over the text's queries: whole and self), and the median of the
 first third of the window's queries against the last third (what grows as
 the process serves more); above them the window's counters, the `serde.*`
-and how many rank maps were served (`device.rank_map_hit`) and built, the rows
-the device aggregates handed the host (`device.groups_out`, and per text the
+and how many rank maps were served (`device.rank_map_hit`) and built, the
+batches the unrolled engine read and the entries it gathered them into
+(`device.gathered_batches_in` / `_out`), the rows the
+device aggregates handed the host (`device.groups_out`, and per text the
 `groups` of its `runtime.to_arrow` spans), the device joins (`runtime.join`:
 spans, seconds, `path`, `method` and `entries`, `out_rows`), the host joins
 (`executor.host_join`: `type`, `declined`, rows) and the key-set links
@@ -241,6 +243,10 @@ def report(cell: str, trace_dir: str = "") -> str:
            f"rank maps (fact aggregates, a partition a query): "
            f"{counters.get('device.rank_map_hit', 0)} served from the prepared partition, "
            f"{counters.get('device.rank_map_build', 0)} built",
+           f"gathered batches (the batches the unrolled engine read, and the entries "
+           f"it gathered them into): {counters.get('device.gathered_batches_in', 0)} in, "
+           f"{counters.get('device.gathered_batches_out', 0)} out (none where the "
+           f"prepared entries are resident; the set-up's: run.py's set-up counters)",
            f"device aggregates handed the host {counters.get('device.groups_out', 0)} rows "
            f"(device.groups_out); {_joins([s for s in log if s.name == 'runtime.join'])}; "
            f"{_host_joins([s for s in log if s.name == 'executor.host_join'])}; "

@@ -629,3 +629,271 @@ def test_the_two_ways_sum_to_map_rows_and_the_span_says_dense():
     assert len(spans) == 1 + 5 and all(s.attrs["dense"] == 2 for s in spans)
     gathers = [s for s in spans if "fact_rows" in s.attrs]
     assert sum(s.attrs["fact_rows"] * s.attrs["dense"] for s in gathers) == 600
+
+
+# ---------------------------------------------------------------------------
+# the unrolled engine gathers a scan partition's batches into entries of
+# bounded padding: a cached parquet read hands a file over in chunks
+# (131,072 rows, or a row group) that to_batches never merges, and run()
+# launches, reads back and assembles a partial table once an entry
+# ---------------------------------------------------------------------------
+
+
+def _chunked_star(tmp_path, n_files=2, rows=4000, group_rows=1000, units_max=1000,
+                  fk_max=850, seed=5):
+    """A fact of `n_files` files whose row groups of `group_rows` a cached
+    read hands over as that many batches, and an 800-row dimension (fact
+    keys from `fk_max` on match none)."""
+    rng = np.random.default_rng(seed)
+    fdir = tmp_path / "fact"
+    fdir.mkdir()
+    for f in range(n_files):
+        t = pa.table({
+            "fk": pa.array(rng.integers(0, fk_max, rows), type=pa.int64()),
+            "g": pa.array(np.arange(rows, dtype=np.int64) // 2),
+            "units": pa.array(rng.integers(0, units_max + 1, rows), type=pa.int64()),
+            "amount": pa.array(rng.uniform(-50, 100, rows)),
+        })
+        pq.write_table(t, str(fdir / f"part-{f}.parquet"), row_group_size=group_rows)
+    dim = pa.table({"dk": pa.array(np.arange(800), type=pa.int64()),
+                    "prio": pa.array([f"p{i % 3}" for i in range(800)])})
+    return {"fact": str(fdir), "dim": _write(tmp_path, "dim", dim)}
+
+
+def _run_traced(paths, sql, settings=()):
+    """(tpu answer, cpu answer, the tpu run's counters, its `runtime.launch`
+    spans) with fresh layout directories, so the stage prepares here."""
+    from ballista_tpu.utils import tracing
+
+    out = {}
+    for backend in ("tpu", "cpu"):
+        ctx = ExecutionContext(BallistaConfig({
+            "ballista.executor.backend": backend,
+            "ballista.tpu.layout_cache_dir": "", **dict(settings)}))
+        for name, p in paths.items():
+            ctx.register_parquet(name, p)
+        tracing.reset()
+        out[backend] = ctx.sql(sql).collect()
+        if backend == "tpu":
+            counters = tracing.counters()
+            launches = [s for s in tracing.spans() if s.name == "runtime.launch"]
+    tracing.reset()
+    return out["tpu"], out["cpu"], counters, launches
+
+
+def _prepared(stage):
+    return list(stage._device_cache.values())
+
+
+def test_one_entry_and_one_launch_a_scan_file(tmp_path):
+    """Two files of four row groups each, about 4,000 rows a file, within an
+    eighth of their 4,096-row bucket: the unrolled stage holds one entry a
+    file and launches once a file, and answers as the host does for a
+    float SUM, an integer SUM, a COUNT, float MIN / MAX (the bijection) and
+    a dimension-valued group key (the dimension-valued input keeps the fact
+    aggregate, which would take a dimension key alone, out of the way)."""
+    paths = _chunked_star(tmp_path)
+    sql = ("select prio, sum(amount) as s, sum(units) as u, count(*) as c, "
+           "min(amount) as lo, max(amount) as hi, "
+           "sum(case when prio = 'p2' then amount else 0 end) as s2 "
+           "from dim, fact where dk = fk group by prio order by prio")
+    t, c, counters, launches = _run_traced(paths, sql)
+    (stage,) = _mapped_stages()
+    prepared = _prepared(stage)
+    assert {p["kind"] for p in prepared} == {"batches"}
+    assert sum(len(p["entries"]) for p in prepared) == 2
+    assert len(launches) == 2
+    assert counters["device.gathered_batches_in"] == 8
+    assert counters["device.gathered_batches_out"] == 2
+    assert not counters.get("device.host_fallback")
+    assert t.column("prio").to_pylist() == c.column("prio").to_pylist() == ["p0", "p1", "p2"]
+    for name in ("u", "c"):
+        assert t.column(name).to_pylist() == c.column(name).to_pylist(), name
+    for name in ("lo", "hi"):
+        for a, b in zip(t.column(name).to_pylist(), c.column(name).to_pylist()):
+            assert np.float64(a).tobytes() == np.float64(b).tobytes(), name
+    for name in ("s", "s2"):
+        np.testing.assert_allclose(t.column(name).to_numpy(), c.column(name).to_numpy(),
+                                   rtol=1e-4, err_msg=name)
+
+
+def test_an_integer_sum_past_the_int32_bound_is_cut_not_declined(tmp_path):
+    """Each 1,000-row group of `units` (at most 10**6) passes the int32 sum
+    bound and a whole 5,000-row file does not: the file's batches are
+    gathered while the bound holds, two at a time (2,147 rows at most), and
+    the stage stays on the device with the exact sum."""
+    paths = _chunked_star(tmp_path, rows=5000, units_max=10 ** 6)
+    sql = ("select prio, sum(units) as u, "
+           "sum(case when prio = 'p0' then amount else 0 end) as s "
+           "from dim, fact where dk = fk group by prio order by prio")
+    t, c, counters, launches = _run_traced(paths, sql)
+    (stage,) = _mapped_stages()
+    entries = [e for p in _prepared(stage) for e in p["entries"]]
+    pieces = sorted(int(np.asarray(e["row_valid"]).sum()) for e in entries)
+    assert pieces == sorted([2000, 2000, 1000] * 2)
+    assert len(launches) == 6
+    assert counters["device.gathered_batches_in"] == 10
+    assert counters["device.gathered_batches_out"] == 6
+    assert not counters.get("device.host_fallback")
+    assert t.column("u").to_pylist() == c.column("u").to_pylist()
+    np.testing.assert_allclose(t.column("s").to_numpy(), c.column("s").to_numpy(), rtol=1e-4)
+
+
+def test_a_gathered_batch_past_max_groups_reaches_the_sorted_engine(tmp_path):
+    """500 groups a row group, 2,000 a file: the gathered batch passes the
+    unrolled engine's MAX_GROUPS, and the stage prepares sorted instead,
+    with the host's answer."""
+    from ballista_tpu.ops.stage import MAX_GROUPS
+
+    paths = _chunked_star(tmp_path)
+    sql = ("select g, sum(case when prio = 'p1' then amount else 0 end) as s, "
+           "count(*) as c from dim, fact where dk = fk group by g order by g")
+    t, c, counters, _ = _run_traced(paths, sql)
+    assert 2000 > MAX_GROUPS > 500
+    (stage,) = _mapped_stages()
+    assert {p["kind"] for p in _prepared(stage)} == {"sorted"}
+    assert not counters.get("device.host_fallback")
+    assert t.column("g").to_pylist() == c.column("g").to_pylist()
+    assert t.column("c").to_pylist() == c.column("c").to_pylist()
+    np.testing.assert_allclose(t.column("s").to_numpy(), c.column("s").to_numpy(),
+                               rtol=1e-4, atol=1e-9)
+
+
+def test_gathered_batches_renumber_groups_and_bound_the_entry():
+    """Two batches whose batch-local group codes differ: the entry numbers
+    the groups that occur, batch by batch in the order of their batch-local
+    codes, joins the lowered columns, and
+    takes no batch of another partition, past the row limit or past the
+    int32 sum bound."""
+    from ballista_tpu.ops.stage import _GatheredBatches
+
+    held = _GatheredBatches(part=3)
+    # batch 1: keys (a, x), (a, y), (b, x) by codes 0..2; (b, y) = 3 occurs not
+    held.add(3, {0: np.array([1, 2, 3], dtype=np.int32)},
+             np.array([2, 0, 0], dtype=np.int32),
+             [pa.array(["a", "a", "b", "b"]), pa.array(["x", "y", "x", "y"])], 4,
+             {"v": 1000})
+    # batch 2: its own codes, (b, x) again and (c, z) new
+    held.add(2, {0: np.array([4, 5], dtype=np.int32)},
+             np.array([1, 0], dtype=np.int32),
+             [pa.array(["c", "b"]), pa.array(["z", "x"])], 2, {"v": 600})
+    ((rows, cols, codes, key_values, n_groups),) = held.entries()
+    assert n_groups == 3 and rows == held.rows == 5
+    assert cols[0].tolist() == [1, 2, 3, 4, 5]
+    keys = list(zip(*(kv.to_pylist() for kv in key_values)))
+    assert keys == [("a", "x"), ("b", "x"), ("c", "z")]
+    assert [keys[c] for c in codes] == [("b", "x"), ("a", "x"), ("a", "x"),
+                                        ("b", "x"), ("c", "z")]
+    assert held.fits(3, 595, {}, limit=600)
+    assert not held.fits(4, 1, {}, limit=600)  # another scan partition
+    assert not held.fits(3, 596, {}, limit=600)  # past the batch size
+    assert not held.fits(3, 596, {"v": 10 ** 6}, limit=10 ** 6)  # past v's 600
+    assert not held.fits(3, 96, {"w": 100}, limit=10 ** 6)  # past the batch's own
+
+
+def test_a_memory_scan_gathers_its_batches_into_one_entry():
+    """A memory table's five 200-row batches become one entry and one
+    launch, with the host's answer."""
+    from ballista_tpu.utils import tracing
+
+    rng = np.random.default_rng(3)
+    batches = [pa.record_batch({"k": pa.array(rng.integers(0, 4, 200), type=pa.int64()),
+                                "v": pa.array(rng.uniform(0, 1, 200))})
+               for _ in range(5)]
+    table = pa.Table.from_batches(batches)
+    ctx = ExecutionContext(BallistaConfig({"ballista.executor.backend": "tpu"}))
+    ctx.register_record_batches("t", table)
+    tracing.reset()
+    got = ctx.sql("select k, sum(v) as s from t group by k order by k").collect()
+    launches = [s for s in tracing.spans() if s.name == "runtime.launch"]
+    counters = tracing.counters()
+    tracing.reset()
+    stages = [s for s in kernels._stage_cache.values() if isinstance(s, FusedAggregateStage)]
+    assert [len(p["entries"]) for s in stages for p in _prepared(s)] == [1]
+    assert len(launches) == 1
+    assert counters["device.gathered_batches_in"] == 5
+    assert counters["device.gathered_batches_out"] == 1
+    want = table.group_by("k").aggregate([("v", "sum")]).sort_by("k")
+    assert got.column("k").to_pylist() == want.column("k").to_pylist()
+    np.testing.assert_allclose(got.column("s").to_numpy(),
+                               want.column("v_sum").to_numpy(), rtol=1e-5)
+
+
+def _device_bytes(entries):
+    """What _prepare_partition counts against the HBM budget for `entries`:
+    the padded columns (and their lookup tables), the codes and row_valid."""
+    total = 0
+    for e in entries:
+        for col in e["cols"].values():
+            total += sum(a.nbytes for a in (col if isinstance(col, tuple) else (col,)))
+        total += 3 * len(e["row_valid"])
+    return total
+
+
+def test_a_file_just_past_a_power_of_two_pads_a_little_and_stays_resident(
+        tmp_path, monkeypatch):
+    """A file of nine 1,024-row groups and a 100-row one, 9,316 rows: as one
+    entry it would pad to 16,384 rows. Gathered with every bucket past the
+    least one subject to the padding bound (the bound's scale shrunk to
+    this file's), it is an entry of 8,192 rows and one of 1,124 (2,048
+    padded), 10% padding; under a budget that holds that layout with a
+    quarter to spare, and not a 16,384-row entry, the stage stays on the
+    device with the host's answers."""
+    from ballista_tpu.ops import runtime
+    from ballista_tpu.ops import stage as stage_mod
+    from ballista_tpu.ops.stage import _tight_runs
+
+    monkeypatch.setattr(stage_mod, "_WHOLE_BUCKET_ROWS", 1024)
+
+    paths = _chunked_star(tmp_path, n_files=1, rows=9 * 1024 + 100, group_rows=1024,
+                          fk_max=800)
+    # a dimension-valued input keeps the fact aggregate out of the way
+    sql = ("select prio, sum(units) as u, count(*) as c, "
+           "sum(case when prio = 'p2' then amount else 0 end) as s "
+           "from dim, fact where dk = fk group by prio order by prio")
+    t, c, counters, launches = _run_traced(paths, sql)
+    (stage,) = _mapped_stages()
+    entries = [e for p in _prepared(stage) for e in p["entries"]]
+    assert [int(np.asarray(e["row_valid"]).sum()) for e in entries] == [8192, 1124]
+    assert [len(e["row_valid"]) for e in entries] == [8192, 2048]
+    assert counters["device.gathered_batches_in"] == 10
+    assert counters["device.gathered_batches_out"] == 2
+    # a budget with a quarter to spare over these entries (16,384 padded
+    # rows in one entry would take 1.6 times their bytes), and no pin of
+    # another test's stages in it
+    budget = _device_bytes(entries) * 5 // 4
+    kernels._stage_cache.clear()
+    kernels._stage_cache_pins.clear()
+    runtime.reset_residency()
+    t, c, counters, launches = _run_traced(
+        paths, sql, {"ballista.tpu.hbm_budget_bytes": str(budget)})
+    (stage,) = _mapped_stages()
+    assert {p["kind"] for p in _prepared(stage)} == {"batches"}
+    assert len(launches) == 2 and not counters.get("device.host_fallback")
+    for name in ("prio", "u", "c"):
+        assert t.column(name).to_pylist() == c.column(name).to_pylist(), name
+    np.testing.assert_allclose(t.column("s").to_numpy(), c.column("s").to_numpy(), rtol=1e-4)
+    # the runs themselves: a 131,072-row chunk file just past 2^23 rows
+    chunks = [131072] * 68 + [87104]
+    runs = _tight_runs(chunks)
+    assert [sum(chunks[lo:hi]) for lo, hi in runs] == [2 ** 23, 2 ** 19, 87104]
+    assert _tight_runs([1000] * 5) == [(0, 4), (4, 5)]  # 5,120 rows padded, not 8,192
+    assert _tight_runs([600, 600]) == [(0, 2)]  # two entries would pad as much: no cut
+    assert _tight_runs([3, 2]) == [(0, 2)]  # under the least bucket: no cut
+
+
+def test_a_gathered_run_of_a_small_bucket_stays_one_entry():
+    """Padding is bounded where it costs device memory: a run whose bucket
+    is at most _WHOLE_BUCKET_ROWS stays one entry (58 filtered chunks of
+    670 rows, 38,860 rows in a 65,536-row bucket: one launch, not three),
+    and a larger one is cut (a file of 131,072-row chunks just past 2^23
+    rows: 2^23 and a tail within its 2^20-row bucket)."""
+    from ballista_tpu.ops.stage import _WHOLE_BUCKET_ROWS, _tight_runs
+
+    assert _WHOLE_BUCKET_ROWS == 2 ** 20
+    assert _tight_runs([670] * 58) == [(0, 58)]
+    assert _tight_runs([131072] * 4 + [87104]) == [(0, 5)]  # 611,392 rows in 2^20
+    assert _tight_runs([131072] * 8 + [87104]) == [(0, 8), (8, 9)]  # 2^20 + 2^17, not 2^21
+    chunks = [131072] * 68 + [87104]
+    runs = _tight_runs(chunks)
+    assert [sum(chunks[lo:hi]) for lo, hi in runs] == [2 ** 23, 4 * 131072 + 87104]
